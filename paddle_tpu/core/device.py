@@ -6,10 +6,11 @@ design: a Place names a jax.Device; the "device context" role (stream + handle
 ownership) is played by PJRT inside jax, so the pool here is just a thin registry
 plus the current-device state used by tensor creation.
 """
+import functools
 import threading
+import warnings
 
 import jax
-import numpy as np
 
 _state = threading.local()
 
@@ -38,9 +39,11 @@ class Place:
 
     def jax_device(self):
         devs = _devices_of_type(self.device_type)
-        if not devs:
-            raise RuntimeError(f"No {self.device_type} devices available")
-        return devs[min(self.device_id, len(devs) - 1)]
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError(
+                f"{self!r}: device id out of range, {len(devs)} "
+                f"device(s) visible")
+        return devs[self.device_id]
 
 
 def CPUPlace():
@@ -57,15 +60,21 @@ def CUDAPlace(device_id=0):  # accepted for API parity; maps to accelerator 0
 
 def _devices_of_type(device_type):
     if device_type == "cpu":
-        try:
-            return jax.devices("cpu")
-        except RuntimeError:
-            return []
-    # Any non-cpu type maps to the default accelerator backend.
+        return jax.devices("cpu")
+    # Any non-cpu type names the default backend's devices.  Reference
+    # scripts say CUDAPlace/TPUPlace on accelerator-less hosts too (the
+    # CPU test mesh), so that maps onto the CPU — said once, not silently.
     default = jax.devices()
-    if default and default[0].platform != "cpu":
-        return default
+    if default[0].platform == "cpu":
+        _warn_accelerator_place_on_cpu(device_type)
     return default
+
+
+@functools.cache
+def _warn_accelerator_place_on_cpu(device_type):
+    warnings.warn(
+        f"a {device_type!r} place was asked for but JAX sees no "
+        f"accelerator: it is placed on the CPU backend", stacklevel=4)
 
 
 def _default_device_type():
@@ -137,17 +146,3 @@ def is_compiled_with_rocm():
 
 def get_cudnn_version():
     return None  # no cuDNN in a TPU build (API parity)
-
-
-def lowered_cost_stats(lowered):
-    """Normalize jax.stages.Lowered.cost_analysis() across jax versions
-    (dict, list-of-dicts, or unavailable) into a plain dict or None.
-    Shared by the compiled-train-step and static-executor cost hooks
-    (the reference op_tester.cc FLOPs-accounting role)."""
-    try:
-        ca = lowered.cost_analysis()
-    except Exception:
-        return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return dict(ca) if ca else None

@@ -10,13 +10,17 @@ prefetch (operators/reader/buffered_reader.h:36).  Per-op fast paths
 path; only whole-graph calls cross the boundary.
 
 The shared library is built on demand with g++ (no pybind11 in the image; the
-ABI is plain C consumed via ctypes).  If a toolchain is unavailable the
-framework degrades to pure-Python planning (`available()` -> False).
+ABI is plain C consumed via ctypes).  If the build fails the static executor
+plans in pure Python (`available()` -> False); the failure is logged once,
+with the compiler's words, and kept in `build_error()`.
 """
 import ctypes
+import logging
 import os
 import subprocess
 import threading
+
+_log = logging.getLogger("ptn.native")
 
 _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
@@ -40,9 +44,20 @@ def _build_and_load():
         lib = ctypes.CDLL(so_path)
     except (OSError, ValueError, subprocess.CalledProcessError) as e:
         _lib_err = e
+        _log.warning("native runtime not built (%s): %s", so_path,
+                     build_error())
         return None
     _declare(lib)
     return lib
+
+
+def build_error():
+    """Why the native library is unavailable (make's stderr when the
+    build itself failed), or None."""
+    if _lib_err is None:
+        return None
+    stderr = getattr(_lib_err, "stderr", None)
+    return f"{_lib_err}" + (f"\n{stderr.strip()}" if stderr else "")
 
 
 def _declare(lib):

@@ -29,6 +29,20 @@ def _interpret():
     return jax.default_backend() == "cpu"
 
 
+def resolve_interpret(interpret):
+    """The `interpret` flag a pallas_call gets: by backend when the
+    caller passed None, the caller's choice otherwise — except that
+    nothing may run the interpreter on a TPU, where it would pass for
+    the kernel while Mosaic never ran."""
+    if interpret is None:
+        return _interpret()
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError(
+            "interpret=True on the TPU backend: the Pallas interpreter "
+            "would stand in for the Mosaic kernel; pass interpret=None")
+    return bool(interpret)
+
+
 _DEFAULT_BLOCK = 512  # swept on v5e: 512 beats 128 ~2x (fewer grid steps)
 
 

@@ -21,13 +21,16 @@ dims (page_size, D); q/out ride as [B, H, 1, D] with (1, 1, 1, D) blocks.
 
 INT8 POOLS: every public kernel takes optional ``k_scale``/``v_scale``
 [P, H] per-page per-head abs-max arrays (generation.quantized_kv).
-They ride as two more scalar-prefetch operands, and each live grid
-cell dequantizes its page block in-kernel — ``int8 * (scale * 1/127)``
-with the exact expression the jnp gather references use, so
-kernel-vs-reference operands stay bitwise equal — before the score
-matmul.  The jnp references dequantize their gathered O(tokens) views;
-the kernels dequantize per block; nobody ever materializes a
-dequantized pool.
+They ride as two more blocked VMEM operands, re-laid per call as one
+128-lane row per 128 pages (``_scale_rows``) and indexed through the
+page table like K/V, so their footprint does not grow with the pool
+(as scalar-prefetch operands the two [P, H] arrays overflowed the
+1 MiB SMEM at num_pages >= 1024).  Each live grid cell dequantizes its
+page block in-kernel — ``int8 * (scale * 1/127)`` with the exact
+expression the jnp gather references use, so kernel-vs-reference
+operands stay bitwise equal — before the score matmul.  The jnp
+references dequantize their gathered O(tokens) views; the kernels
+dequantize per block; nobody ever materializes a dequantized pool.
 
 MESH-NATIVE dispatch: every public kernel takes ``mesh`` / ``tp_axis``.
 Heads are fully independent in all three grids, so under a head-sharded
@@ -48,7 +51,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _interpret
+from .flash_attention import NEG_INF, resolve_interpret
 
 # int8 KV dequant factor: MUST stay bit-equal to
 # generation.quantized_kv.INV_QMAX — the jnp gather references multiply
@@ -79,6 +82,57 @@ def _require_scales(pool, k_scale, v_scale):
             f"k_scale/v_scale passed with a {pool.dtype} pool — scales "
             "belong to int8 pools only (float values would be silently "
             "multiplied by scale/127)")
+
+
+# int8 scales reach the kernels as [H, ceil(P / 128), 1, 128]: a
+# (1, 1, 1, 128) block — tile-legal, its trailing dims are full dims —
+# holds the scales of 128 consecutive pages of one head
+_SCALE_LANES = 128
+
+
+def _scale_rows(scale):
+    """[P, H] per-page per-head scales -> [H, ceil(P/128), 1, 128]."""
+    p, h = scale.shape
+    rows = -(-p // _SCALE_LANES)
+    st = jnp.transpose(jnp.asarray(scale, jnp.float32))
+    if rows * _SCALE_LANES != p:
+        st = jnp.pad(st, ((0, 0), (0, rows * _SCALE_LANES - p)))
+    return st.reshape(h, rows, 1, _SCALE_LANES)
+
+
+def _pool_specs(page_of, page_size, d, n_scales):
+    """in_specs of the k and v page blocks plus, for int8 pools, their
+    `_scale_rows` operands — all indexed by
+    ``page_of(*grid_ids, *prefetch_refs) -> (head, page)``, so a scale
+    block is the lane row that holds its page block's scale."""
+    def lane_row(*ids_and_refs):
+        head, page = page_of(*ids_and_refs)
+        return (head, page // _SCALE_LANES, 0, 0)
+
+    page = pl.BlockSpec(
+        (1, 1, page_size, d),
+        lambda *ids_and_refs: (*page_of(*ids_and_refs), 0, 0))
+    scale = pl.BlockSpec((1, 1, 1, _SCALE_LANES), lane_row)
+    return [page, page] + [scale] * n_scales
+
+
+def _dequant_page(block, s_ref, page):
+    """int8 page block -> f32 values: ``int8 * (scale * 1/127)``, the
+    jnp references' expression.  `page`'s scale comes out of its lane
+    row by a one-hot select and a sum of exact zeros, bit for bit."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _SCALE_LANES), 1)
+    scale = jnp.sum(
+        jnp.where(lane == page % _SCALE_LANES, s_ref[0, 0], 0.0),
+        axis=1, keepdims=True)                       # [1, 1]
+    return block.astype(jnp.float32) * (scale * INV_QMAX)
+
+
+def _split_refs(refs, quantized):
+    """Kernel operand refs past the scalar-prefetch ones, as ``(q, k, v,
+    ks, vs, o, acc, m, l)`` — ks/vs None unless quantized."""
+    if quantized:
+        return refs
+    return (*refs[:3], None, None, *refs[3:])
 
 
 _STATE_ROWS = 8  # scratch rows; every row holds the same value so all
@@ -203,18 +257,15 @@ def ragged_score_blocks(starts, lens, kv_lens, page_size, n_pages, n_rows,
 
 def _decode_kernel(pt_ref, sl_ref, *refs, page_size, n_pages,
                    quantized=False):
-    """refs: ``[ks_ref, vs_ref]`` (quantized only — [P, H] scale
-    arrays in SMEM via scalar prefetch) + q/k/v/o + the three scratch
-    buffers.  In-kernel dequant: the int8 page block multiplies by its
-    ONE per-(page, head) factor ``scale * (1/127)`` before the score
-    matmul — the same elementwise expression the jnp reference applies
-    to its gathered view."""
-    if quantized:
-        ks_ref, vs_ref = refs[0], refs[1]
-        refs = refs[2:]
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    """refs: q/k/v + ``[ks_ref, vs_ref]`` (quantized only — the
+    `_scale_rows` lane rows holding this cell's page) + o + the three
+    scratch buffers.  In-kernel dequant: the int8 page block multiplies
+    by its ONE per-(page, head) factor ``scale * (1/127)`` before the
+    score matmul — the same elementwise expression the jnp reference
+    applies to its gathered view."""
+    q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = \
+        _split_refs(refs, quantized)
     b = pl.program_id(0)
-    h = pl.program_id(1)
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -233,8 +284,8 @@ def _decode_kernel(pt_ref, sl_ref, *refs, page_size, n_pages,
         v = v_ref[0, 0]
         if quantized:
             page = pt_ref[b, i]
-            k = k.astype(jnp.float32) * (ks_ref[page, h] * INV_QMAX)
-            v = v.astype(jnp.float32) * (vs_ref[page, h] * INV_QMAX)
+            k = _dequant_page(k, ks_ref, page)
+            v = _dequant_page(v, vs_ref, page)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         pos = i * page_size + jax.lax.broadcasted_iota(
@@ -269,13 +320,10 @@ def _chunk_kernel(pt_ref, info_ref, *refs, page_size, n_pages, n_rows,
     freshly scattered keys — with a per-row causal mask.  Online-softmax
     state is [n_rows, ...] (the decode kernel's, grown from 1 query row
     to the chunk), accumulated across the page axis.  Quantized pools
-    prepend [P, H] scale refs and dequantize each page block in-kernel
-    (see _decode_kernel)."""
-    if quantized:
-        ks_ref, vs_ref = refs[0], refs[1]
-        refs = refs[2:]
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    h = pl.program_id(0)
+    add the scale lane-row refs after q/k/v and dequantize each page
+    block in-kernel (see _decode_kernel)."""
+    q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = \
+        _split_refs(refs, quantized)
     i = pl.program_id(1)
     start = info_ref[0]
 
@@ -295,8 +343,8 @@ def _chunk_kernel(pt_ref, info_ref, *refs, page_size, n_pages, n_rows,
         v = v_ref[0, 0]
         if quantized:
             page = pt_ref[i]
-            k = k.astype(jnp.float32) * (ks_ref[page, h] * INV_QMAX)
-            v = v.astype(jnp.float32) * (vs_ref[page, h] * INV_QMAX)
+            k = _dequant_page(k, ks_ref, page)
+            v = _dequant_page(v, vs_ref, page)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         pos = i * page_size + jax.lax.broadcasted_iota(
@@ -351,14 +399,11 @@ def _ragged_kernel(pt_ref, st_ref, ln_ref, kv_ref, *refs, page_size,
     tile the descriptor doesn't own see an all-NEG_INF score row, whose
     update is the exact identity (alpha == exp(0) == 1, sum(p) == 0),
     so tiles straddling a descriptor boundary stay exact.  Descriptors
-    with ln == 0 (padding) never run.  Quantized pools prepend [P, H]
-    scale refs and each live cell dequantizes its page block in-kernel
-    (see _decode_kernel)."""
-    if quantized:
-        ks_ref, vs_ref = refs[0], refs[1]
-        refs = refs[2:]
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    hh = pl.program_id(0)
+    with ln == 0 (padding) never run.  Quantized pools add the scale
+    lane-row refs after q/k/v and each live cell dequantizes its page
+    block in-kernel (see _decode_kernel)."""
+    q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = \
+        _split_refs(refs, quantized)
     s = pl.program_id(1)
     i = pl.program_id(2)
     qt = pl.program_id(3)
@@ -390,8 +435,8 @@ def _ragged_kernel(pt_ref, st_ref, ln_ref, kv_ref, *refs, page_size,
         v = v_ref[0, 0]
         if quantized:
             page = pt_ref[s, i]
-            k = k.astype(jnp.float32) * (ks_ref[page, hh] * INV_QMAX)
-            v = v.astype(jnp.float32) * (vs_ref[page, hh] * INV_QMAX)
+            k = _dequant_page(k, ks_ref, page)
+            v = _dequant_page(v, vs_ref, page)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         row = row0 + jax.lax.broadcasted_iota(
@@ -442,6 +487,12 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
     descriptors; all three ride as scalar-prefetch operands so the
     BlockSpec index_map DMAs each descriptor's pages straight out of
     the pool).  Returns [T, H, D].
+
+    LIMIT: the page tables live whole in SMEM (1 MiB on v5e, minor
+    dimension padded to 128): fine at 2k context (9 rows x 128 pages =
+    4.5 KiB), refused by the compiler near 128k context x 65 rows
+    (8192 pages a row = 2 MiB).  Long-context serving needs the table
+    blocked per descriptor; not done here.
 
     q_block tiles the packed query axis (default RAGGED_Q_BLOCK):
     (tile, descriptor, page) cells whose rows lie outside the
@@ -495,17 +546,17 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
         vt = jnp.transpose(v_pool, (2, 0, 1, 3))
     n_seqs, n_pages = page_tables.shape
 
-    # scalar-prefetch operands: page tables + descriptors, plus the
-    # [P, H] scale arrays for int8 pools (SMEM scalars the kernel
-    # indexes per (page, head) for the in-block dequant).  index_maps
-    # take *refs so one lambda serves both operand counts.
+    # scalar-prefetch operands (SMEM): page tables + descriptors
     prefetch = [jnp.asarray(page_tables, jnp.int32),
                 jnp.asarray(starts, jnp.int32),
                 jnp.asarray(lens, jnp.int32),
                 jnp.asarray(kv_lens, jnp.int32)]
-    if quantized:
-        prefetch += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+
+    def page_of(h_, s, i, qt, pt_ref, *_):
+        return h_, pt_ref[s, i]
+
+    scales = ([_scale_rows(k_scale), _scale_rows(v_scale)]
+              if quantized else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         # query tiles INNERMOST: the k/v block index is constant across
@@ -517,12 +568,7 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
         in_specs=[
             pl.BlockSpec((1, tpad, d),
                          lambda h_, s, i, qt, *refs: (h_, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda h_, s, i, qt, *refs:
-                         (h_, refs[0][s, i], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda h_, s, i, qt, *refs:
-                         (h_, refs[0][s, i], 0, 0)),
+            *_pool_specs(page_of, page_size, d, len(scales)),
         ],
         out_specs=pl.BlockSpec((1, tpad, d),
                                lambda h_, s, i, qt, *refs: (h_, 0, 0)),
@@ -538,8 +584,8 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, tpad, d), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-    )(*prefetch, qs, kt, vt)
+        interpret=resolve_interpret(interpret),
+    )(*prefetch, qs, kt, vt, *scales)
     return jnp.transpose(out[:, :t], (1, 0, 2))
 
 
@@ -594,18 +640,18 @@ def chunk_prefill_attention_kernel(q, k_pool, v_pool, page_table, start,
     info = jnp.asarray(start, jnp.int32).reshape(1)
 
     prefetch = [jnp.asarray(page_table, jnp.int32), info]
-    if quantized:
-        prefetch += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+
+    def page_of(h_, i, pt_ref, *_):
+        return h_, pt_ref[i]
+
+    scales = ([_scale_rows(k_scale), _scale_rows(v_scale)]
+              if quantized else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(h, n_pages),
         in_specs=[
             pl.BlockSpec((1, n, d), lambda h_, i, *refs: (h_, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d), lambda h_, i, *refs:
-                         (h_, refs[0][i], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d), lambda h_, i, *refs:
-                         (h_, refs[0][i], 0, 0)),
+            *_pool_specs(page_of, page_size, d, len(scales)),
         ],
         out_specs=pl.BlockSpec((1, n, d), lambda h_, i, *refs:
                                (h_, 0, 0)),
@@ -621,8 +667,8 @@ def chunk_prefill_attention_kernel(q, k_pool, v_pool, page_table, start,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, n, d), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-    )(*prefetch, qs, kt, vt)
+        interpret=resolve_interpret(interpret),
+    )(*prefetch, qs, kt, vt, *scales)
     return jnp.transpose(out, (1, 0, 2))
 
 
@@ -677,19 +723,19 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, page_tables, seq_lens,
 
     prefetch = [jnp.asarray(page_tables, jnp.int32),
                 jnp.asarray(seq_lens, jnp.int32)]
-    if quantized:
-        prefetch += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+
+    def page_of(b_, h_, i, pt_ref, *_):
+        return h_, pt_ref[b_, i]
+
+    scales = ([_scale_rows(k_scale), _scale_rows(v_scale)]
+              if quantized else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, h, n_pages),
         in_specs=[
             pl.BlockSpec((1, 1, 1, d), lambda b_, h_, i, *refs:
                          (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d), lambda b_, h_, i, *refs:
-                         (h_, refs[0][b_, i], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d), lambda b_, h_, i, *refs:
-                         (h_, refs[0][b_, i], 0, 0)),
+            *_pool_specs(page_of, page_size, d, len(scales)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, d), lambda b_, h_, i, *refs:
                                (b_, h_, 0, 0)),
@@ -704,6 +750,6 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, page_tables, seq_lens,
                           n_pages=n_pages, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        interpret=_interpret() if interpret is None else interpret,
-    )(*prefetch, qs, kt, vt)
+        interpret=resolve_interpret(interpret),
+    )(*prefetch, qs, kt, vt, *scales)
     return out.reshape(b, h, d)

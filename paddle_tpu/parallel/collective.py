@@ -13,28 +13,19 @@ Inside compiled/shard_map code the same functions map to lax collectives.
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _jax_shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-import inspect as _inspect
-
-_SM_PARAMS = set(_inspect.signature(_shard_map_raw).parameters)
-_SM_NOCHECK = (
-    {"check_rep": False} if "check_rep" in _SM_PARAMS
-    else {"check_vma": False} if "check_vma" in _SM_PARAMS else {}
-)
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kw):
-    kw.pop("check_rep", None)
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **_SM_NOCHECK)
 
 from ..core.tensor import Tensor, _wrap_data
 from . import env as _env
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    """jax.shard_map with positional mesh/specs and the varying-axes
+    check off (the step functions mix replicated and per-shard values
+    the checker cannot type)."""
+    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
 
 
 class ReduceOp:

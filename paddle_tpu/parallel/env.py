@@ -8,12 +8,15 @@ jax.sharding.Mesh over ICI/DCN; "rings" become named mesh axes; bootstrap is
 jax.distributed.initialize (coordination service) on multi-host.  Groups
 (new_group) are sub-axes of the mesh rather than new communicators.
 """
+import logging
 import os
 import threading
 
 import numpy as np
 import jax
 from jax.sharding import Mesh, PartitionSpec, NamedSharding
+
+_log = logging.getLogger("ptn.parallel")
 
 _lock = threading.Lock()
 _global_mesh = None
@@ -119,6 +122,13 @@ def build_mesh(shape_dict, dcn_shape_dict=None):
     sizes = tuple(int(v) for v in shape_dict.values())
     n = int(np.prod(sizes))
     devs = jax.devices()
+    if n > len(devs):
+        raise ValueError(
+            f"mesh {dict(shape_dict)} needs {n} devices, "
+            f"{len(devs)} visible")
+    if n < len(devs):
+        _log.info("build_mesh %s: using the first %d of %d devices",
+                  dict(shape_dict), n, len(devs))
     if dcn_shape_dict is not None:
         unknown = set(dcn_shape_dict) - set(names)
         if unknown:
@@ -147,14 +157,13 @@ def build_mesh(shape_dict, dcn_shape_dict=None):
                      for i in pair]
             devices = arr.transpose(order).reshape(sizes)
         return Mesh(devices, names)
-    if devs and devs[0].platform == "tpu" and n == len(devs):
-        try:
-            from jax.experimental import mesh_utils
+    if devs[0].platform == "tpu" and n == len(devs):
+        from jax.experimental import mesh_utils
 
-            devices = mesh_utils.create_device_mesh(sizes, devices=devs)
-            return Mesh(devices, names)
-        except Exception:
-            pass  # odd topologies: fall through to the plain reshape
+        # a topology mesh_utils cannot lay out raises: a plain reshape
+        # would run, with axes that do not follow the ICI links
+        devices = mesh_utils.create_device_mesh(sizes, devices=devs)
+        return Mesh(devices, names)
     devices = np.array(devs[:n]).reshape(sizes)
     return Mesh(devices, names)
 

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 from jax.sharding import PartitionSpec as P, NamedSharding
 
-from .collective import shard_map as _shard_map  # version-compat wrapper
+from .collective import shard_map as _shard_map
 
 from ..core.tensor import Tensor, _wrap_data
 from ..core import autograd, random as _random
@@ -547,17 +547,21 @@ class CompiledTrainStep:
             self.params, self.flat_opt_state, vals, key, jnp.uint32(0), lr)
 
     def cost_analysis(self, *batch):
-        """XLA cost analysis of the compiled step (the reference's
+        """XLA cost analysis of the lowered step (the reference's
         operators/benchmark/op_tester.cc role, but for the whole fused
-        step).  Returns the lowered computation's stats dict (keys like
-        'flops', 'bytes accessed') or None when the backend can't say.
-        Measured FLOPs from here beat hand 2*N*tokens models: embedding
-        lookups aren't counted as matmuls and remat FLOPs are included.
-        Build errors (bad mesh/spec) propagate — they would fail step()
-        identically."""
-        from ..core.device import lowered_cost_stats
+        step): a dict with keys like 'flops' and 'bytes accessed', or
+        None where JAX cannot analyse a lowering — every TPU lowering
+        under jax 0.9.0, where only the compiled executable is analysed;
+        callers fall back to a hand model.  Measured FLOPs beat hand
+        2*N*tokens models: embedding lookups aren't counted as matmuls
+        and remat FLOPs are included."""
+        return self._lowered(*batch).cost_analysis()
 
-        return lowered_cost_stats(self._lowered(*batch))
+    def lowered_text(self, *batch):
+        """StableHLO text of the step as it lowers for this backend —
+        what to grep to see whether a kernel (a `tpu_custom_call`) or its
+        composite fallback went into the program."""
+        return self._lowered(*batch).as_text()
 
     def memory_analysis(self, *batch):
         """CompiledMemoryStats of the fused step (peak/temp HBM), or None

@@ -470,13 +470,9 @@ class PipelinedTrainStep:
             self._opt_state["block"], iv, lv, key, jnp.uint32(0), lr)
 
     def cost_analysis(self, ids, labels):
-        """XLA cost stats of the compiled pipelined step, or None."""
-        from ..core.device import lowered_cost_stats
-
-        try:
-            return lowered_cost_stats(self._lowered(ids, labels))
-        except Exception:
-            return None
+        """XLA cost stats of the lowered pipelined step; None for a TPU
+        lowering (see CompiledTrainStep.cost_analysis)."""
+        return self._lowered(ids, labels).cost_analysis()
 
     def memory_analysis(self, ids, labels):
         """CompiledMemoryStats of the pipelined step; temp_size_in_bytes is

@@ -1,0 +1,123 @@
+"""Compile the Pallas kernels for a TPU v5e with no TPU attached.
+
+libtpu builds a compile-only ``v5e:2x2`` topology under
+``JAX_PLATFORMS=cpu``; lowering against one of its devices runs the real
+XLA:TPU and Mosaic compilers.  Tier-1 otherwise runs every kernel in the
+Pallas interpreter, which accepts programs Mosaic refuses (the int8 pools
+overflowed SMEM at num_pages >= 1024 for five PRs while every interpreted
+test passed).  The installation is fixed, so a topology that cannot be
+built is a failure, not a skip.  Shapes are the ones chip_smoke.py's server
+runs: 8 heads of 128, 4096 pages of 16 tokens.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.generation.decode_attention import (
+    chunk_prefill_attention, paged_decode_attention, ragged_paged_attention)
+
+# the package re-exports the function under the module's name
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+HEADS, HEAD_DIM, NUM_PAGES, PAGE_SIZE = 8, 128, 4096, 16
+SLOTS, CHUNK, MAX_PAGES = 8, 64, 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def mosaic_not_interpreter(monkeypatch):
+    # the default backend here is the CPU, where the kernels pick the
+    # interpreter; the lowering below targets the TPU
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+
+def _compile(fn, sharding, *shaped):
+    structs = [jax.ShapeDtypeStruct(shape, np.dtype(dtype),
+                                    sharding=sharding)
+               for shape, dtype in shaped]
+    compiled = jax.jit(fn).lower(*structs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _pool_operands(kv_dtype):
+    pool = ((NUM_PAGES, PAGE_SIZE, HEADS, HEAD_DIM), kv_dtype)
+    scale = ((NUM_PAGES, HEADS), np.float32)
+    return [pool, pool] + ([scale, scale] if kv_dtype == "int8" else [])
+
+
+def _scales(rest):
+    return ({"k_scale": rest[0], "v_scale": rest[1]} if rest else {})
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+def test_ragged_kernel_compiles_for_v5e(v5e, kv_dtype):
+    t, s = CHUNK + SLOTS, SLOTS + 1
+
+    def fn(q, pt, starts, lens, kv_lens, kp, vp, *rest):
+        return ragged_paged_attention(q, kp, vp, pt, starts, lens, kv_lens,
+                                      use_kernel=True, **_scales(rest))
+
+    _compile(fn, v5e, ((t, HEADS, HEAD_DIM), "float32"),
+             ((s, MAX_PAGES), "int32"), ((s,), "int32"), ((s,), "int32"),
+             ((s,), "int32"), *_pool_operands(kv_dtype))
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+def test_decode_kernel_compiles_for_v5e(v5e, kv_dtype):
+    def fn(q, pt, seq_lens, kp, vp, *rest):
+        return paged_decode_attention(q, kp, vp, pt, seq_lens,
+                                      use_kernel=True, **_scales(rest))
+
+    _compile(fn, v5e, ((SLOTS, HEADS, HEAD_DIM), "float32"),
+             ((SLOTS, MAX_PAGES), "int32"), ((SLOTS,), "int32"),
+             *_pool_operands(kv_dtype))
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+def test_chunk_kernel_compiles_for_v5e(v5e, kv_dtype):
+    def fn(q, pt, start, kp, vp, *rest):
+        return chunk_prefill_attention(q, kp, vp, pt, start,
+                                       use_kernel=True, **_scales(rest))
+
+    _compile(fn, v5e, ((CHUNK, HEADS, HEAD_DIM), "float32"),
+             ((MAX_PAGES,), "int32"), ((), "int32"),
+             *_pool_operands(kv_dtype))
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 12, 512, 64), "bfloat16"),     # GPT-2 small, bench.py's batch
+    ((2, 8, 1024, 128), "float32"),
+])
+def test_flash_fwd_bwd_compiles_for_v5e(v5e, shape, dtype):
+    b, h, l, d = shape
+
+    def loss(q, k, v):
+        km = jnp.zeros((1, 1, l), jnp.float32)
+        out = fa._flash(q, k, v, km, True, h, False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    flat = ((b * h, l, d), dtype)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e, flat, flat, flat)
+
+
+def test_a_tpu_lowering_has_no_cost_analysis(v5e):
+    """Why bench._measured_flops and every cost_analysis() caller must
+    take None: on this backend jax 0.9.0 analyses the compiled
+    executable only."""
+    struct = jax.ShapeDtypeStruct((256, 256), np.float32, sharding=v5e)
+    lowered = jax.jit(jnp.matmul).lower(struct, struct)
+    assert lowered.cost_analysis() is None
+    assert lowered.compile().cost_analysis()["flops"] > 0

@@ -1,7 +1,6 @@
 """Driver-contract tests: import __graft_entry__ and call it the way the
-driver does (VERDICT r1 weak-10: both round-1 driver artifacts failed and
-nothing in-repo would have caught it).  Also runs bench.py as a subprocess
-and asserts the single-JSON-line contract."""
+driver does, and run the two chip programs (bench.py, chip_smoke.py) where
+there is no chip."""
 import json
 import os
 import subprocess
@@ -58,36 +57,16 @@ def test_dryrun_multichip_subprocess_from_clean_env():
         assert ok, f"leg {leg} failed: {proc.stdout}"
 
 
-@pytest.mark.slow   # subprocess-runs the WHOLE bench.py (~7 min on
-# one core, forced CPU) — a soak by the conftest slow-lane convention;
-# the entry/dryrun contract tests above stay in tier-1
-def test_bench_prints_one_json_line():
+@pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+def test_chip_programs_refuse_the_cpu(script):
+    """bench.py and chip_smoke.py measure and prove the chip: with no
+    accelerator they exit non-zero and print no result, whatever the
+    backend could have computed."""
     env = dict(os.environ)
-    env["PTN_BENCH_FORCE_CPU"] = "1"  # tests never touch the real chip
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=REPO,
-                          capture_output=True, text=True, timeout=900,
-                          env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [ln for ln in proc.stdout.strip().splitlines() if ln.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    for k in ("metric", "value", "unit", "vs_baseline"):
-        assert k in rec
-    assert rec["value"] > 0, rec
-
-
-@pytest.mark.slow   # same full-bench.py subprocess soak as above
-def test_bench_survives_poisoned_backend():
-    """JAX_PLATFORMS pointing at a nonexistent platform must still yield a
-    JSON line (the round-1 rc=1 scenario)."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "nonexistent_backend"
-    env["PTN_BENCH_PROBE_TIMEOUT"] = "60"  # sacrificial probe, fail fast
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=REPO,
-                          capture_output=True, text=True, timeout=900,
-                          env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = [ln for ln in proc.stdout.strip().splitlines() if ln.strip()]
-    assert len(lines) == 1, proc.stdout
-    rec = json.loads(lines[0])
-    assert rec["value"] > 0, rec  # CPU fallback must produce a real number
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, script], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0, proc.stdout
+    assert "no accelerator" in proc.stderr, proc.stderr[-2000:]
+    for ln in proc.stdout.splitlines():
+        assert not ln.lstrip().startswith("{"), proc.stdout

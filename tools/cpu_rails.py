@@ -1,7 +1,6 @@
 """CPU perf rails: committed numbers that catch regressions without TPU.
 
-VERDICT r2 #6: when the TPU pool is down, the only perf signal the
-project has must live in-repo.  This tool measures (a) the op_bench
+A regression signal that needs no chip.  This tool measures (a) the op_bench
 jitted-op latencies and (b) compile-time rails — time-to-first-step for
 12-layer BERT/GPT CompiledTrainSteps, scan_layers on vs off (the
 scan-vs-unrolled compile claim in docs/PERF.md) — and writes
@@ -20,14 +19,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-
-def _force_cpu():
-    """Standalone runs force CPU before first backend init; under pytest
-    the conftest already did (import-time config flips would be
-    ineffective or would hijack later tests in the same process)."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 RAILS_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -106,7 +97,7 @@ def measure_compile():
 def main():
     import datetime
 
-    _force_cpu()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # these rails are the CPU's
     import jax
 
     rails = {

@@ -5,8 +5,7 @@ paddle_tpu.generation engine (BENCH-style JSON to stdout).
 Measures the paged-KV continuous-batching decode loop end to end —
 prefill, paged decode attention (Pallas kernel on TPU, jnp reference on
 CPU), sampling, scheduling — with the `generation.*` StatRegistry
-snapshot embedded in the artifact (the stats_snapshot() export), so a
-TPU-window run leaves the same evidence trail as BENCH_TPU_SESSION.json.
+snapshot embedded in the artifact (the stats_snapshot() export).
 
 Usage:
     python tools/gen_bench.py                    # default grid
@@ -96,12 +95,6 @@ import numpy as np
 
 # runnable as `python tools/gen_bench.py` from anywhere
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# honor JAX_PLATFORMS=cpu *before* backend init (see op_bench.py)
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 
 def _prewarm_decode_buckets(eng, batch, context, new_tokens, page_size):
@@ -1407,7 +1400,7 @@ def main():
 
     # a multi-device CPU mesh needs forced host devices, and the flag
     # must land before the backend initializes (no devices have been
-    # touched yet — the top-of-module import only sets jax_platforms)
+    # touched yet)
     if (args.mesh != "1" and os.environ.get("JAX_PLATFORMS") == "cpu"
             and "xla_force_host_platform_device_count"
             not in os.environ.get("XLA_FLAGS", "")):
@@ -1419,7 +1412,9 @@ def main():
 
     from paddle_tpu import generation as g
     from paddle_tpu.profiler.monitor import StatRegistry
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     batches = [int(b) for b in args.batches.split(",")]
     contexts = [int(c) for c in args.contexts.split(",")]
     long_ctx = args.long_context or max(contexts) * 8

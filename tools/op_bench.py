@@ -22,13 +22,6 @@ import numpy as np
 # runnable as `python tools/op_bench.py` from anywhere
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# honor JAX_PLATFORMS=cpu *before* backend init: the env var alone does not
-# override an installed TPU plugin's platform selection
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
-
 
 DEFAULT_SUITE = [
     {"op": "matmul", "shapes": [[1024, 1024], [1024, 1024]], "repeat": 30},
@@ -149,6 +142,9 @@ def main():
             suite = json.load(f)
     else:
         suite = DEFAULT_SUITE
+    from paddle_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for cfg in suite:
         print(json.dumps(bench_one(cfg)))
 
