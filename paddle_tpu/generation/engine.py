@@ -67,11 +67,12 @@ import concurrent.futures
 
 import numpy as np
 
+from ..profiler import RecordEvent
 from ..serving.admission import RequestTooLargeError, ServingError
 from ..serving.bucketing import CompiledModelCache, ShapeBucketer
 from .decode_attention import paged_decode_attention
 from .kv_cache import DeviceKVPool, OutOfPagesError, PagedKVCache
-from .metrics import GenerationMetrics, StepTimer
+from .metrics import GenerationMetrics
 from .sampling import SamplingParams, sample_token, sample_tokens_batch
 from .scheduler import (ContinuousBatchingScheduler, GenerationRequest,
                         SequenceState)
@@ -430,6 +431,17 @@ class GenerationHandle:
         # (0 = cold, None = not admitted yet): the per-request warm/cold
         # signal the serving tier (and future SLO routing) reads
         self.prefix_hit_tokens = None
+        # the request's own timeline, beside the two probes above (same
+        # clock, first writer wins): admitted_s when the scheduler
+        # first gave it a slot (a preempted sequence's re-admission
+        # does not move it), finished_s when the engine retired it (a
+        # request that fails has none), prefill_chunks the ragged
+        # dispatches that carried a chunk of its prompt, seq_id the
+        # scheduler's id — what a traced step's `seqs` attribute lists
+        self.admitted_s = None
+        self.finished_s = None
+        self.prefill_chunks = 0
+        self.seq_id = None
         # authoritative delivered-token count: every fleet remigration
         # reads it as the replay-skip FLOOR, so no race in transport
         # ledger bookkeeping can ever replay a token this handle
@@ -868,7 +880,9 @@ class GenerationEngine:
         `handle` lets a CALLER supply the handle object the engine
         drives (anything duck-typing the engine-side surface:
         _push_token/_finish/set_exception/done plus the submitted_s /
-        first_token_s / prefix_hit_tokens attributes) — the hook the
+        first_token_s / prefix_hit_tokens attributes; the engine also
+        sets admitted_s / finished_s / prefill_chunks / seq_id on it,
+        so a handle with __slots__ lists those too) — the hook the
         fleet tier uses so one client-held handle can survive a
         drain-migration cold resubmit on a sibling replica
         (serving/fleet.py).  submitted_s is stamped only when unset, so
@@ -1343,8 +1357,6 @@ class GenerationEngine:
         return out
 
     def _step_locked(self):
-        from ..profiler import RecordEvent
-
         if self._ragged is not None:
             return self._step_ragged()
         if self.prefill_chunk_tokens:
@@ -1360,13 +1372,12 @@ class GenerationEngine:
             self._drain_kv_bytes()
             self._observe_occupancy()
             return 0
-        with StepTimer() as timer:
-            with RecordEvent("generation::decode_step"):
-                active = self._ensure_step_capacity()
-                if not active:
-                    return 0
-                self._decode_batch(active)
-        self.metrics.observe_step(len(active), timer.seconds)
+        with RecordEvent("generation::decode_step"):
+            active = self._ensure_step_capacity()
+            if not active:
+                return 0
+            self._decode_batch(active)
+        self.metrics.observe_step()
         self._observe_step_rows(len(active))
         self._drain_kv_bytes()
         self._observe_occupancy()
@@ -1415,8 +1426,6 @@ class GenerationEngine:
         step put both in ONE dispatch; the legacy path simply runs
         everything (decode never stalls), and the budget only sizes
         the pack so short prompts stop queueing behind long ones."""
-        from ..profiler import RecordEvent
-
         self.scheduler.admit(limit=self.config.max_prefill_batch)
         self._reap_deadlines()
         # the budget sizes the PACK, never the oldest prompt's chunk:
@@ -1449,13 +1458,12 @@ class GenerationEngine:
                     chunk_syncs += 1  # final chunk: logits materialized
         decoding = self.scheduler.decode_ready()
         if decoding:
-            with StepTimer() as timer:
-                with RecordEvent("generation::decode_step"):
-                    decoding = self._ensure_step_capacity()
-                    if decoding:
-                        self._decode_batch(decoding)
+            with RecordEvent("generation::decode_step"):
+                decoding = self._ensure_step_capacity()
+                if decoding:
+                    self._decode_batch(decoding)
             if decoding:
-                self.metrics.observe_step(len(decoding), timer.seconds)
+                self.metrics.observe_step()
                 advanced += len(decoding)
         if chunk_dispatched:
             # the step really issued EXTRA device programs (one per
@@ -1489,8 +1497,48 @@ class GenerationEngine:
         a YOUNGER pack member, which then drops out of the pack), then
         the decode capacity check (which may preempt chunkers — their
         freed rows drop out of the pack)."""
-        from ..profiler import RecordEvent
+        with RecordEvent("generation::schedule"):
+            decoding, pack, spec_plan = self._plan_ragged()
+        if not decoding and not pack:
+            self._account_step()
+            return 0
+        # the host-free loop takes DECODE-ONLY boundaries (no chunk in
+        # the pack) whose every row fits the loop's static caps; a page
+        # shortfall inside _dispatch_loop rolls back and falls through
+        # to the single-step dispatch — the loop is an optimization,
+        # never a new failure source
+        if (self._loop is not None and decoding and not pack
+                and self._loop_ready(decoding)):
+            with RecordEvent("generation::loop_step"):
+                looped = self._dispatch_loop(decoding, spec_plan)
+            if looped is not None:
+                advanced, sampled = looped
+                if sampled:
+                    self.metrics.observe_step()
+                self._account_step()
+                return advanced
+        # the operator's question of a slow step: which requests rode
+        # it (handle.seq_id), and on which executable
+        with RecordEvent(
+                "generation::ragged_step", step=self._step_seq,
+                decode=len(decoding), chunk=len(pack),
+                pages=lambda: self._ragged.last_pages_bucket,
+                seqs=lambda: "/".join(
+                    str(s.seq_id)
+                    for s in decoding + [c[0] for c in pack])):
+            advanced, sampled = self._dispatch_ragged(
+                decoding, pack, spec_plan)
+        if sampled:
+            self.metrics.observe_step()
+        self._account_step()
+        return advanced
 
+    def _plan_ragged(self):
+        """Everything a ragged step decides before it packs: admission,
+        deadlines, the chunk pack and the drafts, their page
+        reservations and the decode capacity check.  Returns
+        ``(decoding, pack, spec_plan)`` with pack as
+        ``[(state, n, start)]``: reserved, still-alive chunks."""
         admitted = self.scheduler.admit(limit=self.config.max_prefill_batch)
         if not self.prefill_chunk_tokens:
             # no chunking: prompts take the one-shot prefill paths and
@@ -1519,7 +1567,7 @@ class GenerationEngine:
                 room=(self.step_token_budget
                       - len(self.scheduler.decode_ready())
                       - sum(n for _, n in planned)))
-        pack = []  # [(state, n, start)] — reserved, still-alive chunks
+        pack = []
         for state, n in planned:
             if state.slot is None or not state.prefilling:
                 continue  # preempted by an earlier pack reservation
@@ -1535,36 +1583,14 @@ class GenerationEngine:
         # out of the pack here
         pack = [(s, n, st) for s, n, st in pack
                 if s.slot is not None and s.prefilling]
-        if not decoding and not pack:
+        return decoding, pack, spec_plan
+
+    def _account_step(self):
+        """A ragged step's closing counters, under their own span so
+        that what the accounting costs is itself visible."""
+        with RecordEvent("generation::account"):
             self._drain_kv_bytes()
             self._observe_occupancy()
-            return 0
-        # the host-free loop takes DECODE-ONLY boundaries (no chunk in
-        # the pack) whose every row fits the loop's static caps; a page
-        # shortfall inside _dispatch_loop rolls back and falls through
-        # to the single-step dispatch — the loop is an optimization,
-        # never a new failure source
-        if (self._loop is not None and decoding and not pack
-                and self._loop_ready(decoding)):
-            with StepTimer() as timer:
-                with RecordEvent("generation::loop_step"):
-                    looped = self._dispatch_loop(decoding, spec_plan)
-            if looped is not None:
-                advanced, sampled = looped
-                if sampled:
-                    self.metrics.observe_step(sampled, timer.seconds)
-                self._drain_kv_bytes()
-                self._observe_occupancy()
-                return advanced
-        with StepTimer() as timer:
-            with RecordEvent("generation::ragged_step"):
-                advanced, sampled = self._dispatch_ragged(
-                    decoding, pack, spec_plan)
-        if sampled:
-            self.metrics.observe_step(sampled, timer.seconds)
-        self._drain_kv_bytes()
-        self._observe_occupancy()
-        return advanced
 
     def _dispatch_ragged(self, decoding, pack, spec_plan=None):
         """Pack, dispatch, sample: the decode batch's spans first (slot
@@ -1575,6 +1601,84 @@ class GenerationEngine:
         Returns ``(advanced, sampled)`` — `sampled` counts TOKENS
         emitted (a speculating row retires accepted + 1 per step)."""
         b = len(decoding)
+        with RecordEvent("generation::pack"):
+            fixed, spec_rows = self._pack_ragged(decoding, pack, spec_plan)
+        with RecordEvent("generation::dispatch"):
+            ids_dev, logits_dev = self._ragged.dispatch(fixed)
+        # host work behind the device: hidden for as long as it is
+        # shorter than the kernel
+        with RecordEvent("generation::post_dispatch"):
+            self._ragged.count_kernel_cells(fixed)
+            # the scatter ran inside the dispatch; keep the O(tokens) write
+            # bound visible in kv_bytes_moved (comparable across paths)
+            self.cache.count_fused_append(self._ragged.last_rows_useful)
+            finishing = []  # [(state, descriptor index)]
+            for j, (state, n, start) in enumerate(pack):
+                state.prefill_pos += n
+                state.handle.prefill_chunks = getattr(
+                    state.handle, "prefill_chunks", 0) + 1
+                self.metrics.count_prefill(n)
+                self.metrics.count_chunk()
+                self._prewarm_decode(state)
+                if state.prefill_pos == len(state.tokens):
+                    state.prefilling = False
+                    self._register_prefix(state)
+                    finishing.append((state, b + j))
+            # samplers: every decode row, plus each packed chunk's last
+            # row when it just completed its prompt (those logits ARE
+            # the first-token logits), each with its descriptor index
+            samplers = [(s, i) for i, s in enumerate(decoding)] + finishing
+            greedy = all(s.request.params.greedy for s, _ in samplers)
+        # the single host sync — the wait for the device: the ids (with
+        # speculation the [S, 3] int block) of an all-greedy step, the
+        # logits (augmented [S, V + 3]) when any sampler is stochastic.
+        # A mid-prompt chunk-only step fetches NOTHING — zero host
+        # syncs, exactly like the legacy unmaterialized chunks.
+        fetched = None
+        if samplers:
+            with RecordEvent("generation::fetch"):
+                fetched = np.asarray(ids_dev if greedy else logits_dev)
+        with RecordEvent("generation::emit"):
+            if not samplers:
+                sampled = 0
+            elif self._spec is not None:
+                sampled = self._apply_ragged_spec(samplers, spec_rows, b,
+                                                  fetched, greedy)
+            else:
+                states = [s for s, _ in samplers]
+                picked = fetched[[di for _, di in samplers]]
+                if greedy:
+                    self._apply_tokens(states, picked)
+                else:
+                    self._apply_logits_batch(states, picked)
+                sampled = len(samplers)
+        with RecordEvent("generation::account"):
+            self.metrics.observe_decode_step(
+                self._ragged.last_dispatches, 1 if samplers else 0)
+            self.metrics.observe_collective_bytes(
+                self._ragged.last_collective_bytes)
+            # zero padded_token_waste by construction: descriptors
+            # cover exactly the packed rows; the fixed axis's inert
+            # slots are reported by step_row_utilization, not counted
+            # as dummy work
+            self.metrics.observe_step_rows(
+                self._ragged.last_rows_useful,
+                self._ragged.last_rows_dispatched, 0)
+            # the query-tiling FLOP proxy: score blocks this dispatch
+            # computed vs the untiled kernel's bill on the same
+            # descriptors, and the grid it was given
+            self.metrics.count_score_blocks(
+                self._ragged.last_score_blocks,
+                self._ragged.last_score_blocks_untiled,
+                self._ragged.last_grid_cells)
+        return b + len(pack), sampled
+
+    def _pack_ragged(self, decoding, pack, spec_plan):
+        """The host half of a ragged dispatch: reserve the decode rows
+        (and their drafts), lay every descriptor's rows on the packed
+        axis, and pad to the executable's fixed shapes.  Returns
+        ``(fixed, spec_rows)``: RaggedStep.pad's arguments and
+        ``{decode row: its drafts}``."""
         seq_ids, d_tokens, positions = self._reserve_decode_rows(decoding)
         # speculation: EXTEND a drafting row's reservation past its
         # guaranteed decode token.  The capacity check only vouched for
@@ -1638,89 +1742,29 @@ class GenerationEngine:
                                 lens)
         pages = pt[desc_of_row, pos_all // ps]
         rows = pos_all % ps
-        ids_dev, logits_dev = self._ragged.step(
+        fixed = self._ragged.pad(
             np.asarray(tokens, np.int32), pos_all, pages, rows, pt,
             starts, lens, kv_lens)
-        # the scatter ran inside the dispatch; keep the O(tokens) write
-        # bound visible in kv_bytes_moved (comparable across paths)
-        self.cache.count_fused_append(t_real)
-        finishing = []  # [(state, descriptor index)]
-        for j, (state, n, start) in enumerate(pack):
-            state.prefill_pos += n
-            self.metrics.count_prefill(n)
-            self.metrics.count_chunk()
-            self._prewarm_decode(state)
-            if state.prefill_pos == len(state.tokens):
-                state.prefilling = False
-                self._register_prefix(state)
-                finishing.append((state, b + j))
-        # samplers: every decode row, plus each packed chunk's last row
-        # when it just completed its prompt (those logits ARE the
-        # first-token logits).  A mid-prompt chunk-only step fetches
-        # NOTHING — zero host syncs, exactly like the legacy
-        # unmaterialized chunks.
-        if self._spec is not None:
-            sampled, syncs = self._apply_ragged_spec(
-                decoding, spec_rows, finishing, ids_dev, logits_dev)
-        else:
-            samplers = list(decoding)
-            rows_idx = list(range(b))
-            for state, di in finishing:
-                samplers.append(state)
-                rows_idx.append(di)
-            syncs = 0
-            if samplers:
-                syncs = 1
-                if all(s.request.params.greedy for s in samplers):
-                    ids_h = np.asarray(ids_dev)  # the single host sync
-                    self._apply_tokens(samplers, ids_h[rows_idx])
-                else:
-                    logits_h = np.asarray(logits_dev)
-                    self._apply_logits_batch(samplers,
-                                             logits_h[rows_idx])
-            sampled = len(samplers)
-        self.metrics.observe_decode_step(self._ragged.last_dispatches,
-                                         syncs)
-        self.metrics.observe_collective_bytes(
-            self._ragged.last_collective_bytes)
-        # zero padded_token_waste by construction: descriptors cover
-        # exactly the packed rows; the fixed axis's inert slots are
-        # reported by step_row_utilization, not counted as dummy work
-        self.metrics.observe_step_rows(self._ragged.last_rows_useful,
-                                       self._ragged.last_rows_dispatched,
-                                       0)
-        # the query-tiling FLOP proxy: score blocks this dispatch
-        # computed vs the untiled kernel's bill on the same descriptors
-        self.metrics.count_score_blocks(
-            self._ragged.last_score_blocks,
-            self._ragged.last_score_blocks_untiled)
-        return b + len(pack), sampled
+        return fixed, spec_rows
 
-    def _apply_ragged_spec(self, decoding, spec_rows, finishing,
-                           ints_dev, aug_dev):
-        """The speculative step's sampling half — still ONE host fetch:
-        the [S, 3] int block (last-row argmax, accepted count, bonus)
-        for an all-greedy step, the [S, V + 3] augmented logits when
-        any sampler is stochastic.  Then per descriptor exactly one of:
-        accepted drafts + bonus (speculating rows), the last-row argmax
-        (plain greedy rows and finishing greedy chunks), or batched
-        host sampling from the logits columns (stochastic rows).
-        Returns ``(tokens_emitted, syncs)``."""
-        b = len(decoding)
-        samplers = [(s, i) for i, s in enumerate(decoding)]
-        samplers += list(finishing)
-        if not samplers:
-            return 0, 0
-        vocab = int(self.model.vocab_size)
-        if all(s.request.params.greedy for s, _ in samplers):
-            ints = np.asarray(ints_dev)          # the single host sync
-            logits_h = None
+    def _apply_ragged_spec(self, samplers, spec_rows, b, fetched, greedy):
+        """The speculative step's sampling half, over its ONE host
+        fetch: the [S, 3] int block (last-row argmax, accepted count,
+        bonus) of an all-greedy step, the [S, V + 3] augmented logits
+        when any sampler is stochastic.  Per ``(state, descriptor)``
+        sampler exactly one of: accepted drafts + bonus (speculating
+        rows, the first `b` descriptors), the last-row argmax (plain
+        greedy rows and finishing greedy chunks), or batched host
+        sampling from the logits columns (stochastic rows).  Returns
+        the tokens emitted."""
+        if greedy:
+            ints, logits_h = fetched, None
         else:
-            aug = np.asarray(aug_dev)            # the single host sync
-            logits_h = aug[:, :vocab]
+            vocab = int(self.model.vocab_size)
+            logits_h = fetched[:, :vocab]
             # the appended int columns are exact in f32 (ids < vocab,
             # accepted <= spec_tokens — both far under 2**24)
-            ints = aug[:, vocab:].astype(np.int64)
+            ints = fetched[:, vocab:].astype(np.int64)
         ids_col, acc_col, bonus_col = ints[:, 0], ints[:, 1], ints[:, 2]
         emitted = 0
         stoch = []   # (state, descriptor): one batched host sample
@@ -1741,7 +1785,7 @@ class GenerationEngine:
             self._apply_logits_batch([s for s, _ in stoch],
                                      logits_h[[di for _, di in stoch]])
             emitted += len(stoch)
-        return emitted, 1
+        return emitted
 
     def _apply_spec_row(self, state, drafts, accepted, bonus):
         """Retire one speculating row's verified tokens.  The cache is
@@ -1988,8 +2032,6 @@ class GenerationEngine:
         (batch, length) bucket, one model call, scatter the K/V spans
         into the pool (padding positions are dropped, never written),
         and sample each sequence's first token from its own row."""
-        from ..profiler import RecordEvent
-
         ready = []
         for state in states:
             try:
@@ -2031,8 +2073,6 @@ class GenerationEngine:
                                  last_logits[:b_real])
 
     def _prefill(self, state):
-        from ..profiler import RecordEvent
-
         try:
             with RecordEvent("generation::prefill"):
                 tokens = np.asarray(state.tokens, np.int32)
@@ -2062,8 +2102,6 @@ class GenerationEngine:
         prefill runs, never what the sequence samples.  (The chunked
         engine mode never lands here: its chunk loop resumes at
         prefill_pos natively.)"""
-        from ..profiler import RecordEvent
-
         n = len(state.tokens) - state.prefill_pos
         try:
             # reserve may copy-on-write the clipped tail page (counted
@@ -2149,8 +2187,6 @@ class GenerationEngine:
         chunk sample the first token from the chunk's last-position
         logits (they ARE the next-token logits, exactly as in full
         prefill).  Returns True when the chunk ran."""
-        from ..profiler import RecordEvent
-
         start = self._reserve_chunk(state, n)
         if start is None:
             return False
@@ -2379,8 +2415,6 @@ class GenerationEngine:
         sequence when a stop condition fires (the per-row path: single
         prefill and one-off fallbacks; batches go through
         _apply_logits_batch)."""
-        from ..profiler import RecordEvent
-
         req = state.request
         if state.n_generated >= req.max_new_tokens:
             self._finish(state, "length")
@@ -2430,8 +2464,6 @@ class GenerationEngine:
         Greedy rows share ONE vectorized argmax (sample_tokens_batch);
         stochastic rows keep their per-request RNGs — token-identical
         to the per-row path by construction."""
-        from ..profiler import RecordEvent
-
         logits = np.asarray(logits)
         live = []
         for i, state in enumerate(states):
@@ -2466,7 +2498,10 @@ class GenerationEngine:
         result = GenerationResult(
             state.tokens[len(req.prompt):], reason, len(req.prompt),
             state.preemptions)
-        state.handle._finish(result)
+        handle = state.handle
+        if getattr(handle, "finished_s", None) is None:
+            handle.finished_s = time.monotonic()
+        handle._finish(result)
         self.metrics.count_finished()
 
     def _drain_kv_bytes(self):

@@ -571,6 +571,10 @@ class RaggedStep:
         # kernel would have, in the same [q_block, page_size] units
         self.last_score_blocks = 0
         self.last_score_blocks_untiled = 0
+        # ... and the grid the dispatch was given: descriptors x pages
+        # bucket x query tiles, the same units (0 off the kernel path)
+        self.last_grid_cells = 0
+        self.last_pages_bucket = 0
 
     @property
     def compile_count(self):
@@ -607,18 +611,14 @@ class RaggedStep:
         self._exec.get(args)
         return self._exec.compile_count > before
 
-    def step(self, tokens, positions, pages, rows, page_tables, starts,
-             lens, kv_lens):
-        """Dispatch one packed mixed-batch step.  All inputs are the
-        PACKED host arrays (the engine built them at exact sizes);
-        this pads the token axis to `max_tokens` with inert slots
-        (sentinel page, position 0), the descriptor axis to `max_seqs`
-        with len-0 descriptors, and the page-table axis to its pages
-        bucket — then runs the ONE donated dispatch.  Returns
-        ``(ids [S], logits [S, V])`` UNMATERIALIZED — or, with
-        spec_tokens, ``(ints [S, 3], logits_aug [S, V + 3])`` carrying
-        the accept/bonus columns (model.ragged_step_fn) — the caller
-        fetches at most one of them (its single host sync)."""
+    def pad(self, tokens, positions, pages, rows, page_tables, starts,
+            lens, kv_lens):
+        """The executable's eight fixed arguments from the PACKED host
+        arrays (the engine built them at exact sizes): the token axis
+        padded to `max_tokens` with inert slots (sentinel page,
+        position 0), the descriptor axis to `max_seqs` with len-0
+        descriptors, and the page-table axis to its pages bucket.  Host
+        work only — what `dispatch` takes."""
         t_real = len(tokens)
         s_real = len(starts)
         if t_real > self.max_tokens:
@@ -648,31 +648,50 @@ class RaggedStep:
         ln[:s_real] = lens
         kv = np.zeros((s,), np.int32)
         kv[:s_real] = kv_lens
-        state = self._cache.take_pool_state()
-        args = [tok, pos, pg, rw, pt, st, ln, kv,
-                *state, *self._param_leaves]
-        ids, logits = _dispatch_donating(
-            self._cache, self._exec, args, self._num_layers, n_out=2)
-        # the FLOP proxy mirrors the TILED KERNEL's skip rule — only
-        # meaningful (and only paid) when the kernel path actually
-        # dispatched; the jnp reference computes dense masked blocks,
-        # and reporting kernel skip statistics for it would make the
-        # gen_bench /ref-vs-/kernel score_blocks column path-blind
-        if self._use_kernel:
-            from ..ops.pallas.paged_attention import ragged_score_blocks
-
-            self.last_score_blocks, self.last_score_blocks_untiled = \
-                ragged_score_blocks(st, ln, kv, self._cache.page_size,
-                                    bucket_p, t)
-        else:
-            self.last_score_blocks = self.last_score_blocks_untiled = 0
-        self.last_dispatches = 1
+        self.last_pages_bucket = bucket_p
         self.last_rows_useful = t_real
         self.last_rows_dispatched = t
         self.last_collective_bytes = _collective_bytes_estimate(
             self._num_layers, t, self._d_model, self._tp,
             quantized=self._quant_collectives)
-        return ids, logits
+        return [tok, pos, pg, rw, pt, st, ln, kv]
+
+    def dispatch(self, fixed):
+        """The ONE donated dispatch of a step over `pad`'s arguments.
+        Returns ``(ids [S], logits [S, V])`` UNMATERIALIZED — or, with
+        spec_tokens, ``(ints [S, 3], logits_aug [S, V + 3])`` carrying
+        the accept/bonus columns (model.ragged_step_fn) — the caller
+        fetches at most one of them (its single host sync)."""
+        args = [*fixed, *self._cache.take_pool_state(),
+                *self._param_leaves]
+        out = _dispatch_donating(
+            self._cache, self._exec, args, self._num_layers, n_out=2)
+        self.last_dispatches = 1
+        return out
+
+    def count_kernel_cells(self, fixed):
+        """The dispatch's grid and the part of it that computes, per
+        head and layer, into last_grid_cells / last_score_blocks /
+        last_score_blocks_untiled.  The FLOP proxy mirrors the TILED
+        KERNEL's skip rule — only meaningful (and only paid) when the
+        kernel path actually dispatched; the jnp reference computes
+        dense masked blocks, and reporting kernel skip statistics for
+        it would make the gen_bench /ref-vs-/kernel score_blocks column
+        path-blind.  Host work the engine runs while the device does
+        the step."""
+        if not self._use_kernel:
+            return
+        from ..ops.pallas.paged_attention import (ragged_query_tiles,
+                                                  ragged_score_blocks)
+
+        st, ln, kv = fixed[5:]
+        bucket_p = self.last_pages_bucket
+        self.last_score_blocks, self.last_score_blocks_untiled = \
+            ragged_score_blocks(st, ln, kv, self._cache.page_size,
+                                bucket_p, self.max_tokens)
+        self.last_grid_cells = (
+            self.max_seqs * bucket_p
+            * ragged_query_tiles(self.max_tokens)[1])
 
 
 class LoopedRaggedStep:

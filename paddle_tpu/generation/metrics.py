@@ -80,7 +80,6 @@ Metric names:
                                       by the mid-prefill pre-warm path
                                       (the `prewarm` tag on
                                       decode_compiles_total)
-- ``generation.tokens_per_s``         gauge: decode throughput (EWMA)
 - ``generation.slot_occupancy_pct``   gauge: active / decode slots
 - ``generation.page_utilization_pct`` gauge: pool pages in use
 - ``generation.prefix_cache_hit_tokens``  prompt tokens served from the
@@ -133,6 +132,16 @@ Metric names:
                                       dispatches, in the same tile
                                       units — tiled < untiled is the
                                       measured out-of-span skip
+- ``generation.step_grid_cells``      (descriptor, page, query tile)
+                                      cells per head the ragged
+                                      kernel's GRID held, dispatch by
+                                      dispatch: max_seqs x pages bucket
+                                      x query tiles, whatever the batch
+                                      holds — the denominator of
+                                      step_score_blocks (same units,
+                                      same 0 on the jnp reference).
+                                      Over steps_total it is the mean
+                                      pages bucket a step took
 - ``generation.kv_quant_dtype``       gauge (string): the pool storage
                                       dtype ("float32" / "bfloat16" /
                                       "int8") stamped at engine build —
@@ -215,8 +224,6 @@ Metric names:
                                       latency-vs-waste cost of big N
                                       the gen_bench loop A/B watches
 """
-import time
-
 from ..profiler.monitor import StatRegistry
 
 PREFIX = "generation."
@@ -245,12 +252,12 @@ STEP_ROWS_DISPATCHED = PREFIX + "step_rows_dispatched"
 STEP_ROW_UTILIZATION = PREFIX + "step_row_utilization"
 PADDED_TOKEN_WASTE = PREFIX + "padded_token_waste"
 DECODE_COMPILES_PREWARM = PREFIX + "decode_compiles_prewarm"
-TOKENS_PER_S = PREFIX + "tokens_per_s"
 SLOT_OCCUPANCY_PCT = PREFIX + "slot_occupancy_pct"
 PAGE_UTILIZATION_PCT = PREFIX + "page_utilization_pct"
 KERNEL_PATH = PREFIX + "kernel_path"
 STEP_SCORE_BLOCKS = PREFIX + "step_score_blocks"
 STEP_SCORE_BLOCKS_UNTILED = PREFIX + "step_score_blocks_untiled"
+STEP_GRID_CELLS = PREFIX + "step_grid_cells"
 SPEC_MODE = PREFIX + "spec_mode"
 SPEC_PROPOSED_TOKENS = PREFIX + "spec_proposed_tokens"
 SPEC_ACCEPTED_TOKENS = PREFIX + "spec_accepted_tokens"
@@ -278,11 +285,8 @@ class GenerationMetrics:
     """Writes generation.* to the process StatRegistry (STAT_ADD
     parity: concurrent engines aggregate)."""
 
-    _EWMA = 0.3  # tokens/s smoothing: jittery host steps, stable gauge
-
     def __init__(self, registry=None):
         self._reg = registry or StatRegistry.instance()
-        self._rate = 0.0
         # prefix-cache hit-rate accumulators (per-engine: the gauge is
         # this engine's cumulative warm fraction, not a fleet mix)
         self._prefix_hit_cum = 0
@@ -424,13 +428,16 @@ class GenerationMetrics:
         path = "pallas" if use_kernel else "jnp-reference"
         self._stat(KERNEL_PATH).set(f"{mode}:{path}")
 
-    def count_score_blocks(self, tiled, untiled):
+    def count_score_blocks(self, tiled, untiled, grid_cells):
         """FLOP-proxy accounting for one ragged dispatch: score blocks
         the query-TILED kernel computes vs what the untiled kernel
-        would have (same units; ops/pallas ragged_score_blocks)."""
-        if untiled:
+        would have, and the cells of the grid it was given (same
+        units; ops/pallas ragged_score_blocks).  All 0 on the jnp
+        reference path."""
+        if grid_cells:
             self._stat(STEP_SCORE_BLOCKS).increase(int(tiled))
             self._stat(STEP_SCORE_BLOCKS_UNTILED).increase(int(untiled))
+            self._stat(STEP_GRID_CELLS).increase(int(grid_cells))
 
     def set_kv_quant_dtype(self, dtype_name):
         """Gauge (string): the KV pool storage dtype, stamped once at
@@ -545,15 +552,10 @@ class GenerationMetrics:
             self._stat(STEP_ROW_UTILIZATION).set(
                 round(useful / dispatched, 3))
 
-    def observe_step(self, tokens, step_seconds):
-        """One decode step that advanced `tokens` sequences (the token
+    def observe_step(self):
+        """One engine step that sampled at least one token (the token
         counter itself is kept by count_token at the sampling site)."""
         self._stat(STEPS_TOTAL).increase()
-        if step_seconds > 0:
-            inst = tokens / step_seconds
-            self._rate = (inst if self._rate == 0.0 else
-                          self._EWMA * inst + (1 - self._EWMA) * self._rate)
-            self._stat(TOKENS_PER_S).set(round(self._rate, 1))
 
     def observe_occupancy(self, active, slots, page_utilization):
         if slots:
@@ -584,14 +586,3 @@ class DecodeCacheMetrics:
     def count_compile(self):
         self._gm.count_decode_compile()
 
-
-class StepTimer:
-    """Tiny helper: `with StepTimer() as t: ...; t.seconds`."""
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self._t0
-        return False

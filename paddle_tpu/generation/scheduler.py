@@ -27,6 +27,7 @@ computed, never WHICH.
 """
 import collections
 import math
+import time
 
 from ..serving.admission import (AdmissionQueue, DeadlineExceededError,
                                  Request, RequestTooLargeError, ServingError)
@@ -263,6 +264,10 @@ class ContinuousBatchingScheduler:
             if s is None:
                 state.slot = i
                 self.slots[i] = state
+                # under which id this engine's steps list the request
+                # (a traced ragged_step's `seqs`); a live-migrated
+                # resident gets its new engine's here
+                state.handle.seq_id = state.seq_id
                 return
         raise AssertionError("no free slot (checked by caller)")
 
@@ -359,6 +364,10 @@ class ContinuousBatchingScheduler:
                                         match_tokens)
                 state.prefill_pos = match_tokens
             handle = state.handle
+            if getattr(handle, "admitted_s", None) is None:
+                # first admission only, like prefix_hit_tokens below: a
+                # preempted sequence's re-admission does not move it
+                handle.admitted_s = time.monotonic()
             if getattr(handle, "prefix_hit_tokens", 0) is None:
                 # first admission stamps the handle: the serving tier
                 # reads warm-vs-cold per request, not per re-admission

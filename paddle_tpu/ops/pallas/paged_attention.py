@@ -219,6 +219,16 @@ def _head_shard_map(body, mesh, tp_axis, layout, q, k_pool, v_pool,
     return fn(*args, *scalars)
 
 
+def ragged_query_tiles(n_rows, q_block=None):
+    """``(q_block, n_tiles)`` the ragged kernel cuts a packed axis of
+    `n_rows` rows into: its grid is heads x descriptors x pages x
+    n_tiles.  The ONE statement of the tiling rule — the kernel's grid,
+    the skip-rule mirror below and the engine's grid counter
+    (`generation.step_grid_cells`) all read it here."""
+    qb = max(1, min(int(q_block or RAGGED_Q_BLOCK), int(n_rows)))
+    return qb, -(-int(n_rows) // qb)
+
+
 def ragged_score_blocks(starts, lens, kv_lens, page_size, n_pages, n_rows,
                         q_block=RAGGED_Q_BLOCK):
     """Host-side mirror of the tiled ragged kernel's skip rule — the
@@ -232,8 +242,7 @@ def ragged_score_blocks(starts, lens, kv_lens, page_size, n_pages, n_rows,
     measured statement that out-of-span work was skipped."""
     import numpy as np
 
-    qb = max(1, min(int(q_block), int(n_rows)))
-    n_tiles = -(-int(n_rows) // qb)
+    qb, n_tiles = ragged_query_tiles(n_rows, q_block)
     ps = int(page_size)
     starts = np.asarray(starts, np.int64)
     lens = np.asarray(lens, np.int64)
@@ -528,8 +537,7 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
             scales=((k_scale, v_scale) if quantized else None))
     _reject_mesh_sharded_pool(k_pool)
     t, h, d = q.shape
-    qb = max(1, min(int(q_block or RAGGED_Q_BLOCK), t))
-    n_tiles = -(-t // qb)
+    qb, n_tiles = ragged_query_tiles(t, q_block)
     tpad = n_tiles * qb
     qs = jnp.transpose((q * scale).astype(q.dtype), (1, 0, 2))  # [H, T, D]
     if tpad != t:

@@ -22,7 +22,7 @@ import jax
 _state = threading.local()
 # name -> [count, total_s, min_s, max_s]
 _records = defaultdict(lambda: [0, 0.0, float("inf"), 0.0])
-_events = []  # (name, tid, start_s, dur_s) for chrome-trace export
+_events = []  # (name, tid, start_s, dur_s, args) for chrome-trace export
 _MAX_EVENTS = 200_000
 _enabled = [False]
 _trace_dir = [None]
@@ -30,10 +30,19 @@ _t_origin = [0.0]
 
 
 class RecordEvent:
-    """RAII span (platform/profiler.h RecordEvent parity)."""
+    """RAII span (platform/profiler.h RecordEvent parity).
 
-    def __init__(self, name, event_type=None):
+    Keyword attributes describe the span: with the profiler on they
+    become stats of its TraceAnnotation (so they sit beside the span in
+    the device's trace) and the `args` of the chrome-trace export.  They
+    are read as the span CLOSES, so a callable value can say what the
+    span found out (the pages bucket a dispatch took); it is called
+    then and only then.  With the profiler off `begin`/`end` are one
+    list read each and no attribute is evaluated."""
+
+    def __init__(self, name, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
         self._t0 = None
         self._jax_ctx = None
 
@@ -49,7 +58,16 @@ class RecordEvent:
 
     def end(self):
         if self._t0 is not None:
-            dt = time.perf_counter() - self._t0
+            t0, self._t0 = self._t0, None
+            dt = time.perf_counter() - t0
+            args = None
+            try:
+                if self._attrs:
+                    args = {k: v() if callable(v) else v
+                            for k, v in self._attrs.items()}
+                    self._jax_ctx.set_metadata(**args)
+            finally:
+                self._jax_ctx.__exit__(None, None, None)
             rec = _records[self.name]
             rec[0] += 1
             rec[1] += dt
@@ -57,10 +75,7 @@ class RecordEvent:
             rec[3] = max(rec[3], dt)
             if len(_events) < _MAX_EVENTS:
                 _events.append((self.name, threading.get_ident(),
-                                self._t0 - _t_origin[0], dt))
-            if self._jax_ctx is not None:
-                self._jax_ctx.__exit__(None, None, None)
-            self._t0 = None
+                                t0 - _t_origin[0], dt, args))
 
     def __exit__(self, *exc):
         self.end()
@@ -135,8 +150,8 @@ def export_chrome_trace(path):
         "traceEvents": [
             {"name": name, "ph": "X", "pid": os.getpid(), "tid": tid,
              "ts": round(start * 1e6, 3), "dur": round(dur * 1e6, 3),
-             "cat": "host"}
-            for name, tid, start, dur in _events
+             "cat": "host", **({"args": args} if args else {})}
+            for name, tid, start, dur, args in _events
         ],
         "displayTimeUnit": "ms",
     }

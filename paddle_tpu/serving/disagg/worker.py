@@ -44,7 +44,8 @@ class _StreamHandle:
     client's GenerationHandle from these frames."""
 
     __slots__ = ("sid", "_send_event", "submitted_s", "first_token_s",
-                 "prefix_hit_tokens", "_done", "_n")
+                 "prefix_hit_tokens", "admitted_s", "finished_s",
+                 "prefill_chunks", "seq_id", "_done", "_n")
 
     def __init__(self, sid, send_event):
         self.sid = sid
@@ -52,6 +53,12 @@ class _StreamHandle:
         self.submitted_s = None
         self.first_token_s = None
         self.prefix_hit_tokens = None
+        # the engine's timeline stamps stay in this process: its clock
+        # is not the parent's
+        self.admitted_s = None
+        self.finished_s = None
+        self.prefill_chunks = 0
+        self.seq_id = None
         self._done = False
         self._n = 0   # per-stream event index: the parent dedups
         # duplicated frames and detects holes from dropped ones

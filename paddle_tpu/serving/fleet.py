@@ -483,6 +483,18 @@ class ReplicaSpec:
         self.port = None if port is None else int(port)
 
 
+def _client_stamp(name, default=None):
+    """A _MigrationRelay attribute that lives on the client's handle
+    (which, duck-typed, may not have it yet)."""
+    def get(self):
+        return getattr(self._client, name, default)
+
+    def put(self, value):
+        setattr(self._client, name, value)
+
+    return property(get, put)
+
+
 class _MigrationRelay:
     """Engine-side handle adapter for a drain-migrated request.
 
@@ -493,7 +505,9 @@ class _MigrationRelay:
     forwards the rest into the client's untouched handle — the client
     observes one continuous, gap-free, duplicate-free stream.  TTFT
     probes and the prefix_hit_tokens stamp stay the CLIENT handle's:
-    first admission wins, exactly as for preemption re-admission."""
+    first admission wins, exactly as for preemption re-admission.  The
+    engine's other stamps (admitted_s, finished_s, prefill_chunks,
+    seq_id) are the client handle's too, read and written through."""
 
     __slots__ = ("_client", "_skip", "_skip0", "_pushed", "submitted_s",
                  "first_token_s")
@@ -506,13 +520,11 @@ class _MigrationRelay:
         self.submitted_s = None      # own clock; client keeps original
         self.first_token_s = None
 
-    @property
-    def prefix_hit_tokens(self):
-        return self._client.prefix_hit_tokens
-
-    @prefix_hit_tokens.setter
-    def prefix_hit_tokens(self, v):
-        self._client.prefix_hit_tokens = v
+    prefix_hit_tokens = _client_stamp("prefix_hit_tokens")
+    admitted_s = _client_stamp("admitted_s")
+    finished_s = _client_stamp("finished_s")
+    prefill_chunks = _client_stamp("prefill_chunks", 0)
+    seq_id = _client_stamp("seq_id")
 
     def client_and_delivered(self):
         """(client handle, stream tokens the client has received) — the
@@ -551,7 +563,7 @@ class _Replica:
     here — plus the admission state the router flips and the measured
     TTFT EWMA the latency-aware load score folds in."""
 
-    _TTFT_EWMA_ALPHA = 0.3   # same smoothing as generation.tokens_per_s
+    _TTFT_EWMA_ALPHA = 0.3   # jittery samples, stable load signal
     _TTFT_LOAD_CAP = 4.0     # a slow replica weighs at most like this
     # many queued requests: bounded back-pressure, never starvation
 
