@@ -214,17 +214,35 @@ def ragged_paged_attention_reference(q, k_pool, v_pool, page_tables,
     return jnp.einsum("sthk,skhd->thd", weights, v)
 
 
+def ragged_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows,
+                     use_kernel=None):
+    """What `ragged_paged_attention(work=...)` takes: the Pallas
+    kernel's grid for these descriptors (ops/pallas `ragged_work_list`,
+    pure jnp, loop-body safe), for a caller that attends layer after
+    layer over the same descriptors and builds it ONCE.  None where the
+    jnp reference runs: it has no grid."""
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    if not use_kernel:
+        return None
+    from ..ops.pallas.paged_attention import ragged_work_list as build
+
+    return build(page_tables, starts, lens, kv_lens, page_size, n_rows)
+
+
 def ragged_paged_attention(q, k_pool, v_pool, page_tables, starts, lens,
                            kv_lens, scale=None, use_kernel=None,
                            interpret=None, layout="token", mesh=None,
-                           tp_axis=None, k_scale=None, v_scale=None):
+                           tp_axis=None, k_scale=None, v_scale=None,
+                           work=None):
     """Dispatch for the ragged mixed-batch path: the Pallas kernel on
     TPU (or when forced), the jnp gather reference elsewhere — the
     exact contract of paged_decode_attention, grown from one query row
     per sequence to a ragged run of rows per descriptor.  `mesh`/
     `tp_axis` run the kernel as a shard_map over the head-sharded mesh
     (the reference path ignores them — GSPMD partitions it on its
-    own).
+    own).  `work` is `ragged_work_list` of the same descriptors (the
+    kernel builds its own when None; the reference ignores it).
 
     LOOP-BODY SAFE (the host-free decode loop's protocol,
     model.ragged_loop_fn): both paths are pure functions of their
@@ -265,7 +283,7 @@ def ragged_paged_attention(q, k_pool, v_pool, page_tables, starts, lens,
         jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
         page_tables, starts, lens, kv_lens, scale, interpret=interpret,
         layout=layout, mesh=mesh, tp_axis=tp_axis, k_scale=k_scale,
-        v_scale=v_scale)
+        v_scale=v_scale, work=work)
 
 
 def chunk_prefill_attention_reference(q, k, v, start, scale=None):

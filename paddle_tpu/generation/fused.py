@@ -571,8 +571,8 @@ class RaggedStep:
         # kernel would have, in the same [q_block, page_size] units
         self.last_score_blocks = 0
         self.last_score_blocks_untiled = 0
-        # ... and the grid the dispatch was given: descriptors x pages
-        # bucket x query tiles, the same units (0 off the kernel path)
+        # ... and the steps the kernel's grid walked for them (ops/pallas
+        # ragged_grid_cells), the same units (0 off the kernel path)
         self.last_grid_cells = 0
         self.last_pages_bucket = 0
 
@@ -681,7 +681,7 @@ class RaggedStep:
         the step."""
         if not self._use_kernel:
             return
-        from ..ops.pallas.paged_attention import (ragged_query_tiles,
+        from ..ops.pallas.paged_attention import (ragged_grid_cells,
                                                   ragged_score_blocks)
 
         st, ln, kv = fixed[5:]
@@ -689,9 +689,9 @@ class RaggedStep:
         self.last_score_blocks, self.last_score_blocks_untiled = \
             ragged_score_blocks(st, ln, kv, self._cache.page_size,
                                 bucket_p, self.max_tokens)
-        self.last_grid_cells = (
-            self.max_seqs * bucket_p
-            * ragged_query_tiles(self.max_tokens)[1])
+        self.last_grid_cells = ragged_grid_cells(
+            self.max_seqs, bucket_p, self.max_tokens,
+            live=self.last_score_blocks)
 
 
 class LoopedRaggedStep:

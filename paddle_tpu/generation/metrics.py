@@ -132,16 +132,19 @@ Metric names:
                                       dispatches, in the same tile
                                       units — tiled < untiled is the
                                       measured out-of-span skip
-- ``generation.step_grid_cells``      (descriptor, page, query tile)
-                                      cells per head the ragged
-                                      kernel's GRID held, dispatch by
-                                      dispatch: max_seqs x pages bucket
-                                      x query tiles, whatever the batch
-                                      holds — the denominator of
-                                      step_score_blocks (same units,
-                                      same 0 on the jnp reference).
-                                      Over steps_total it is the mean
-                                      pages bucket a step took
+- ``generation.step_grid_cells``      grid steps per head the ragged
+                                      kernel WALKED, dispatch by
+                                      dispatch (ops/pallas
+                                      ragged_grid_cells): its grid is a
+                                      compacted list of the live
+                                      (descriptor, page, query tile)
+                                      cells under a traced bound, so
+                                      this is step_score_blocks held to
+                                      [1, the list's capacity] — the
+                                      denominator of step_score_blocks
+                                      (same units, same 0 on the jnp
+                                      reference); a ratio under 1 is
+                                      steps that computed nothing
 - ``generation.kv_quant_dtype``       gauge (string): the pool storage
                                       dtype ("float32" / "bfloat16" /
                                       "int8") stamped at engine build —
@@ -431,9 +434,9 @@ class GenerationMetrics:
     def count_score_blocks(self, tiled, untiled, grid_cells):
         """FLOP-proxy accounting for one ragged dispatch: score blocks
         the query-TILED kernel computes vs what the untiled kernel
-        would have, and the cells of the grid it was given (same
-        units; ops/pallas ragged_score_blocks).  All 0 on the jnp
-        reference path."""
+        would have, and the steps its grid walked (same units;
+        ops/pallas ragged_score_blocks, ragged_grid_cells).  All 0 on
+        the jnp reference path."""
         if grid_cells:
             self._stat(STEP_SCORE_BLOCKS).increase(int(tiled))
             self._stat(STEP_SCORE_BLOCKS_UNTILED).increase(int(untiled))

@@ -585,6 +585,13 @@ class TinyCausalLM:
             # construction); their K/V rides the sentinel page and their
             # attention rows belong to no descriptor (exact zeros)
             x = params["tok_emb"][tokens] + params["pos_emb"][positions]
+            # the kernel's grid follows the descriptors, not the layer:
+            # built once here (once an iteration inside the host-free
+            # loop, whose descriptors change), shared by every layer
+            work = decode_attention.ragged_work_list(
+                pt, starts, lens, kv_lens,
+                k_pools[0].shape[2 if pool_layout == "kernel" else 1], t,
+                use_kernel=use_kernel)
             k_out, v_out, ks_out, vs_out = [], [], [], []
             for li, blk in enumerate(params["blocks"]):
                 hn = _layer_norm(x, blk["ln1_s"], blk["ln1_b"])
@@ -620,7 +627,8 @@ class TinyCausalLM:
                 attn = decode_attention.ragged_paged_attention(
                     q, kp, vp, pt, starts, lens, kv_lens,
                     use_kernel=use_kernel, layout=pool_layout,
-                    mesh=mesh, tp_axis=tp_axis, k_scale=ks, v_scale=vs)
+                    mesh=mesh, tp_axis=tp_axis, k_scale=ks, v_scale=vs,
+                    work=work)
                 x = x + rowmm(attn.reshape(t, self.d_model), blk["wo"])
                 x = x + self._mlp_rowmm(
                     blk, _layer_norm(x, blk["ln2_s"], blk["ln2_b"]),

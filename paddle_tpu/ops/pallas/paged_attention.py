@@ -1,23 +1,47 @@
-"""Paged decode attention (Lq == 1) as a Pallas TPU kernel.
+"""Paged attention over a paged KV cache as Pallas TPU kernels.
 
-The decode-side counterpart of flash_attention.py: one query token per
-sequence attends over a paged KV cache (Ragged Paged Attention, arxiv
-2604.15464).  The kernel never materializes a per-sequence contiguous KV
-copy — the page table rides in as a scalar-prefetch operand and the
-BlockSpec index_map DMAs each sequence's pages straight out of the pool:
+The decode-side counterpart of flash_attention.py (Ragged Paged
+Attention, arxiv 2604.15464).  No kernel here materializes a
+per-sequence contiguous KV copy — page numbers ride in as scalar-prefetch
+operands and the BlockSpec index_map DMAs each sequence's pages straight
+out of the pool.  Three kernels:
+
+DECODE (`paged_decode_attention_kernel`, Lq == 1) and CHUNK
+(`chunk_prefill_attention_kernel`, one sequence's prefill chunk), the
+legacy step modes' pair — dense grids over the page table:
 
     grid = (B, H, max_pages)          # pages innermost, sequential
     k block = pool_t[h, page_table[b, i]]       # [1, 1, page_size, D]
 
+Pages past a sequence's length are skipped via @pl.when on the
+prefetched seq_lens; the page table pads unused slots with page 0, which
+is always a valid DMA target.
+
+RAGGED (`ragged_paged_attention_kernel`), the serving engine's one
+kernel: decode rows, prefill chunks and speculative verify runs in one
+packed token axis under ``[start, len, kv_len]`` descriptors.  Its grid
+is NOT the bounding box descriptors x pages x query tiles (of which 96 %
+did nothing at the benchmark's shapes: PERF.md, PR 25) but a COMPACTED
+list of the cells that compute, built in the trace from the descriptors
+(`ragged_work_list`) and walked under a traced bound:
+
+    grid = (H, live cells)            # count traced, <= ragged_grid_cells
+    cell w = (descriptor s, page i, query tile qt)      # one SMEM word
+    k block = pool_t[h, pages[w]]                       # one SMEM word
+    order: descriptors as given, a descriptor's pages ascending, the
+           tiles that see a page innermost (its block is fetched once)
+
+A 1-token decode row costs one grid step per page of its context; a
+padding descriptor, a page past a row's horizon and a tile outside a
+descriptor's rows cost none.
+
 Online softmax state (m, l, acc) lives in VMEM scratch across the page
-axis exactly like the flash forward kernel.  Pages past a sequence's
-length are skipped via @pl.when on the prefetched seq_lens (ragged
-sequences pay for the pages they own, not the batch max); the page table
-pads unused slots with page 0, which is always a valid DMA target.
+axis exactly like the flash forward kernel.
 
 Layouts are chosen Mosaic tile-legal by construction: pools transpose to
 [H, P, page_size, D] so every block's trailing two dims are full array
-dims (page_size, D); q/out ride as [B, H, 1, D] with (1, 1, 1, D) blocks.
+dims (page_size, D); decode q/out ride as [B, H, 1, D] with (1, 1, 1, D)
+blocks, ragged q/out as one whole-axis [1, T, D] block a head.
 
 INT8 POOLS: every public kernel takes optional ``k_scale``/``v_scale``
 [P, H] per-page per-head abs-max arrays (generation.quantized_kv).
@@ -32,16 +56,20 @@ operands stay bitwise equal — before the score matmul.  The jnp
 references dequantize their gathered O(tokens) views; the kernels
 dequantize per block; nobody ever materializes a dequantized pool.
 
+SMEM holds what the index maps read: the decode and chunk kernels' page
+tables, the ragged kernel's work list (two words a cell; its limit is in
+`ragged_paged_attention_kernel`'s docstring) and the descriptors.
+
 MESH-NATIVE dispatch: every public kernel takes ``mesh`` / ``tp_axis``.
 Heads are fully independent in all three grids, so under a head-sharded
 tensor-parallel mesh the kernel runs as a ``shard_map`` whose per-shard
 program is the SAME single-device kernel on ``num_heads / tp`` heads
 over that shard's slice of the pool — q/out split on the head axis,
-pools split per ``kv_pool_spec``, page tables and descriptors
-replicated.  NO collective enters the kernel: the generation stack's
-two per-layer Megatron allreduces stay XLA-placed outside it (exactly
-where GSPMD puts them on the jnp reference path), which is the layout
-the EQuARX-style quantized-collective follow-on assumes.
+pools split per ``kv_pool_spec``, page tables, descriptors and the work
+list replicated.  NO collective enters the kernel: the generation
+stack's two per-layer Megatron allreduces stay XLA-placed outside it
+(exactly where GSPMD puts them on the jnp reference path), which is the
+layout the EQuARX-style quantized-collective follow-on assumes.
 """
 import functools
 
@@ -138,13 +166,13 @@ def _split_refs(refs, quantized):
 _STATE_ROWS = 8  # scratch rows; every row holds the same value so all
 # scratch traffic is full-width vector ops (the Mosaic-proven layout)
 
-# query-axis tile of the RAGGED kernel (RPA-paper waste fix #1): a
-# (head, descriptor, page) grid cell computes a [RAGGED_Q_BLOCK,
-# page_size] score block for ONE query tile instead of the full packed
+# query-axis tile of the RAGGED kernel (RPA-paper waste fix #1): a grid
+# cell computes a [RAGGED_Q_BLOCK, page_size] score block for ONE query
+# tile of one (descriptor, page) instead of the full packed
 # [T, page_size] axis, and tiles outside the descriptor's row span are
-# skipped entirely — a 1-token decode descriptor computes 1 tile per
-# page, not T/8.  8 is the Mosaic sublane width (the flash kernels'
-# proven minor-axis tile).
+# not on the work list at all — a 1-token decode descriptor computes 1
+# tile per page, not T/8.  8 is the Mosaic sublane width (the flash
+# kernels' proven minor-axis tile).
 RAGGED_Q_BLOCK = 8
 
 
@@ -221,12 +249,104 @@ def _head_shard_map(body, mesh, tp_axis, layout, q, k_pool, v_pool,
 
 def ragged_query_tiles(n_rows, q_block=None):
     """``(q_block, n_tiles)`` the ragged kernel cuts a packed axis of
-    `n_rows` rows into: its grid is heads x descriptors x pages x
-    n_tiles.  The ONE statement of the tiling rule — the kernel's grid,
-    the skip-rule mirror below and the engine's grid counter
-    (`generation.step_grid_cells`) all read it here."""
+    `n_rows` rows into.  The ONE statement of the tiling rule — the
+    kernel's work list, the skip-rule mirror below and the engine's grid
+    counter (`generation.step_grid_cells`) all read it here."""
     qb = max(1, min(int(q_block or RAGGED_Q_BLOCK), int(n_rows)))
     return qb, -(-int(n_rows) // qb)
+
+
+def ragged_grid_cells(n_seqs, n_pages, n_rows, live=None):
+    """Grid steps a head of the ragged kernel: the CAPACITY of its work
+    list, or, given the `live` (descriptor, page, tile) cells of a
+    step's descriptors, the steps the kernel walks for them — exactly
+    those (the grid's bound is traced), but never fewer than the one
+    step that writes the output and never more than the list holds.
+    The ONE home of the grid's size: the kernel's `grid=` (on the
+    list's traced count), the engine's `generation.step_grid_cells` (on
+    `ragged_score_blocks`, the host's mirror of that count) and the
+    tests call it.
+
+    THE CAPACITY, and what it assumes.  Descriptors own DISJOINT row
+    ranges of the packed axis (`RaggedStep.pad` hands the engine's
+    back-to-back packing over; the host-free loop's layout is the
+    static ``s * (1 + K)``).  Every descriptor but the first begins
+    inside exactly one tile, so two of them share at most the tile the
+    later one begins in: the (descriptor, tile) pairs that intersect
+    number at most ``n_tiles + n_seqs - 1``, each meets at most
+    `n_pages` pages, and the list is sized to that product.  Ranges
+    that overlap can exceed it; the kernel then returns NaN, not the
+    attention of the cells that fitted."""
+    capacity = (ragged_query_tiles(n_rows)[1] + n_seqs - 1) * n_pages
+    if live is None:
+        return capacity
+    if isinstance(live, jax.Array):
+        return jnp.clip(live, 1, capacity)
+    return min(max(int(live), 1), capacity)
+
+
+def _cell_bits(n_seqs, n_pages, n_tiles):
+    """Shifts of a packed work-list cell ``descriptor | page | tile``
+    (tile in the low bits).  One int32 a cell keeps the list at one
+    SMEM word per entry beside its physical page."""
+    tile_bits = (n_tiles - 1).bit_length()
+    page_bits = (n_pages - 1).bit_length()
+    if tile_bits + page_bits + (n_seqs - 1).bit_length() > 31:
+        raise ValueError(
+            f"ragged work list: {n_seqs} descriptors x {n_pages} pages x "
+            f"{n_tiles} query tiles do not pack into one int32 cell")
+    return tile_bits, tile_bits + page_bits
+
+
+def ragged_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows):
+    """The ragged kernel's grid, IN THE TRACE: every (descriptor, page,
+    query tile) cell that computes, in the order the kernel walks them —
+    descriptors as given, a descriptor's pages ascending, the tiles
+    that see a page innermost (so a page block shared by a chunk's
+    tiles is fetched once, and each row meets its pages in ascending
+    order).  A cell is live by the rule `ragged_score_blocks` mirrors
+    on the host: the tile meets the descriptor's rows and the page
+    starts at or under the horizon of the tile's last in-span row.
+    Tiles are monotone in that horizon, so the tiles of a (descriptor,
+    page) are a suffix ``[first, t1]`` of the descriptor's tiles: a
+    count per (descriptor, page), a running sum, and a search give the
+    w-th cell in closed form.
+
+    Returns ``(pages [W], cells [W], count [1])`` int32, W the capacity
+    `ragged_grid_cells` states: cell w's physical page (what the k/v
+    index maps read) and its packed ``descriptor | page | tile`` word
+    (`_cell_bits`).  Entries past `count` repeat the last live one, so
+    their blocks are already resident; the kernel never computes them.
+    Pure jnp over traced descriptors: built once a step (model
+    `_ragged_core_fn`) or once an iteration of the host-free loop, and
+    shared by the layers."""
+    pt = jnp.asarray(page_tables, jnp.int32)
+    n_seqs, n_pages = pt.shape
+    qb, n_tiles = ragged_query_tiles(n_rows)
+    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
+    st, ln, kv = (jnp.asarray(x, jnp.int32)[:, None]
+                  for x in (starts, lens, kv_lens))            # [S, 1]
+    end = st + ln
+    t1 = jnp.minimum((end - 1) // qb, n_tiles - 1)
+    # page i starts under the horizon of tile qt's last in-span row iff
+    # min((qt + 1) * qb, end) >= need
+    need = (jnp.arange(n_pages, dtype=jnp.int32)[None, :] * page_size
+            - (kv - ln) + st + 1)                              # [S, P]
+    first = jnp.maximum(st // qb, -(-need // qb) - 1)
+    tiles = jnp.where((ln > 0) & (need <= end),
+                      jnp.maximum(t1 - first + 1, 0), 0).reshape(-1)
+    upto = jnp.cumsum(tiles)
+    count = upto[-1]
+    capacity = ragged_grid_cells(n_seqs, n_pages, n_rows)
+    w = jnp.minimum(jnp.arange(capacity, dtype=jnp.int32),
+                    jnp.maximum(count - 1, 0))
+    group = jnp.minimum(jnp.searchsorted(upto, w, side="right"),
+                        n_seqs * n_pages - 1).astype(jnp.int32)
+    tile = jnp.clip(first.reshape(-1)[group]
+                    + w - (upto[group] - tiles[group]), 0, n_tiles - 1)
+    cells = ((group // n_pages) << page_bits
+             | (group % n_pages) << tile_bits | tile)
+    return pt.reshape(-1)[group], cells, count.reshape(1)
 
 
 def ragged_score_blocks(starts, lens, kv_lens, page_size, n_pages, n_rows,
@@ -382,68 +502,61 @@ def _chunk_kernel(pt_ref, info_ref, *refs, page_size, n_pages, n_rows,
         o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
-def _ragged_kernel(pt_ref, st_ref, ln_ref, kv_ref, *refs, page_size,
-                   n_pages, n_seqs, q_block, quantized=False):
+def _ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref, *refs,
+                   page_size, q_block, tile_bits, page_bits, capacity,
+                   quantized=False):
     """RAGGED mixed-batch paged attention, QUERY-TILED (the RPA paper's
-    kernel shape): packed query rows (decode singletons AND
-    prefill-chunk runs in one token axis) attend through per-descriptor
-    page tables.  Descriptor s owns packed rows [st_ref[s], st_ref[s] +
-    ln_ref[s]); row r of s sits at global position kv_ref[s] - ln_ref[s]
-    + (r - st_ref[s]) and sees keys [0, position].
+    kernel shape), over a COMPACTED grid: packed query rows (decode
+    singletons AND prefill-chunk runs in one token axis) attend through
+    per-descriptor page tables.  Descriptor s owns packed rows
+    [st_ref[s], st_ref[s] + ln_ref[s]); row r of s sits at global
+    position kv_ref[s] - ln_ref[s] + (r - st_ref[s]) and sees keys
+    [0, position].
 
-    The grid walks (head, descriptor, page, QUERY TILE) — the tile axis
-    INNERMOST, so the k/v BlockSpec index (h, pt[s, i]) is constant
-    across a page's tile sweep and Pallas elides the repeated page-
-    block DMA: the tiled kernel moves exactly the HBM bytes the untiled
-    kernel did (q/out ride whole-axis blocks fetched once per head),
-    while COMPUTE is per-tile.  A (descriptor, page, tile) cell runs
-    ONLY when the tile intersects the descriptor's row span AND the
-    page holds a key some in-span row of the tile can see — a 1-token
-    decode descriptor computes one [q_block, page_size] block per
-    visible page instead of a full [T, page_size] one, and pages past a
-    row's causal horizon are skipped too (the tile's last in-span row
-    sees the most: qpos_max = kv_len - ln + (last_row - start)).
+    The grid is (head, w): step w is the w-th LIVE (descriptor, page,
+    query tile) cell of `ragged_work_list` — cell_ref[w] names it,
+    pg_ref[w] is its physical page (what the k/v index maps read) — and
+    computes one [q_block, page_size] score block.  A cell is on the
+    list only when its tile intersects the descriptor's row span AND
+    its page holds a key some in-span row of the tile can see, so a
+    1-token decode descriptor costs one step per visible page and a
+    padding descriptor (ln == 0) none; the tiles of one page are
+    consecutive, so Pallas elides the repeated page-block DMA.  The
+    second grid bound is TRACED (cnt_ref[0], held to [1, capacity]):
+    steps at or past the count — the lone step of an all-padding batch
+    — compute nothing.
     Online-softmax state spans the whole (tile-padded) token axis in
-    scratch; each live cell updates ITS tile's row slice.  Rows of a
-    tile the descriptor doesn't own see an all-NEG_INF score row, whose
+    scratch; each cell updates ITS tile's row slice.  Rows of a tile
+    the descriptor doesn't own see an all-NEG_INF score row, whose
     update is the exact identity (alpha == exp(0) == 1, sum(p) == 0),
-    so tiles straddling a descriptor boundary stay exact.  Descriptors
-    with ln == 0 (padding) never run.  Quantized pools add the scale
-    lane-row refs after q/k/v and each live cell dequantizes its page
-    block in-kernel (see _decode_kernel)."""
+    so tiles straddling a descriptor boundary stay exact.  Quantized
+    pools add the scale lane-row refs after q/k/v and each cell
+    dequantizes its page block in-kernel (see _decode_kernel)."""
     q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = \
         _split_refs(refs, quantized)
-    s = pl.program_id(1)
-    i = pl.program_id(2)
-    qt = pl.program_id(3)
+    w = pl.program_id(1)
 
-    @pl.when((s == 0) & (i == 0) & (qt == 0))
+    @pl.when(w == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    start = st_ref[s]
-    ln = ln_ref[s]
-    kv_len = kv_ref[s]
-    row0 = qt * q_block
-    # the tile's last row inside the descriptor's span sees the most
-    # keys; pages past its causal horizon hold nothing any tile row can
-    # attend (qpos_max < kv_len always, so "page has resident keys" is
-    # implied)
-    last = jnp.minimum(row0 + q_block, start + ln) - 1
-    qpos_max = kv_len - ln + (last - start)
-    live = ((ln > 0) & (row0 < start + ln) & (row0 + q_block > start)
-            & (i * page_size <= qpos_max))
-
-    @pl.when(live)
+    @pl.when(w < cnt_ref[0])
     def _compute():
+        cell = cell_ref[w]
+        s = cell >> page_bits
+        i = (cell >> tile_bits) & ((1 << (page_bits - tile_bits)) - 1)
+        row0 = (cell & ((1 << tile_bits) - 1)) * q_block
+        start = st_ref[s]
+        ln = ln_ref[s]
+        kv_len = kv_ref[s]
         rows_sl = pl.dslice(row0, q_block)
         q = q_ref[0, rows_sl]                      # [q_block, D]
         k = k_ref[0, 0]                            # [page_size, D]
         v = v_ref[0, 0]
         if quantized:
-            page = pt_ref[s, i]
+            page = pg_ref[w]
             k = _dequant_page(k, ks_ref, page)
             v = _dequant_page(v, vs_ref, page)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -471,19 +584,20 @@ def _ragged_kernel(pt_ref, st_ref, ln_ref, kv_ref, *refs, page_size,
         l_ref[rows_sl] = jnp.broadcast_to(l_cur, (q_block,
                                                   l_ref.shape[1]))
 
-    @pl.when((s == n_seqs - 1) & (i == n_pages - 1)
-             & (qt == pl.num_programs(3) - 1))
+    @pl.when(w == pl.num_programs(1) - 1)
     def _finalize():
         l = jnp.max(l_ref[...], axis=1, keepdims=True)
         safe_l = jnp.where(l > 0.0, l, 1.0)  # unclaimed rows: zeros
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+        # more live cells than the list holds (descriptors that overlap:
+        # ragged_grid_cells) must not pass for attention
+        fits = jnp.where(cnt_ref[0] > capacity, jnp.nan, 1.0)
+        o_ref[0] = (acc_ref[...] / safe_l * fits).astype(o_ref.dtype)
 
 
 def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
                                   lens, kv_lens, scale, interpret=None,
-                                  layout="token", q_block=None,
-                                  mesh=None, tp_axis=None, k_scale=None,
-                                  v_scale=None):
+                                  layout="token", mesh=None, tp_axis=None,
+                                  k_scale=None, v_scale=None, work=None):
     """q: [T, H, D] — the step's PACKED query rows (decode rows, the
     prefill chunks, and speculative verify runs — a decode row with
     len = 1 + k drafts is just a chunk-shaped descriptor to this
@@ -493,51 +607,58 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
     [P, page_size, H, D] (layout="token") or [H, P, page_size, D]
     (layout="kernel").  page_tables: [S, max_pages] int32 (pad with 0).
     starts/lens/kv_lens: [S] int32 descriptors (lens == 0 marks padding
-    descriptors; all three ride as scalar-prefetch operands so the
-    BlockSpec index_map DMAs each descriptor's pages straight out of
-    the pool).  Returns [T, H, D].
+    descriptors), owning DISJOINT row ranges (`ragged_grid_cells`).
+    work: `ragged_work_list` of these descriptors, for a caller that
+    builds it once for all its layers; built here when None.
+    Returns [T, H, D].
 
-    LIMIT: the page tables live whole in SMEM (1 MiB on v5e, minor
-    dimension padded to 128): fine at 2k context (9 rows x 128 pages =
-    4.5 KiB), refused by the compiler near 128k context x 65 rows
-    (8192 pages a row = 2 MiB).  Long-context serving needs the table
-    blocked per descriptor; not done here.
-
-    q_block tiles the packed query axis (default RAGGED_Q_BLOCK):
-    (tile, descriptor, page) cells whose rows lie outside the
-    descriptor's span — or whose page no in-span row can see — are
-    skipped (see _ragged_kernel; ragged_score_blocks mirrors the rule
+    The grid is (heads, live cells): the work list names each step's
+    (descriptor, page, query tile) and physical page, and rides with
+    the descriptors as scalar-prefetch operands, so the BlockSpec
+    index_map DMAs each cell's page straight out of the pool
+    (see _ragged_kernel; ragged_score_blocks mirrors the list's count
     host-side for the FLOP-proxy counter).
 
+    LIMIT: the list lives whole in SMEM (1 MiB on v5e), two int32 words
+    a cell at the capacity ``(n_tiles + S - 1) * max_pages``
+    (`ragged_grid_cells`); the page tables themselves no longer do.
+    The benchmark's 17 descriptors x 80 rows take 26 KiB at 2k context
+    (128 pages) and 832 KiB at 64k (4096 pages); the compiler refuses
+    them at 128k (8192 pages: 1.63 MiB), and 65 descriptors x 128 rows
+    at 32k (2048 pages: 1.25 MiB; 16k fits) — compile-only for v5e,
+    PR 25.  Long-context serving needs the list blocked; not done here.
+
     mesh / tp_axis runs the shard_map'd form: the same kernel per shard
-    on num_heads/tp heads over that shard's pool slice (_head_shard_map).
+    on num_heads/tp heads over that shard's pool slice (_head_shard_map),
+    the list replicated like the descriptors.
 
     Layout handling mirrors the decode kernel: token-layout pools are
     transposed per call, kernel-layout pools are consumed as stored."""
     _require_scales(k_pool, k_scale, v_scale)
     quantized = k_scale is not None
+    t, h, d = q.shape
+    page_size = k_pool.shape[2 if layout == "kernel" else 1]
+    starts, lens, kv_lens = (jnp.asarray(x, jnp.int32)
+                             for x in (starts, lens, kv_lens))
+    if work is None:
+        work = ragged_work_list(page_tables, starts, lens, kv_lens,
+                                page_size, t)
     if mesh is not None:
-        if quantized:
-            def body(q_, kp_, vp_, ks_, vs_, pt_, st_, ln_, kv_):
-                return ragged_paged_attention_kernel(
-                    q_, kp_, vp_, pt_, st_, ln_, kv_, scale,
-                    interpret=interpret, layout=layout, q_block=q_block,
-                    k_scale=ks_, v_scale=vs_)
-        else:
-            def body(q_, kp_, vp_, pt_, st_, ln_, kv_):
-                return ragged_paged_attention_kernel(
-                    q_, kp_, vp_, pt_, st_, ln_, kv_, scale,
-                    interpret=interpret, layout=layout, q_block=q_block)
+        def body(q_, kp_, vp_, *rest):
+            # rest: (k_scale, v_scale) when quantized, then the scalars
+            *scales_, pt_, st_, ln_, kv_, pages_, cells_, count_ = rest
+            return ragged_paged_attention_kernel(
+                q_, kp_, vp_, pt_, st_, ln_, kv_, scale,
+                interpret=interpret, layout=layout,
+                work=(pages_, cells_, count_),
+                **dict(zip(("k_scale", "v_scale"), scales_)))
 
         return _head_shard_map(
             body, mesh, tp_axis, layout, q, k_pool, v_pool,
-            jnp.asarray(page_tables, jnp.int32),
-            jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32),
-            jnp.asarray(kv_lens, jnp.int32),
-            scales=((k_scale, v_scale) if quantized else None))
+            jnp.asarray(page_tables, jnp.int32), starts, lens, kv_lens,
+            *work, scales=((k_scale, v_scale) if quantized else None))
     _reject_mesh_sharded_pool(k_pool)
-    t, h, d = q.shape
-    qb, n_tiles = ragged_query_tiles(t, q_block)
+    qb, n_tiles = ragged_query_tiles(t)
     tpad = n_tiles * qb
     qs = jnp.transpose((q * scale).astype(q.dtype), (1, 0, 2))  # [H, T, D]
     if tpad != t:
@@ -546,40 +667,33 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
         # descriptor (exact zeros) and are sliced off below
         qs = jnp.pad(qs, ((0, 0), (0, tpad - t), (0, 0)))
     if layout == "kernel":
-        page_size = k_pool.shape[2]
         kt, vt = k_pool, v_pool          # stored kernel-ready: no copy
     else:
-        page_size = k_pool.shape[1]
         kt = jnp.transpose(k_pool, (2, 0, 1, 3))
         vt = jnp.transpose(v_pool, (2, 0, 1, 3))
     n_seqs, n_pages = page_tables.shape
+    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
+    pages, cells, count = work
 
-    # scalar-prefetch operands (SMEM): page tables + descriptors
-    prefetch = [jnp.asarray(page_tables, jnp.int32),
-                jnp.asarray(starts, jnp.int32),
-                jnp.asarray(lens, jnp.int32),
-                jnp.asarray(kv_lens, jnp.int32)]
+    # scalar-prefetch operands (SMEM): the work list + descriptors
+    prefetch = [pages, cells, count, starts, lens, kv_lens]
 
-    def page_of(h_, s, i, qt, pt_ref, *_):
-        return h_, pt_ref[s, i]
+    def page_of(h_, w, pg_ref, *_):
+        return h_, pg_ref[w]
 
     scales = ([_scale_rows(k_scale), _scale_rows(v_scale)]
               if quantized else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        # query tiles INNERMOST: the k/v block index is constant across
-        # a page's tile sweep, so the tiling multiplies COMPUTE cells
-        # only — the page-block DMA schedule (and q/out whole-axis
-        # blocks, fetched once per head) is exactly the untiled
-        # kernel's
-        grid=(h, n_seqs, n_pages, n_tiles),
+        # one step per live cell (a traced bound); q/out ride whole-axis
+        # blocks fetched once per head
+        grid=(h, ragged_grid_cells(n_seqs, n_pages, t, live=count[0])),
         in_specs=[
-            pl.BlockSpec((1, tpad, d),
-                         lambda h_, s, i, qt, *refs: (h_, 0, 0)),
+            pl.BlockSpec((1, tpad, d), lambda h_, w, *refs: (h_, 0, 0)),
             *_pool_specs(page_of, page_size, d, len(scales)),
         ],
         out_specs=pl.BlockSpec((1, tpad, d),
-                               lambda h_, s, i, qt, *refs: (h_, 0, 0)),
+                               lambda h_, w, *refs: (h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((tpad, d), jnp.float32),
             pltpu.VMEM((tpad, 128), jnp.float32),
@@ -587,8 +701,9 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, page_size=page_size,
-                          n_pages=n_pages, n_seqs=n_seqs, q_block=qb,
+        functools.partial(_ragged_kernel, page_size=page_size, q_block=qb,
+                          tile_bits=tile_bits, page_bits=page_bits,
+                          capacity=ragged_grid_cells(n_seqs, n_pages, t),
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, tpad, d), q.dtype),

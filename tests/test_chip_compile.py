@@ -75,6 +75,30 @@ def test_ragged_kernel_compiles_for_v5e(v5e, kv_dtype):
              ((s,), "int32"), *_pool_operands(kv_dtype))
 
 
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("pages_bucket", [16, 64, 128])
+def test_ragged_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket,
+                                                        kv_dtype):
+    """opt-6.7b-d8's serving cells (benchmarks/configs/opt-6.7b-d8.json):
+    32 heads of 128, 16 decode slots + a 64-token chunk = 80 packed rows
+    under 17 descriptors, a 1280-page pool, one executable a pages
+    bucket.  The work list ((10 tiles + 16) x bucket cells, two SMEM
+    words each, built in the same trace) and the traced grid bound lower,
+    and the list fits SMEM at the largest bucket."""
+    heads, pages, t, s = 32, 1280, 80, 17
+    pool = ((pages, PAGE_SIZE, heads, HEAD_DIM), kv_dtype)
+    scale = ((pages, heads), np.float32)
+
+    def fn(q, pt, starts, lens, kv_lens, kp, vp, *rest):
+        return ragged_paged_attention(q, kp, vp, pt, starts, lens, kv_lens,
+                                      use_kernel=True, **_scales(rest))
+
+    _compile(fn, v5e, ((t, heads, HEAD_DIM), "float32"),
+             ((s, pages_bucket), "int32"), ((s,), "int32"), ((s,), "int32"),
+             ((s,), "int32"), pool, pool,
+             *([scale, scale] if kv_dtype == "int8" else []))
+
+
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
 def test_decode_kernel_compiles_for_v5e(v5e, kv_dtype):
     def fn(q, pt, seq_lens, kp, vp, *rest):

@@ -6,7 +6,8 @@ handle type the engine drives, and the kernel grid's denominator.
 All CPU; what is asserted is structure and counts, never a time: which
 spans a step opens, in which order and inside which, that nothing is
 recorded with the profiler off, that the stamps are ordered, and that
-the grid counter is max_seqs x pages bucket x query tiles.
+the grid counter is the steps the kernel's compacted grid walks
+(ops/pallas ragged_grid_cells).
 """
 import math
 import statistics
@@ -16,7 +17,8 @@ import pytest
 from paddle_tpu import generation as gen
 from paddle_tpu import profiler
 from paddle_tpu.generation import metrics as gmetrics
-from paddle_tpu.ops.pallas.paged_attention import ragged_query_tiles
+from paddle_tpu.ops.pallas.paged_attention import (ragged_grid_cells,
+                                                   ragged_query_tiles)
 from paddle_tpu.profiler.monitor import StatRegistry
 from paddle_tpu.serving import fleet as fleet_mod
 from paddle_tpu.serving.disagg.worker import _StreamHandle
@@ -405,9 +407,9 @@ def test_the_relay_reads_and_writes_the_clients_stamps():
 def test_grid_cells_per_dispatch_on_the_kernel_path(model):
     eng = _engine(model, slots=6, chunk=16, use_kernel=True)
     handles = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
-    tiles = ragged_query_tiles(eng._ragged.max_tokens)[1]
-    assert tiles == math.ceil(22 / 8)      # chunk 16 + 6 slots, q_block 8
-    cells = dispatches = 0
+    step = eng._ragged
+    assert ragged_query_tiles(step.max_tokens)[1] == math.ceil(22 / 8)
+    cells = dispatches = 0       # chunk 16 + 6 slots, q_block 8
     while eng.scheduler.active() or eng.scheduler.pending_count():
         before = eng.metrics.snapshot()
         eng.step()
@@ -417,15 +419,20 @@ def test_grid_cells_per_dispatch_on_the_kernel_path(model):
         if grew:
             dispatches += 1
             cells += grew
-            assert grew == (eng._ragged.max_seqs
-                            * eng._ragged.last_pages_bucket * tiles)
-            assert grew == eng._ragged.last_grid_cells
+            shape = (step.max_seqs, step.last_pages_bucket, step.max_tokens)
+            assert grew == step.last_grid_cells == ragged_grid_cells(
+                *shape, live=step.last_score_blocks)
+            # (3 tiles + 7 descriptors - 1) x pages bucket: the list
+            assert grew <= ragged_grid_cells(*shape) == (
+                9 * step.last_pages_bucket)
     for h in handles:
         h.result(timeout=5)
     snap = eng.metrics.snapshot()
     assert dispatches > 5 and snap[gmetrics.STEP_GRID_CELLS] == cells
-    assert 0 < snap[gmetrics.STEP_SCORE_BLOCKS] <= cells
-    assert snap[gmetrics.STEP_SCORE_BLOCKS_UNTILED] <= cells
+    # the grid is the live cells: every step of it computes, and it is
+    # smaller than what the kernel without query tiles would compute
+    assert 0 < snap[gmetrics.STEP_SCORE_BLOCKS] == cells
+    assert cells < snap[gmetrics.STEP_SCORE_BLOCKS_UNTILED]
     eng.shutdown()
 
 
