@@ -34,12 +34,14 @@ from .decode_attention import (chunk_prefill_attention,
                                ragged_paged_attention,
                                ragged_paged_attention_reference)
 from .engine import (DEFAULT_PREFILL_CHUNK_TOKENS, GenerationConfig,
-                     GenerationEngine, GenerationHandle, GenerationResult)
+                     GenerationEngine, GenerationHandle, GenerationResult,
+                     UnsupportedModelPathError)
 from .fused import (ChunkedPrefillStep, FusedDecodeStep,
                     LoopedRaggedStep, RaggedStep, decode_batch_menu)
-from .kv_cache import (DeviceKVPool, KVQuantMismatchError,
+from .kv_cache import (DeviceKVPool, KVQuantMismatchError, LatentRows,
                        OutOfPagesError, PagedKVCache,
-                       UnknownSequenceError)
+                       UnknownSequenceError, UnsupportedCachePathError)
+from .latent_moe_model import LatentMoELM
 from .metrics import GenerationMetrics
 from .model import TinyCausalLM
 from .sampling import (SampleStream, SamplingParams, sample_token,
@@ -56,7 +58,8 @@ __all__ = [
     "dense_causal_reference", "ContinuousBatchingScheduler",
     "GenerationRequest", "SequenceState", "SamplingParams", "sample_token",
     "sample_tokens_batch", "sample_tokens_device", "SampleStream",
-    "GenerationMetrics", "TinyCausalLM",
+    "GenerationMetrics", "TinyCausalLM", "LatentMoELM", "LatentRows",
+    "UnsupportedModelPathError", "UnsupportedCachePathError",
     "FusedDecodeStep", "ChunkedPrefillStep", "RaggedStep",
     "LoopedRaggedStep", "decode_batch_menu",
     "chunk_prefill_attention", "chunk_prefill_attention_reference",

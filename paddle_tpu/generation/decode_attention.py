@@ -379,3 +379,63 @@ def dense_causal_reference(q, k, v, scale=None):
     logits = jnp.where(causal[None], logits, NEG_INF)
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("hqk,khd->qhd", weights, jnp.asarray(v))
+
+
+# ------------------------- latent (MLA) pools -------------------------
+def latent_ragged_attention_reference(q, pool, page_tables, starts, lens,
+                                      kv_lens, scale, v_width):
+    """Pure-jnp absorbed-form latent attention: the CPU path and the
+    oracle of `latent_ragged_attention_kernel`, built like
+    `ragged_paged_attention_reference` (masked keys contribute exactly
+    0).  q: [T, H, W] absorbed queries; pool: [P, page_size, W] latent
+    rows ``[c | k_rope]``, the key of every head whole and their value
+    in the first `v_width` lanes.  Returns [T, H, v_width] float32;
+    rows owned by no descriptor come back exactly 0."""
+    q = jnp.asarray(q)
+    t = q.shape[0]
+    pt = jnp.asarray(page_tables, jnp.int32)
+    starts = jnp.asarray(starts, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    kv_lens = jnp.asarray(kv_lens, jnp.int32)
+    rows = jnp.asarray(pool)[pt]                  # [S, MP, page, W]
+    rows = rows.reshape(pt.shape[0], -1, rows.shape[-1]).astype(jnp.float32)
+    logits = jnp.einsum("thw,skw->sthk", q.astype(jnp.float32), rows,
+                        precision="highest") * scale
+    row = jnp.arange(t, dtype=jnp.int32)[None, :]
+    mine = (row >= starts[:, None]) & (row < (starts + lens)[:, None])
+    qpos = (kv_lens - lens)[:, None] + (row - starts[:, None])
+    col = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :]
+    visible = (mine[:, :, None] & (col <= qpos[:, :, None]))[:, :, None, :]
+    weights = jax.nn.softmax(jnp.where(visible, logits, NEG_INF), axis=-1)
+    weights = jnp.where(visible, weights, 0.0)
+    return jnp.einsum("sthk,skv->thv", weights, rows[..., :v_width],
+                      precision="highest")
+
+
+def latent_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows,
+                     use_kernel):
+    """`ragged_work_list`'s sibling for a latent pool: the latent
+    kernel's grid, built once a step for all layers; None where the jnp
+    form runs."""
+    if not use_kernel:
+        return None
+    from ..ops.pallas.paged_attention import latent_work_list as build
+
+    return build(page_tables, starts, lens, kv_lens, page_size, n_rows)
+
+
+def latent_ragged_attention(q, pool, page_tables, starts, lens, kv_lens,
+                            scale, v_width, use_kernel, interpret=None,
+                            work=None):
+    """Absorbed-form attention over one layer's latent pool: the Pallas
+    kernel, or the jnp form of the same (`use_kernel` resolved by the
+    caller at trace time, as the engine resolves it once)."""
+    if not use_kernel:
+        return latent_ragged_attention_reference(
+            q, pool, page_tables, starts, lens, kv_lens, scale, v_width)
+    from ..ops.pallas.paged_attention import latent_ragged_attention_kernel
+
+    return latent_ragged_attention_kernel(
+        jnp.asarray(q), jnp.asarray(pool),
+        jnp.asarray(page_tables, jnp.int32), starts, lens, kv_lens, scale,
+        v_width, interpret=interpret, work=work)

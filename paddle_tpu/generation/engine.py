@@ -501,6 +501,38 @@ class GenerationHandle:
             raise exc
 
 
+class UnsupportedModelPathError(ValueError):
+    """The configuration asks for a path this model does not implement
+    (a latent-cache model off the ragged step): refused when the engine
+    is built, never mis-served."""
+
+
+def _refuse_latent_paths(model, config):
+    """A model with `kv_rows` (a latent cache) is served by the ragged
+    step over device pools and by nothing else; every option that
+    selects another path is refused here, once, by name."""
+    asked = {
+        "kv_backend='host'": config.kv_backend == "host",
+        f"decode={config.decode!r}": config.decode is not None,
+        "step_mode='legacy'": config.step_mode == "legacy",
+        f"loop_steps={config.loop_steps}": config.loop_steps > 1,
+        f"spec_mode={config.spec_mode!r}": config.spec_mode == "ngram",
+        f"kv_dtype={config.kv_dtype}":
+            config.kv_dtype != np.dtype(np.float32),
+        f"pool_layout={config.pool_layout!r}":
+            config.pool_layout not in (None, "token"),
+        "mesh": config.mesh is not None,
+        "prefill_chunk_tokens=0": config.prefill_chunk_tokens == 0,
+    }
+    bad = [name for name, hit in asked.items() if hit]
+    if bad:
+        raise UnsupportedModelPathError(
+            f"{type(model).__name__} keeps a latent cache and is served "
+            f"by the ragged step over a device pool in the model's own "
+            f"dtype, with chunked prefill; not carried for it: "
+            f"{', '.join(bad)}")
+
+
 class GenerationEngine:
     """Paged-KV continuous-batching decode engine over a protocol model."""
 
@@ -513,6 +545,12 @@ class GenerationEngine:
         self.config = config or GenerationConfig()
         self.metrics = metrics or GenerationMetrics()
         on_tpu = jax.default_backend() == "tpu"
+        # what a token's cache row is, where the model says (a latent
+        # cache): everything model-specific is settled here, while the
+        # engine is built, and never asked again inside a step
+        kv_rows = model.kv_rows() if hasattr(model, "kv_rows") else None
+        if kv_rows is not None:
+            _refuse_latent_paths(model, self.config)
         # tensor-parallel mesh: sharded decode is device-pool + fused
         # by construction, so the mesh flips both auto policies
         mesh = self.config.mesh
@@ -522,7 +560,8 @@ class GenerationEngine:
         self.tp_degree = (int(mesh.shape[tp_axis])
                           if mesh is not None else 1)
         backend = self.config.kv_backend or (
-            "device" if (on_tpu or mesh is not None) else "host")
+            "device" if (on_tpu or mesh is not None or kv_rows is not None)
+            else "host")
         if mesh is not None and backend != "device":
             raise ValueError(
                 "mesh-sharded generation requires kv_backend='device': "
@@ -534,7 +573,7 @@ class GenerationEngine:
                 num_pages=self.config.num_pages,
                 page_size=self.config.page_size,
                 dtype=self.config.kv_dtype, pool_layout=pool_layout,
-                mesh=mesh, tp_axis=tp_axis)
+                mesh=mesh, tp_axis=tp_axis, rows=kv_rows)
         else:
             if pool_layout == "kernel":
                 raise ValueError(
@@ -599,8 +638,9 @@ class GenerationEngine:
             # of the eager oracle anyway: asking for either resolves
             # the auto step mode to ragged wherever the model supports
             # it (CPU included)
-            step_mode = "ragged" if ((on_tpu or spec_on or loop_on)
-                                     and ragged_capable) else "legacy"
+            step_mode = "ragged" if (
+                (on_tpu or spec_on or loop_on or kv_rows is not None)
+                and ragged_capable) else "legacy"
         if step_mode == "ragged" and not ragged_capable:
             raise ValueError(
                 "step_mode='ragged' needs kv_backend='device' and a "
@@ -771,6 +811,10 @@ class GenerationEngine:
                 mesh=mesh, tp_axis=tp_axis,
                 quant_collectives=self._quant_collectives,
                 spec_tokens=self.spec_tokens)
+            if self._ragged.step_counters:
+                # the model counts inside its step: its accounting takes
+                # the place of the plain one on this engine alone
+                self._account_step = self._account_step_counting
         # the host-free decode loop: N fused ragged iterations per
         # dispatch at decode-only boundaries, ONE host fetch per N
         # steps — built ALONGSIDE the single-step RaggedStep, which
@@ -808,6 +852,9 @@ class GenerationEngine:
         # the pools store, and whether the quantized ring ACTUALLY
         # carries the allreduces (a requested-but-inert flag reads 0)
         self.metrics.set_kv_quant_dtype(str(self.cache.dtype))
+        if kv_rows is not None:
+            self.metrics.set_kv_token_bytes(
+                kv_rows.token_bytes(self.cache.num_layers))
         self.metrics.set_collective_quantized(self._quant_collectives)
         # the spec_mode build stamp (kernel_path pattern): engine
         # construction refuses unsupported spec combos, so the stamp
@@ -1591,6 +1638,20 @@ class GenerationEngine:
         with RecordEvent("generation::account"):
             self._drain_kv_bytes()
             self._observe_occupancy()
+
+    def _account_step_counting(self):
+        """`_account_step` of an engine whose model counts inside its
+        step (`RaggedStep.step_counters`): the counter blocks of the
+        steps since the last read are fetched here, after the step's own
+        fetch, when the device has long finished them."""
+        GenerationEngine._account_step(self)
+        pending = self._ragged.pending_counters
+        if pending:
+            with RecordEvent("generation::account"):
+                self._ragged.pending_counters = []
+                self.metrics.count_model_step(
+                    self._ragged.step_counters,
+                    np.sum([np.asarray(c) for c in pending], axis=0))
 
     def _dispatch_ragged(self, decoding, pack, spec_plan=None):
         """Pack, dispatch, sample: the decode batch's spans first (slot

@@ -176,6 +176,10 @@ def _state_structs(jax_mod, cache, mesh, num_layers, quant):
     [P, H] scale arrays for quantized pools), sharded under a mesh so
     pre-warm lowers the REAL signature."""
     sds = jax_mod.ShapeDtypeStruct
+    if cache.rows is not None:
+        # a latent cache: one pool a layer, never sharded
+        pool = cache.latent_pool(0)
+        return [sds(tuple(pool.shape), pool.dtype)] * num_layers
     pool = cache.layer_pools(0)[0]
     if mesh is not None:
         pool_sds = sds(tuple(pool.shape), pool.dtype,
@@ -529,7 +533,7 @@ class RaggedStep:
         self._use_kernel = bool(use_kernel)
         self._quant = bool(getattr(cache, "quantized", False))
         self._quant_collectives = bool(quant_collectives) and self._tp > 1
-        self._n_groups = 4 if self._quant else 2
+        self._n_groups = cache.n_state_groups
         self._param_leaves, self._param_tree = _shard_params(
             model, mesh, tp_axis, jax)
         pages_menu = ShapeBucketer.geometric_menu(cache.num_pages, start=1)
@@ -541,6 +545,16 @@ class RaggedStep:
             step_kw["kv_quant"] = True
         if self._quant_collectives:
             step_kw["quant_collectives"] = True
+        # a model that counts inside its step (`step_counters`, the
+        # names of one more [n] int32 output) gets a third output and
+        # its own dispatch, chosen here once: nothing of it is asked
+        # about again in a step
+        self.step_counters = tuple(getattr(model, "step_counters", ()))
+        self.pending_counters = []
+        self._n_out = 2
+        if self.step_counters:
+            self._n_out = 3
+            self.dispatch = self._dispatch_counting
         if self.spec_tokens:
             # only spec-aware models see the kwarg: the plain ragged
             # protocol keeps working unchanged for models without it
@@ -555,7 +569,8 @@ class RaggedStep:
         wrapped = _wrap_donating(
             self._num_layers, self._param_tree, jax,
             lambda params, f, *gs: fn(params, *f, *gs),
-            n_fixed=self._n_fixed, n_out=2, n_groups=self._n_groups)
+            n_fixed=self._n_fixed, n_out=self._n_out,
+            n_groups=self._n_groups)
         self._exec = CompiledModelCache(
             wrapped, metrics=DecodeCacheMetrics(metrics), aot=True,
             donate_argnums=_pool_donate_plan(self._num_layers,
@@ -665,9 +680,18 @@ class RaggedStep:
         args = [*fixed, *self._cache.take_pool_state(),
                 *self._param_leaves]
         out = _dispatch_donating(
-            self._cache, self._exec, args, self._num_layers, n_out=2)
+            self._cache, self._exec, args, self._num_layers,
+            n_out=self._n_out)
         self.last_dispatches = 1
         return out
+
+    def _dispatch_counting(self, fixed):
+        """`dispatch` for a model with `step_counters`: the step's
+        counter block stays on the device in `pending_counters` until
+        the engine's accounting reads it."""
+        ids, logits, counters = RaggedStep.dispatch(self, fixed)
+        self.pending_counters.append(counters)
+        return ids, logits
 
     def count_kernel_cells(self, fixed):
         """The dispatch's grid and the part of it that computes, per

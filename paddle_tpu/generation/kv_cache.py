@@ -108,6 +108,44 @@ class UnknownSequenceError(KeyError):
                 f"already freed ({self.live_count} live sequence(s))")
 
 
+class LatentRows:
+    """What a latent-attention model tells `DeviceKVPool` a token's
+    cache row is: ONE row of `width` numbers a layer (the compressed kv
+    and the rotated shared key, side by side), in `dtype`, of which the
+    first `value_width` are also every head's value.  No head axis and
+    no V pool: a layer's pool is ``[num_pages, page_size, lanes]``, as
+    the latent kernel reads it, so no step relays the pool out.
+
+    `lanes` is the stored width: `width` up to a whole number of
+    128-lane vregs, zeros past `width`.  A trailing dimension that is
+    not lane-aligned makes XLA:TPU pick a pool layout with the PAGE
+    axis minor-most, and every kernel call then copies the whole pool
+    (compile-only for v5e, PR 28: 576 wide copied 424 MB a call, 640
+    wide none).  The tiled layout would pad to the same bytes anyway."""
+
+    def __init__(self, width, value_width, dtype):
+        self.width = int(width)
+        self.value_width = int(value_width)
+        self.dtype = np.dtype(dtype)
+        if not 0 < self.value_width <= self.width:
+            raise ValueError(
+                f"value_width {value_width} outside (0, width={width}]")
+
+    @property
+    def lanes(self):
+        return -(-self.width // 128) * 128
+
+    def token_bytes(self, num_layers):
+        """Logical bytes a cached token costs over `num_layers`."""
+        return self.width * self.dtype.itemsize * int(num_layers)
+
+
+class UnsupportedCachePathError(NotImplementedError):
+    """A cache operation this pool kind does not carry (a latent pool
+    asked for a per-head K/V read, write or page payload): refused by
+    name rather than served from the wrong layout."""
+
+
 class _PrefixNode:
     """One full page of prompt tokens in the prefix index.
 
@@ -1623,6 +1661,18 @@ def _jitted_page_copy(layout, sharding=None):
 _PAGE_COPY_JIT = {}
 
 
+def _jitted_latent_page_copy():
+    """The copy-on-write body of a latent cache: page `src` -> `dst` in
+    every layer's one pool, one donated dispatch."""
+    if "latent" not in _PAGE_COPY_JIT:
+        import jax
+
+        _PAGE_COPY_JIT["latent"] = jax.jit(
+            lambda pools, src, dst: [p.at[dst].set(p[src]) for p in pools],
+            donate_argnums=(0,))
+    return _PAGE_COPY_JIT["latent"]
+
+
 class DeviceKVPool(PagedKVCache):
     """PagedKVCache whose pools live on the device (HBM on TPU).
 
@@ -1665,12 +1715,23 @@ class DeviceKVPool(PagedKVCache):
 
     def __init__(self, num_layers, num_heads, head_dim, num_pages=256,
                  page_size=16, dtype=np.float32, pool_layout="token",
-                 mesh=None, tp_axis=None):
+                 mesh=None, tp_axis=None, rows=None):
         if pool_layout not in ("token", "kernel"):
             raise ValueError(
                 f"pool_layout must be 'token' or 'kernel', got "
                 f"{pool_layout!r}")
         self.pool_layout = pool_layout
+        # a LatentRows: ONE pool a layer, [P, page_size, lanes], written
+        # only inside the ragged step (see "latent pools" below)
+        self.rows = rows
+        if rows is not None:
+            if mesh is not None or pool_layout != "token":
+                raise UnsupportedCachePathError(
+                    "a latent pool has no head axis to shard or to "
+                    "transpose: mesh and pool_layout='kernel' do not "
+                    "apply")
+            dtype = rows.dtype
+            self._count_write_payload = self._count_latent_payload
         self.mesh = mesh
         self.tp_axis = None
         self.tp_degree = 1
@@ -1728,7 +1789,8 @@ class DeviceKVPool(PagedKVCache):
             return z
 
         self._k = [zeros() for _ in range(self.num_layers)]
-        self._v = [zeros() for _ in range(self.num_layers)]
+        self._v = ([] if self.rows is not None
+                   else [zeros() for _ in range(self.num_layers)])
         if self.quantized:
             def zscale():
                 z = jnp.zeros((self.num_pages, self.num_heads),
@@ -1748,6 +1810,15 @@ class DeviceKVPool(PagedKVCache):
         import jax.numpy as jnp
 
         self._jnp = jnp
+        self._groups = (1 if self.rows is not None
+                        else 4 if self.quantized else 2)
+        if self.rows is not None:
+            if self.quantized:
+                raise UnsupportedCachePathError(
+                    "int8 latent pools are not carried")
+            self._materialize_pools(
+                (self.num_pages, self.page_size, self.rows.lanes))
+            return
         if self.pool_layout == "kernel":
             shape = (self.num_heads, self.num_pages, self.page_size,
                      self.head_dim)
@@ -1801,6 +1872,7 @@ class DeviceKVPool(PagedKVCache):
         return int(len(np.unique(arr[arr < self.num_pages])))
 
     def _scatter_layer(self, layer, pages, rows, k, v, real_tokens):
+        self._refuse_latent("a per-layer K/V write")
         jnp = self._jnp
         kp, vp = self._k[layer], self._v[layer]
         pg = jnp.asarray(np.asarray(pages), jnp.int32)
@@ -1838,6 +1910,7 @@ class DeviceKVPool(PagedKVCache):
         """One donated dispatch covering every layer; k, v: [L, n, H, D]
         (indices are the same per layer, so there is no reason to pay
         num_layers dispatch latencies)."""
+        self._refuse_latent("a K/V append")
         jnp = self._jnp
         pg = jnp.asarray(np.asarray(pages), jnp.int32)
         rw = jnp.asarray(np.asarray(rows), jnp.int32)
@@ -1925,6 +1998,7 @@ class DeviceKVPool(PagedKVCache):
         per-shard read GSPMD assembles — np.asarray on the sharded
         slice collects every device's head split into the canonical
         full-head payload."""
+        self._refuse_latent("a K/V page export")
         jnp = self._jnp
         self._flush_scale_resets()
         idx = jnp.asarray(np.asarray(pages, np.int32).reshape(-1))
@@ -1956,6 +2030,7 @@ class DeviceKVPool(PagedKVCache):
         mesh-sharded pool comes back in its NamedSharding — the same
         contract as every other write path).  Quantized pools install
         the exporter's scale rows in the same dispatch."""
+        self._refuse_latent("a K/V page import")
         jnp = self._jnp
         pg = jnp.asarray(np.asarray(pages, np.int32))
         if self.quantized:
@@ -1982,6 +2057,10 @@ class DeviceKVPool(PagedKVCache):
         boundary (page-to-page inside the resident pools).  Quantized
         pools copy the scale rows with the bytes."""
         jnp = self._jnp
+        if self.rows is not None:
+            self._k = _jitted_latent_page_copy()(
+                self._k, jnp.int32(src), jnp.int32(dst))
+            return
         if self.quantized:
             self._flush_scale_resets()
             fn = _jitted_page_copy_quantized(self.pool_layout,
@@ -1999,13 +2078,38 @@ class DeviceKVPool(PagedKVCache):
     def layer_pools(self, layer):
         """The live device arrays — nothing crosses the host<->device
         boundary here, unlike the host backend's O(pool) upload."""
+        self._refuse_latent("a (K, V) pool pair")
         return self._k[layer], self._v[layer]
+
+    # ------------------------- latent pools --------------------------
+    # A latent pool (`rows`, a LatentRows) keeps the page tables, the
+    # prefix tree, copy-on-write, truncate and eviction of any pool:
+    # they are bookkeeping over page ids.  Its storage is one array a
+    # layer, written only inside the ragged step (the model scatters a
+    # token's row in the trace; `take_pool_state` hands the L pools
+    # over) and copied page to page on a copy-on-write.  What reads or
+    # writes per-head K and V (the eager and fused-decode paths, the
+    # disaggregated fleet's page payloads) is refused by name.
+    def _refuse_latent(self, what):
+        if self.rows is not None:
+            raise UnsupportedCachePathError(
+                f"{what} was asked of a latent pool, which holds one "
+                f"[{self.rows.lanes}]-lane row a token and no K or V")
+
+    def latent_pool(self, layer):
+        """One layer's live latent pool [P, page_size, lanes]."""
+        return self._k[layer]
+
+    def _count_latent_payload(self, tokens, layers):
+        self._bytes_moved += (tokens * layers * self.rows.width
+                              * self.dtype.itemsize)
 
     def gather_prefix(self, seq_id, layer, length):
         """Device-resident prefix gather: rows come straight out of the
         live pool arrays (same values as the host override — the stored
         dtype is the stored dtype), nothing crosses the host<->device
         boundary."""
+        self._refuse_latent("a K/V prefix gather")
         self._check_span(seq_id, 0, int(length))
         table = self._table(seq_id)
         length = int(length)
@@ -2040,9 +2144,10 @@ class DeviceKVPool(PagedKVCache):
     @property
     def n_state_groups(self):
         """Length-L array groups in the donated pool state: k + v
-        pools, plus k + v scale arrays when quantized — what
-        take_pool_state returns and the fused wrappers split on."""
-        return 4 if self.quantized else 2
+        pools, plus k + v scale arrays when quantized; the one latent
+        pool of a latent cache — what take_pool_state returns and the
+        fused wrappers split on."""
+        return self._groups
 
     def take_pool_state(self):
         """The WHOLE donated device state as one flat list —
@@ -2100,11 +2205,13 @@ class DeviceKVPool(PagedKVCache):
     @property
     def k_pool(self):
         """Host copy ``[L, P, page_size, H, D]`` in the canonical token
-        layout whatever pool_layout is (debug/tests only)."""
+        layout whatever pool_layout is; a latent cache's
+        ``[L, P, page_size, lanes]`` (debug/tests only)."""
         return np.stack([self._canonical(p) for p in self._k])
 
     @property
     def v_pool(self):
+        self._refuse_latent("a V pool")
         return np.stack([self._canonical(p) for p in self._v])
 
     @property

@@ -151,6 +151,16 @@ Metric names:
                                       every snapshot says what
                                       precision its numbers were
                                       measured at
+- ``generation.moe_assignments_total`` / ``_assignments_max_expert`` /
+  ``_experts_touched``                (token, expert) pairs the expert
+                                      layers computed; the busiest
+                                      expert's count a layer, summed;
+                                      experts with any row a layer,
+                                      summed — counted inside the step
+                                      by a model with `step_counters`
+- ``generation.kv_token_bytes``       gauge: bytes one cached token
+                                      costs over all layers (a latent
+                                      pool stamps it at engine build)
 - ``generation.kv_scale_bytes``       int8 scale bytes in flight
                                       (writes, exports, imports, COW)
                                       — a SUBSET of kv_bytes_moved
@@ -270,6 +280,7 @@ SPEC_DRAFT_ROWS = PREFIX + "spec_draft_rows"
 MESH_DEVICES = PREFIX + "mesh_devices"
 COLLECTIVE_BYTES_PER_STEP = PREFIX + "collective_bytes_per_step"
 KV_QUANT_DTYPE = PREFIX + "kv_quant_dtype"
+KV_TOKEN_BYTES = PREFIX + "kv_token_bytes"
 KV_SCALE_BYTES = PREFIX + "kv_scale_bytes"
 COLLECTIVE_QUANTIZED = PREFIX + "collective_quantized"
 PREFIX_CACHE_HIT_TOKENS = PREFIX + "prefix_cache_hit_tokens"
@@ -441,6 +452,19 @@ class GenerationMetrics:
             self._stat(STEP_SCORE_BLOCKS).increase(int(tiled))
             self._stat(STEP_SCORE_BLOCKS_UNTILED).increase(int(untiled))
             self._stat(STEP_GRID_CELLS).increase(int(grid_cells))
+
+    def count_model_step(self, names, values):
+        """What a model counted inside its steps (`step_counters`, e.g.
+        the experts' ``generation.moe_assignments_total``,
+        ``_assignments_max_expert`` — the busiest expert's count a
+        layer, summed — and ``_experts_touched``)."""
+        for name, value in zip(names, values):
+            self._stat(name).increase(int(value))
+
+    def set_kv_token_bytes(self, n):
+        """Gauge: bytes one cached token costs over all layers, stamped
+        at engine build by a cache that knows it (a latent pool)."""
+        self._stat(KV_TOKEN_BYTES).set(int(n))
 
     def set_kv_quant_dtype(self, dtype_name):
         """Gauge (string): the KV pool storage dtype, stamped once at

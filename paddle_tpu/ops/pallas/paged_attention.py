@@ -876,3 +876,209 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, page_tables, seq_lens,
         interpret=resolve_interpret(interpret),
     )(*prefetch, qs, kt, vt, *scales)
     return out.reshape(b, h, d)
+
+
+# ---------------------------------------------------------------------
+# LATENT (MLA, absorbed form) ragged attention.
+#
+# A latent-attention model caches one row a token a layer — the
+# normalised compressed kv `c` followed by the rotated shared key
+# `k_rope` — and every head of a query row attends the SAME row: as the
+# key whole, as the value in its leading `v_width` lanes.  There is no
+# head axis to put on the grid, so a cell here holds a tile's rows x
+# ALL heads against one page, which is what lets a page be read once.
+# That changes what stays resident: state for every row of the packed
+# axis x every head does not fit VMEM, so the list is walked TILE-major
+# (a tile's pages innermost) and the online-softmax state is one tile's.
+# The cells are the same set as `ragged_work_list`'s, in another order:
+# `ragged_grid_cells`, `ragged_score_blocks` and `_cell_bits` hold for
+# both.
+# ---------------------------------------------------------------------
+def latent_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows):
+    """`ragged_work_list`'s cells, ordered for the latent kernel:
+    descriptors as given, a descriptor's query tiles ascending, the
+    pages a tile sees innermost.  Descriptors own disjoint ASCENDING
+    row ranges (`RaggedStep.pad`'s packing), so the tile of the cells is
+    monotone along the list and each tile's cells are one run: the
+    kernel opens its state at a run's first cell and writes the tile's
+    output at its last.  Same return contract: ``(pages [W], cells [W],
+    count [1])``, entries past `count` repeating the last live one."""
+    pt = jnp.asarray(page_tables, jnp.int32)
+    n_seqs, n_pages = pt.shape
+    qb, n_tiles = ragged_query_tiles(n_rows)
+    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
+    st, ln, kv = (jnp.asarray(x, jnp.int32)[:, None]
+                  for x in (starts, lens, kv_lens))            # [S, 1]
+    end = st + ln
+    qt = jnp.arange(n_tiles, dtype=jnp.int32)[None, :]         # [1, Q]
+    meets = (ln > 0) & (qt >= st // qb) & (qt <= (end - 1) // qb)
+    # the tile's last in-span row sits at this position; it sees the
+    # pages that start at or under it
+    horizon = kv - ln + (jnp.minimum((qt + 1) * qb, end) - 1 - st)
+    seen = jnp.where(meets, jnp.clip(horizon // page_size + 1, 0, n_pages),
+                     0).reshape(-1)                            # [S * Q]
+    upto = jnp.cumsum(seen)
+    count = upto[-1]
+    capacity = ragged_grid_cells(n_seqs, n_pages, n_rows)
+    w = jnp.minimum(jnp.arange(capacity, dtype=jnp.int32),
+                    jnp.maximum(count - 1, 0))
+    # the group of cell w is the last one that starts at or under w: a
+    # mark at every group's start and a running sum, not a search (a
+    # `searchsorted` over this cell's 42 k entries is a 3-4 ms loop a
+    # step on the chip: PERF.md, PR 28); marks at or past the count
+    # (trailing empty groups) fall off the end
+    marks = jnp.zeros((capacity,), jnp.int32).at[upto - seen].add(
+        1, mode="drop")
+    group = jnp.clip(jnp.cumsum(marks)[w] - 1, 0, n_seqs * n_tiles - 1)
+    page = jnp.clip(w - (upto[group] - seen[group]), 0, n_pages - 1)
+    desc = group // n_tiles
+    cells = desc << page_bits | page << tile_bits | group % n_tiles
+    return pt[desc, page], cells, count.reshape(1)
+
+
+def _latent_ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref,
+                          q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                          page_size, q_block, tile_bits, page_bits,
+                          capacity, v_width):
+    """One (descriptor, page, query tile) cell of `latent_work_list`:
+    the tile's ``q_block`` rows x every head (head-major rows of the q
+    block: row ``h * q_block + r``) against one latent page, which is
+    the key whole and the value in its first `v_width` lanes.  The
+    masks and the online-softmax update are `_ragged_kernel`'s; the
+    state is one tile's, opened where the list's tile changes and
+    written out where it changes next."""
+    w = pl.program_id(1)
+    count = cnt_ref[0]
+    tile_mask = (1 << tile_bits) - 1
+    cell = cell_ref[w]
+    tile = cell & tile_mask
+    live = w < count
+    opens = (w == 0) | ((cell_ref[jnp.maximum(w - 1, 0)] & tile_mask)
+                        != tile)
+    closes = (w >= count - 1) | (
+        (cell_ref[jnp.minimum(w + 1, capacity - 1)] & tile_mask) != tile)
+    rows = acc_ref.shape[0]
+
+    @pl.when(opens)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(live)
+    def _compute():
+        s = cell >> page_bits
+        i = (cell >> tile_bits) & ((1 << (page_bits - tile_bits)) - 1)
+        start = st_ref[s]
+        ln = ln_ref[s]
+        kv_len = kv_ref[s]
+        q = q_ref[0]                               # [H * q_block, W]
+        c = c_ref[0]                               # [page_size, W]
+        sc = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        row = tile * q_block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 0) % q_block
+        col = i * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 1)
+        mine = (row >= start) & (row < start + ln)
+        qpos = kv_len - ln + (row - start)
+        sc = jnp.where(mine & (col <= qpos), sc, NEG_INF)
+        m_prev = jnp.max(m_ref[...], axis=1, keepdims=True)
+        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(sc - m_cur)
+        p = jnp.where(sc <= NEG_INF / 2, 0.0, p)   # masked keys: exactly 0
+        l_prev = jnp.max(l_ref[...], axis=1, keepdims=True)
+        l_cur = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(c.dtype), c[:, :v_width],
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_cur, l_ref.shape)
+
+    @pl.when(closes)
+    def _finalize():
+        l = jnp.max(l_ref[...], axis=1, keepdims=True)
+        safe_l = jnp.where(l > 0.0, l, 1.0)  # unclaimed rows: zeros
+        fits = jnp.where(count > capacity, jnp.nan, 1.0)
+        o_ref[0] = (acc_ref[...] / safe_l * fits).astype(o_ref.dtype)
+
+
+def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
+                                   kv_lens, scale, v_width, interpret=None,
+                                   work=None):
+    """Absorbed-form latent attention over a paged latent pool.
+
+    q: [T, H, W] — the packed rows' absorbed queries, ``[q_nope W_k^T |
+    q_rope]`` a head, W the pool's row width.  pool: one layer's latent
+    pool [P, page_size, W], this step's rows already scattered: a row
+    is ``[c | k_rope]``, the key of every head whole and their value in
+    its first `v_width` lanes.  page_tables / starts / lens / kv_lens:
+    the ragged kernel's descriptors, owning disjoint ASCENDING row
+    ranges.  work: `latent_work_list` of them (built here when None).
+    Returns [T, H, v_width] in q's dtype: ``sum_s p c(s)`` a head, for
+    the caller to carry through the value projection.  Rows of a tile
+    that no descriptor claims come back 0; a tile no cell touches is
+    never written, so the result is masked to the descriptors' rows.
+
+    The grid is ``(1, live cells)`` under the same traced bound and the
+    same scalar-prefetch list as `ragged_paged_attention_kernel` (the
+    list's SMEM limit is stated there)."""
+    t, h, width = q.shape
+    page_size = pool.shape[1]
+    starts, lens, kv_lens = (jnp.asarray(x, jnp.int32)
+                             for x in (starts, lens, kv_lens))
+    if work is None:
+        work = latent_work_list(page_tables, starts, lens, kv_lens,
+                                page_size, t)
+    _reject_mesh_sharded_pool(pool)
+    qb, n_tiles = ragged_query_tiles(t)
+    tpad = n_tiles * qb
+    qs = (q * scale).astype(q.dtype)
+    if tpad != t:
+        qs = jnp.pad(qs, ((0, tpad - t), (0, 0), (0, 0)))
+    # [tiles, H * q_block, W], head-major inside a tile: one 2-D block a
+    # cell, no in-kernel reshape across the (unaligned) head count
+    qs = jnp.transpose(qs.reshape(n_tiles, qb, h, width),
+                       (0, 2, 1, 3)).reshape(n_tiles, h * qb, width)
+    n_seqs, n_pages = page_tables.shape
+    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
+    capacity = ragged_grid_cells(n_seqs, n_pages, t)
+    pages, cells, count = work
+    prefetch = [pages, cells, count, starts, lens, kv_lens]
+    tile_mask = (1 << tile_bits) - 1
+
+    def tile_of(_, w, pg_ref, cell_ref, *rest):
+        return cell_ref[w] & tile_mask, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(1, ragged_grid_cells(n_seqs, n_pages, t, live=count[0])),
+        in_specs=[
+            pl.BlockSpec((1, h * qb, width), tile_of),
+            pl.BlockSpec((1, page_size, width),
+                         lambda _, w, pg_ref, *rest: (pg_ref[w], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, h * qb, v_width), tile_of),
+        scratch_shapes=[
+            pltpu.VMEM((h * qb, v_width), jnp.float32),
+            pltpu.VMEM((h * qb, 128), jnp.float32),
+            pltpu.VMEM((h * qb, 128), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_ragged_kernel, page_size=page_size,
+                          q_block=qb, tile_bits=tile_bits,
+                          page_bits=page_bits, capacity=capacity,
+                          v_width=v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles, h * qb, v_width), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(*prefetch, qs, pool)
+    out = jnp.transpose(out.reshape(n_tiles, h, qb, v_width),
+                        (0, 2, 1, 3)).reshape(tpad, h, v_width)[:t]
+    row = jnp.arange(t, dtype=jnp.int32)[None, :]
+    claimed = jnp.any((row >= starts[:, None])
+                      & (row < (starts + lens)[:, None]), axis=0)
+    return jnp.where(claimed[:, None, None], out, 0)

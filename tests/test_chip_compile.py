@@ -145,3 +145,89 @@ def test_a_tpu_lowering_has_no_cost_analysis(v5e):
     lowered = jax.jit(jnp.matmul).lower(struct, struct)
     assert lowered.cost_analysis() is None
     assert lowered.compile().cost_analysis()["flops"] > 0
+
+
+def _glm_cell():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm-4.7-flash-d7.json")) as f:
+        builder = json.load(f)["builder"]
+    return builder["model_args"], builder["engine"]
+
+
+@pytest.mark.parametrize("pages_bucket", [64, 512])
+def test_latent_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket):
+    """glm-4.7-flash-d7.docqa-closed: 20 heads against one 640-lane latent
+    row (576 numbers a token), 16 slots + a 64-token chunk = 80 packed
+    rows under 17 descriptors, a 5760-page pool of 64-token pages.  The
+    tile-major work list, a q tile's 160 rows and an 80 KiB page block
+    lower, and the list fits SMEM at the 512-page bucket."""
+    from paddle_tpu.generation.decode_attention import (
+        latent_ragged_attention)
+
+    args, engine = _glm_cell()
+    t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
+    s, lanes = engine["max_decode_slots"] + 1, 640
+
+    def fn(q, pool, pt, starts, lens, kv_lens):
+        return latent_ragged_attention(
+            q, pool, pt, starts, lens, kv_lens, 1 / 16,
+            args["kv_lora_rank"], True)
+
+    compiled = _compile(
+        fn, v5e, ((t, args["num_heads"], lanes), "bfloat16"),
+        ((engine["num_pages"], engine["page_size"], lanes), "bfloat16"),
+        ((s, pages_bucket), "int32"), ((s,), "int32"), ((s,), "int32"),
+        ((s,), "int32"))
+    # the pool goes to the kernel as it is stored: a copy of it would
+    # show as a temporary of the pool's size
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_latent_step_compiles_at_the_published_widths(v5e, monkeypatch):
+    """The whole ragged step of glm-4.7-flash-d7 at the cell's largest
+    pages bucket, weights as shapes: it fits the chip beside its 11.5 GiB
+    of weights and pools, the 7 latent pools are updated in place (no
+    operation but a layer's scatter produces a whole pool), and the
+    kernels are there (7 latent calls, 6 layers x 3 grouped-product
+    calls)."""
+    import re
+
+    from paddle_tpu.generation import latent_moe_model as lm
+
+    args, engine = _glm_cell()
+    draw = lm.LatentMoELM._draw
+    monkeypatch.setattr(
+        lm.LatentMoELM, "_draw",
+        lambda self, seed: jax.eval_shape(lambda: draw(self, seed)))
+    model = lm.LatentMoELM(**args, seed=1)
+    t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
+    s = engine["max_decode_slots"] + 1
+    rows = model.kv_rows()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    model.params)
+    pool = sds((engine["num_pages"], engine["page_size"], rows.lanes),
+               rows.dtype)
+    fixed = [sds((t,), "int32")] * 4 + [sds((s, 512), "int32")] + [
+        sds((s,), "int32")] * 3
+    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                              use_kernel=True)
+    compiled = jax.jit(fn, donate_argnums=(9,)).lower(
+        params, *fixed, [pool] * model.num_layers).compile()
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert memory.temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 7 + 6 * 3
+    whole = [m.group(1) for m in re.finditer(
+        r"^\s*%?([\w.\-]+) = bf16\[5760,64,640\]\S* (?!parameter)", text,
+        re.M)]
+    assert len(whole) == model.num_layers, whole

@@ -10,7 +10,7 @@ Raise the floor when adding tests (never lower it silently).
 import pathlib
 import re
 
-FLOOR = 990  # committed minimum number of test FUNCTIONS under
+FLOOR = 1011  # committed minimum number of test FUNCTIONS under
 # tests/ (parametrize expansion makes the collected count higher)
 
 

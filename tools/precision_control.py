@@ -1,0 +1,104 @@
+#!/usr/bin/env python
+"""The precision control of a serving cell's reference check: what the
+runner's own comparison says of a system that computes in the nearest
+precision BELOW the one the configuration states.  It has to say "not
+correct"; a check that passes the control cannot tell the stated
+precision from a worse one.
+
+    chiprun -- python tools/precision_control.py \\
+        --workload glm-4.7-flash-d7.docqa-closed --seed 2147484301 [--rehearse]
+
+The control system is the cell's plain reference with every matrix
+rounded to float8 (e4m3; the configuration states bfloat16): at each of
+the last `check.new_tokens` positions of the check's prompts (seeded as
+the runner seeds them, the document question behind seeded tokens of
+the document's length) it "serves" its argmax.  The float32 reference
+reads those tokens as it reads the engine's, `reference_readings()` by
+another road, and the runner's `verdict()` judges them by the
+configuration's limits.  The last line is a JSON object; exit code 0
+when the control comes out not correct, 1 when it passes.
+"""
+import argparse
+import json
+import os
+import sys
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.harness import manifest  # noqa: E402
+from benchmarks.run import _merge  # noqa: E402
+
+
+def _round_matrices_in_place(tree, dtype):
+    """A leaf at a time: two copies of the weights do not fit a chip."""
+    for key, value in (tree.items() if isinstance(tree, dict)
+                       else enumerate(tree)):
+        if isinstance(value, (dict, list)):
+            _round_matrices_in_place(value, dtype)
+        elif value.ndim >= 2:
+            tree[key] = value.astype(dtype).astype(value.dtype)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="the cell's tiny preset on the CPU")
+    args = ap.parse_args(argv)
+    if args.rehearse:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax.numpy as jnp
+
+    cell = manifest.Cell(manifest.load(manifest.ROOT), args.workload,
+                         manifest.ROOT)
+    config, traffic = cell.config, cell.traffic
+    if args.rehearse:
+        preset = dict(config["rehearsal"])
+        traffic = _merge(traffic, preset.pop("traffic", {}),
+                         only_existing=True)
+        config = _merge(config, preset)
+    runner = cell.module("runners", config["runner"])
+    reference = cell.module("reference", config["reference"])
+    check, builder = config["check"], config["builder"]
+    model = runner._load(builder["model"])(**builder["model_args"],
+                                           seed=args.seed)
+    params, n_new = model.decode_params(), int(check["new_tokens"])
+    rng = np.random.default_rng([args.seed, 0xC0DE])
+    lengths = [int(n) for n in check["prompt_tokens"]] + [
+        int(traffic["prefix"]["tokens"])
+        + int(check["document_question_tokens"])]
+    # prompt + the tokens the positions are read behind
+    texts = [rng.integers(0, model.vocab_size, n + n_new - 1).tolist()
+             for n in lengths]
+    full = []
+    for text in texts:
+        margins = []
+        logits = np.asarray(reference.next_token_logits(
+            params, text, builder["model_args"], n_new, margins))
+        full.append((logits, np.min([np.asarray(m)[-n_new:]
+                                     for m in margins], axis=0)))
+        print(f"float32 reference over {len(text)} tokens", flush=True)
+    _round_matrices_in_place(params, jnp.float8_e4m3fn)
+    requests = []
+    for text, (logits, tie) in zip(texts, full):
+        served = np.asarray(reference.next_token_logits(
+            params, text, builder["model_args"], n_new)).argmax(-1)
+        short = logits.max(-1) - logits[np.arange(n_new), served]
+        requests.append({"what": f"float8 reference, {len(text)} tokens",
+                         "short": short.astype(float).tolist(),
+                         "router_margin": tie.astype(float).tolist()})
+        print(f"  {requests[-1]}", flush=True)
+    ok, worst, lines = runner.verdict(check, requests, True)
+    for line in lines:
+        print("  precision control: " + line, flush=True)
+    print(json.dumps({"control": "float8_e4m3fn", "seed": args.seed,
+                      "correct": ok, "worst_shortfall": worst,
+                      "requests": requests}))
+    return 1 if ok else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
