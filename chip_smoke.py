@@ -358,8 +358,11 @@ def phase_server(jax, size, tp=None):
     The engine is built the way a deployment builds it — model, pool
     size, slots — and picks its own path; this phase requires the pick to
     be the ragged Pallas step over device pools with chunking and the
-    prefix cache on."""
+    prefix cache on, the pools stored as the kernel reads them wherever
+    their rows can be written in place there (the full size's float32
+    heads of 128; the toy's heads of 8 keep the token layout)."""
     from paddle_tpu import generation as g
+    from paddle_tpu.ops.pallas.paged_attention import pool_scatter_in_place
     from paddle_tpu.parallel.env import tp_mesh
     from paddle_tpu.profiler.monitor import StatRegistry
 
@@ -378,13 +381,20 @@ def phase_server(jax, size, tp=None):
         print(f"  engine picked: step_mode={engine.step_mode} "
               f"kernel_path={stats['generation.kernel_path']} "
               f"pools={type(engine.cache).__name__}"
-              f"[{engine.cache.pool_layout}, {engine.cache.dtype}] "
+              f"[{stats['generation.kv_pool_layout']} layout, "
+              f"{engine.cache.dtype}] "
               f"chunk={engine.prefill_chunk_tokens} "
               f"prefix_cache={engine.prefix_cache_enabled} "
               f"tp={engine.tp_degree}")
         assert engine.step_mode == "ragged"
         assert stats["generation.kernel_path"] == "ragged:pallas"
         assert isinstance(engine.cache, g.DeviceKVPool)
+        in_place = pool_scatter_in_place(
+            (model.num_heads, config.num_pages, config.page_size,
+             model.head_dim), engine.cache.dtype)
+        assert (stats["generation.kv_pool_layout"]
+                == engine.cache.pool_layout
+                == ("kernel" if in_place else "token"))
         assert engine.prefill_chunk_tokens > 0
         assert engine.prefix_cache_enabled
         handles = [engine.submit(p, max_new_tokens=new) for p in prompts]

@@ -132,8 +132,14 @@ class GenerationConfig:
         ([P, page_size, H, D], append-natural) or "kernel"
         ([H, P, page_size, D], what the Pallas decode kernel consumes:
         scatters write the kernel layout so the kernel path skips its
-        per-call whole-pool transpose).  None = "token".  Device
-        backend only.
+        per-call whole-pool transpose).  None = auto: the layout
+        follows the reader — "kernel" for a per-head device pool read
+        by the Pallas kernels (use_kernel resolved true), "token" for
+        host pools, the jnp gather path, and a pool whose rows no
+        in-place writer serves in kernel layout
+        (ops.pallas.pool_scatter_in_place: other than float32 heads of
+        128); a latent pool has its own layout either way.  "kernel"
+        is device backend only.
     prefill_chunk_tokens: CHUNKED prefill — split every admitted prompt
         into fixed-size chunks of this many tokens and stream them in
         one chunk per engine step, interleaved with decode, instead of
@@ -566,7 +572,33 @@ class GenerationEngine:
             raise ValueError(
                 "mesh-sharded generation requires kv_backend='device': "
                 "host numpy pools cannot carry a NamedSharding")
-        pool_layout = self.config.pool_layout or "token"
+        # the kernels are mesh-native (shard_map over the head-sharded
+        # mesh, ops/pallas/paged_attention._head_shard_map), so a mesh
+        # no longer forces the jnp fallback: sharded and fast are the
+        # same path.  Genuinely unsupported combos (heads not divisible
+        # by tp) still fail loudly — at pool construction and again in
+        # the kernel wrapper.
+        self._use_kernel = (self.config.use_kernel
+                            if self.config.use_kernel is not None
+                            else on_tpu)
+        # the pool's layout follows its reader: a per-head device pool
+        # read by the Pallas kernels is stored [H, P, page, D], as they
+        # consume it, so no step transposes a pool — where its rows can
+        # be written in place there (pool_scatter_in_place); the jnp
+        # gather, host pools and a latent pool (no head axis) keep the
+        # token layout
+        pool_layout = self.config.pool_layout
+        if pool_layout is None:
+            pool_layout = "token"
+            if backend == "device" and kv_rows is None and self._use_kernel:
+                from ..ops.pallas.paged_attention import (
+                    pool_scatter_in_place)
+
+                if pool_scatter_in_place(
+                        (model.num_heads, self.config.num_pages,
+                         self.config.page_size, model.head_dim),
+                        self.config.kv_dtype):
+                    pool_layout = "kernel"
         if backend == "device":
             self.cache = DeviceKVPool(
                 model.num_layers, model.num_heads, model.head_dim,
@@ -610,15 +642,6 @@ class GenerationEngine:
         # mirrors jit_prefill's auto policy — TPU default, eager-exact
         # stays the CPU tier-1 default so the zero-tolerance oracle is
         # anchored on the unfused path
-        # the kernels are mesh-native (shard_map over the head-sharded
-        # mesh, ops/pallas/paged_attention._head_shard_map), so a mesh
-        # no longer forces the jnp fallback: sharded and fast are the
-        # same path.  Genuinely unsupported combos (heads not divisible
-        # by tp) still fail loudly — at pool construction and again in
-        # the kernel wrapper.
-        self._use_kernel = (self.config.use_kernel
-                            if self.config.use_kernel is not None
-                            else on_tpu)
         fusable = (backend == "device"
                    and hasattr(model, "decode_step_fn")
                    and hasattr(model, "decode_params"))
@@ -848,6 +871,9 @@ class GenerationEngine:
         # visible stats fact instead of an inference from timings (the
         # bug class that hid the mesh/kernel gap for three PRs)
         self.metrics.set_kernel_path(self.decode_mode, self._use_kernel)
+        # and which layout the KV pool is stored in, beside it
+        self.metrics.set_kv_pool_layout(
+            "latent" if kv_rows is not None else self.cache.pool_layout)
         # precision facts, stamped once like kernel_path: what dtype
         # the pools store, and whether the quantized ring ACTUALLY
         # carries the allreduces (a requested-but-inert flag reads 0)

@@ -1380,7 +1380,8 @@ def _pin_sharding(pool, sharding):
     return jax.lax.with_sharding_constraint(pool, sharding)
 
 
-def scatter_pool_update(pool, pages, rows, x, layout):
+def scatter_pool_update(pool, pages, rows, x, layout, mesh=None,
+                        tp_axis=None):
     """Scatter token payload `x` into `(pages[i], rows[i])` of one pool,
     layout-aware.  Out-of-range page ids (the padding sentinel
     ``num_pages``) are DROPPED — length-padded positions can never write
@@ -1390,13 +1391,35 @@ def scatter_pool_update(pool, pages, rows, x, layout):
 
     token layout:  pool [P, page_size, H, D], x [n, H, D]
     kernel layout: pool [H, P, page_size, D], x [n, H, D] (swapped in)
+
+    A kernel-layout pool is written by row DMAs where Mosaic compiles
+    them (`kernel_pool_scatter`; mesh / tp_axis name the head-sharded
+    mesh for its shard_map), since XLA:TPU's scatter into that layout
+    copies the whole pool there and back; the interpreter, and a pool
+    those DMAs cannot address, take the XLA scatter: same result.
     """
     if layout == "kernel":
         import jax.numpy as jnp
 
+        from ..ops.pallas.flash_attention import resolve_interpret
+        from ..ops.pallas.paged_attention import (kernel_pool_scatter,
+                                                  pool_scatter_in_place)
+
+        if (pool_scatter_in_place(pool.shape, pool.dtype)
+                and not resolve_interpret(None)):
+            return kernel_pool_scatter(pool, pages, rows, x, mesh=mesh,
+                                       tp_axis=tp_axis)
         return pool.at[:, pages, rows].set(jnp.swapaxes(x, 0, 1),
                                            mode="drop")
     return pool.at[pages, rows].set(x, mode="drop")
+
+
+def _mesh_of(sharding):
+    """``{mesh, tp_axis}`` of a kernel-layout pool's NamedSharding
+    (`kv_pool_spec`: heads lead), nothing for an unsharded pool."""
+    if sharding is None:
+        return {}
+    return {"mesh": sharding.mesh, "tp_axis": sharding.spec[0]}
 
 
 def _scatter_kv(k_pool, v_pool, pages, rows, k, v, *, layout,
@@ -1405,10 +1428,11 @@ def _scatter_kv(k_pool, v_pool, pages, rows, k, v, *, layout,
     pools.  Donated: XLA performs the update in place, so an append
     moves the token payload, never the pool.  `sharding` pins the
     result for mesh-sharded pools (head-axis NamedSharding)."""
+    on = _mesh_of(sharding)
     return (_pin_sharding(scatter_pool_update(k_pool, pages, rows, k,
-                                              layout), sharding),
+                                              layout, **on), sharding),
             _pin_sharding(scatter_pool_update(v_pool, pages, rows, v,
-                                              layout), sharding))
+                                              layout, **on), sharding))
 
 
 def _scatter_kv_all_layers(k_pools, v_pools, pages, rows, k, v, *, layout,
@@ -1417,11 +1441,12 @@ def _scatter_kv_all_layers(k_pools, v_pools, pages, rows, k, v, *, layout,
     across layers): k_pools/v_pools are length-L lists (all donated),
     k/v are ``[L, n, H, D]``.  Prefill latency stays flat in depth
     instead of paying L dispatches per chunk."""
+    on = _mesh_of(sharding)
     return ([_pin_sharding(scatter_pool_update(kp, pages, rows, k[i],
-                                               layout), sharding)
+                                               layout, **on), sharding)
              for i, kp in enumerate(k_pools)],
             [_pin_sharding(scatter_pool_update(vp, pages, rows, v[i],
-                                               layout), sharding)
+                                               layout, **on), sharding)
              for i, vp in enumerate(v_pools)])
 
 
@@ -1686,14 +1711,20 @@ class DeviceKVPool(PagedKVCache):
 
     pool_layout picks the storage layout of each per-layer pool:
 
-    - ``"token"`` (default): ``[num_pages, page_size, H, D]`` — the
+    - ``"token"`` (this class's default; the engine's for the jnp
+      gather path): ``[num_pages, page_size, H, D]`` — the
       append-natural layout (one token's K is one contiguous row).
-    - ``"kernel"``: ``[H, num_pages, page_size, D]`` — the layout the
-      Pallas decode kernel consumes.  Scatters write INTO this layout,
-      so the kernel path skips its per-call whole-pool transpose — the
-      O(pool) HBM traffic per layer per step the token layout forces
-      on it (the ROADMAP-flagged gap).  The jnp reference gathers
-      either layout bitwise-identically (decode_attention.py).
+    - ``"kernel"`` (what the engine picks where the Pallas kernels read
+      the pool: `GenerationConfig.pool_layout`): ``[H, num_pages,
+      page_size, D]`` — the layout those kernels consume.  Scatters
+      write INTO this layout (`scatter_pool_update`: row DMAs, in
+      place), so no step transposes a pool — the O(pool) HBM traffic
+      per layer per step the token layout forces on the kernel path,
+      half its busy time at 335 MB a pool (PERF.md, PR 29).  The jnp
+      reference gathers either layout bitwise-identically
+      (decode_attention.py).  Page export/import and the prefix
+      gather transpose the few pages they move; copy-on-write is a
+      page-sized update in place.
 
     The arrays returned by ``layer_pools`` are invalidated by the next
     write (donation): read between writes, as the engine's step does.

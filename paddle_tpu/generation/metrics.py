@@ -114,6 +114,14 @@ Metric names:
                                       path is visible in every stats
                                       snapshot instead of inferred
                                       from timings
+- ``generation.kv_pool_layout``       gauge (string): how the KV pool
+                                      is stored — ``"kernel"``
+                                      ([H, P, page, D], read by the
+                                      Pallas kernels as stored),
+                                      ``"token"`` ([P, page, H, D]) or
+                                      ``"latent"`` (a latent cache's
+                                      [P, page, lanes]).  Stamped at
+                                      engine build beside kernel_path
 - ``generation.step_score_blocks``    [q_block, page_size] score-block
                                       computations per head the TILED
                                       ragged kernel performs (the
@@ -268,6 +276,7 @@ DECODE_COMPILES_PREWARM = PREFIX + "decode_compiles_prewarm"
 SLOT_OCCUPANCY_PCT = PREFIX + "slot_occupancy_pct"
 PAGE_UTILIZATION_PCT = PREFIX + "page_utilization_pct"
 KERNEL_PATH = PREFIX + "kernel_path"
+KV_POOL_LAYOUT = PREFIX + "kv_pool_layout"
 STEP_SCORE_BLOCKS = PREFIX + "step_score_blocks"
 STEP_SCORE_BLOCKS_UNTILED = PREFIX + "step_score_blocks_untiled"
 STEP_GRID_CELLS = PREFIX + "step_grid_cells"
@@ -441,6 +450,13 @@ class GenerationMetrics:
         which path produced its numbers."""
         path = "pallas" if use_kernel else "jnp-reference"
         self._stat(KERNEL_PATH).set(f"{mode}:{path}")
+
+    def set_kv_pool_layout(self, layout):
+        """Gauge (string): ``"kernel"`` / ``"token"`` / ``"latent"`` —
+        the layout the KV pool is stored in, stamped once at engine
+        build beside kernel_path, so every snapshot says which layout
+        produced its numbers."""
+        self._stat(KV_POOL_LAYOUT).set(str(layout))
 
     def count_score_blocks(self, tiled, untiled, grid_cells):
         """FLOP-proxy accounting for one ragged dispatch: score blocks

@@ -317,10 +317,12 @@ class TinyCausalLM:
                 else:
                     kp = scatter_pool_update(
                         k_pools[li], pages, rows,
-                        k.astype(k_pools[li].dtype), pool_layout)
+                        k.astype(k_pools[li].dtype), pool_layout,
+                        mesh=mesh, tp_axis=tp_axis)
                     vp = scatter_pool_update(
                         v_pools[li], pages, rows,
-                        v.astype(v_pools[li].dtype), pool_layout)
+                        v.astype(v_pools[li].dtype), pool_layout,
+                        mesh=mesh, tp_axis=tp_axis)
                 if pool_spec is not None:
                     kp = constrain(kp, mesh, *pool_spec)
                     vp = constrain(vp, mesh, *pool_spec)
@@ -504,10 +506,12 @@ class TinyCausalLM:
                 else:
                     kp = scatter_pool_update(
                         k_pools[li], pages, rows,
-                        k.astype(k_pools[li].dtype), pool_layout)
+                        k.astype(k_pools[li].dtype), pool_layout,
+                        mesh=mesh, tp_axis=tp_axis)
                     vp = scatter_pool_update(
                         v_pools[li], pages, rows,
-                        v.astype(v_pools[li].dtype), pool_layout)
+                        v.astype(v_pools[li].dtype), pool_layout,
+                        mesh=mesh, tp_axis=tp_axis)
                 if pool_spec is not None:
                     kp = constrain(kp, mesh, *pool_spec)
                     vp = constrain(vp, mesh, *pool_spec)
@@ -615,10 +619,12 @@ class TinyCausalLM:
                 else:
                     kp = scatter_pool_update(
                         k_pools[li], pages, rows,
-                        k.astype(k_pools[li].dtype), pool_layout)
+                        k.astype(k_pools[li].dtype), pool_layout,
+                        mesh=mesh, tp_axis=tp_axis)
                     vp = scatter_pool_update(
                         v_pools[li], pages, rows,
-                        v.astype(v_pools[li].dtype), pool_layout)
+                        v.astype(v_pools[li].dtype), pool_layout,
+                        mesh=mesh, tp_axis=tp_axis)
                 if pool_spec is not None:
                     kp = constrain(kp, mesh, *pool_spec)
                     vp = constrain(vp, mesh, *pool_spec)

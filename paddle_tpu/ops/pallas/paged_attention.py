@@ -38,7 +38,7 @@ descriptor's rows cost none.
 Online softmax state (m, l, acc) lives in VMEM scratch across the page
 axis exactly like the flash forward kernel.
 
-Layouts are chosen Mosaic tile-legal by construction: pools transpose to
+Layouts are chosen Mosaic tile-legal by construction: pools are read as
 [H, P, page_size, D] so every block's trailing two dims are full array
 dims (page_size, D); decode q/out ride as [B, H, 1, D] with (1, 1, 1, D)
 blocks, ragged q/out as one whole-axis [1, T, D] block a head.
@@ -632,8 +632,8 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
     on num_heads/tp heads over that shard's pool slice (_head_shard_map),
     the list replicated like the descriptors.
 
-    Layout handling mirrors the decode kernel: token-layout pools are
-    transposed per call, kernel-layout pools are consumed as stored."""
+    Kernel-layout pools (what the engine stores for this kernel: its
+    auto pool_layout) are consumed as stored; token-layout ones cost a copy."""
     _require_scales(k_pool, k_scale, v_scale)
     quantized = k_scale is not None
     t, h, d = q.shape
@@ -728,8 +728,8 @@ def chunk_prefill_attention_kernel(q, k_pool, v_pool, page_table, start,
     mesh / tp_axis runs the shard_map'd form (heads independent, page
     table and start replicated — _head_shard_map).
 
-    Same layout reasoning as the decode kernel: token-layout pools are
-    transposed per call, kernel-layout pools are consumed as stored."""
+    Kernel-layout pools (the engine's auto pool_layout on this path) are
+    consumed as stored; a token-layout pool is transposed whole per call."""
     _require_scales(k_pool, k_scale, v_scale)
     quantized = k_scale is not None
     if mesh is not None:
@@ -808,10 +808,10 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, page_tables, seq_lens,
     mesh / tp_axis runs the shard_map'd form (heads independent, page
     tables and seq_lens replicated — _head_shard_map).
 
-    The kernel itself always consumes [H, P, page_size, D].  Token-layout
-    pools are transposed here per call — O(pool) HBM traffic per layer
-    per step, which is exactly why kernel-layout pools exist: scattering
-    into [H, P, page_size, D] on write makes this call transpose-free."""
+    The kernel itself always consumes [H, P, page_size, D]: the layout
+    the engine stores a pool in when this path reads it (pool_layout=None).
+    A token-layout pool is transposed here per call — O(pool) HBM traffic
+    per layer per step (half the busy time at 335 MB a pool: PERF.md)."""
     _require_scales(k_pool, k_scale, v_scale)
     quantized = k_scale is not None
     if mesh is not None:
@@ -1082,3 +1082,103 @@ def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
     claimed = jnp.any((row >= starts[:, None])
                       & (row < (starts + lens)[:, None]), axis=0)
     return jnp.where(claimed[:, None, None], out, 0)
+
+
+# ---- the kernel-layout pool's row write (kept last: the kernels above
+# carry their line numbers into their lowered text) ----
+
+# tokens whose row copies are in flight together in `kernel_pool_scatter`
+_SCATTER_WINDOW = 64
+
+
+def pool_scatter_in_place(pool_shape, dtype):
+    """Whether `kernel_pool_scatter` serves a kernel-layout pool of this
+    shape ``[H, P, page_size, D]`` and dtype: one token's row of one head
+    has to be a copy Mosaic takes — 128 four-byte lanes (narrower types
+    pack several rows into a word, a narrower or wider head is not one
+    lane row) in pages of whole sublane tiles.  The engine stores a pool
+    in kernel layout of its own accord only where this holds: XLA's own
+    scatter into ``[H, P, page_size, D]`` copies the whole pool twice a
+    call (it wants the scattered axes major: PERF.md, PR 29)."""
+    _, _, page_size, d = pool_shape
+    return (jnp.dtype(dtype).itemsize == 4 and d == 128
+            and page_size % 8 == 0)
+
+
+def _pool_scatter_kernel(x_ref, pool_in, at_ref, pool_out, sem, *,
+                         num_pages):
+    # x_ref [T, H, D] and the pool [H, P, page_size, D] both stay in HBM;
+    # pool_in is pool_out (aliased): nothing but the rows moves.
+    # at_ref (SMEM): the T target pages, then the T target rows
+    del pool_in
+    n = x_ref.shape[0]
+
+    def each(lo, hi, act):
+        def body(t, carry):
+            # the padding sentinel (page == num_pages) is dropped
+            @pl.when(at_ref[t] < num_pages)
+            def _():
+                act(pltpu.make_async_copy(
+                    x_ref.at[t],
+                    pool_out.at[:, at_ref[t], at_ref[n + t], :], sem))
+            return carry
+
+        jax.lax.fori_loop(lo, hi, body, 0)
+
+    def window(w, carry):
+        lo = w * _SCATTER_WINDOW
+        hi = jnp.minimum(lo + _SCATTER_WINDOW, n)
+        each(lo, hi, lambda copy: copy.start())
+        each(lo, hi, lambda copy: copy.wait())
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(n, _SCATTER_WINDOW), window, 0)
+
+
+def kernel_pool_scatter(pool, pages, rows, x, interpret=None, mesh=None,
+                        tp_axis=None):
+    """Write token rows into a KERNEL-layout pool in place: ``x[i]``
+    ([H, D]) lands at ``pool[:, pages[i], rows[i], :]``; an out-of-range
+    page (the padding sentinel ``num_pages``) is dropped.  pool:
+    [H, P, page_size, D] (`pool_scatter_in_place` says which); pages,
+    rows: [T] int32, distinct targets; x: [T, H, D] in the pool's dtype.
+    Returns the pool, aliased to its operand: under a donating jit the
+    call moves T x H rows of 512 B and nothing else.
+
+    One strided DMA a token, HBM to HBM (H pieces of one lane row),
+    `_SCATTER_WINDOW` of them in flight.  It stands where
+    ``pool.at[:, pages, rows].set`` stood, which XLA:TPU serves by
+    copying the pool into a layout with the page axis major and back.
+    The targets ride last, as one SMEM operand: a trace reader that
+    tells the attention kernels by their leading int32 operand
+    (benchmarks/trace/kernels.py) does not take this call for one.
+
+    mesh / tp_axis: the shard_map'd form, as the attention kernels have
+    it — each shard writes its heads' pieces into its slice of the pool,
+    pages and rows replicated."""
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from ...parallel.collective import shard_map
+        from ...parallel.sharding_annotations import kv_pool_spec
+
+        if tp_axis is None:
+            tp_axis = tuple(mesh.axis_names)[0]
+        pspec = P(*kv_pool_spec("kernel", tp_axis))
+        return shard_map(
+            functools.partial(kernel_pool_scatter, interpret=interpret),
+            mesh=mesh, in_specs=(pspec, P(), P(), P(None, tp_axis, None)),
+            out_specs=pspec)(pool, pages, rows, x)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_pool_scatter_kernel, num_pages=pool.shape[1]),
+        in_specs=[any_space, any_space,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=any_space,
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={1: 0},
+        interpret=resolve_interpret(interpret),
+    )(x.astype(pool.dtype), pool,
+      jnp.concatenate([jnp.asarray(pages, jnp.int32),
+                       jnp.asarray(rows, jnp.int32)]))

@@ -29,11 +29,16 @@ SLOTS, CHUNK, MAX_PAGES = 8, 64, 128
 
 
 @pytest.fixture(scope="module")
-def v5e():
+def v5e_2x2():
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     assert topo.devices[0].device_kind == "TPU v5 lite"
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture(autouse=True)
@@ -97,6 +102,130 @@ def test_ragged_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket,
              ((s, pages_bucket), "int32"), ((s,), "int32"), ((s,), "int32"),
              ((s,), "int32"), pool, pool,
              *([scale, scale] if kv_dtype == "int8" else []))
+
+
+@pytest.mark.parametrize("heads,pages,rows", [
+    (32, 1280, 80),      # opt-6.7b-d8's step
+    (8, 4096, 72),       # chip_smoke.py's server
+    (32, 1280, 2048),    # a whole prompt's rows, the legacy prefill's write
+])
+def test_pool_row_scatter_compiles_for_v5e(v5e, heads, pages, rows):
+    """One token's row of one head is a DMA Mosaic takes (a 512 B lane
+    row at any page and row), and the pool comes back aliased."""
+    from paddle_tpu.ops.pallas.paged_attention import kernel_pool_scatter
+
+    compiled = _compile(
+        kernel_pool_scatter, v5e,
+        ((heads, pages, PAGE_SIZE, HEAD_DIM), "float32"), ((rows,), "int32"),
+        ((rows,), "int32"), ((rows, heads, HEAD_DIM), "float32"))
+    assert "output_to_operand_aliasing" in compiled.as_text()
+
+
+def test_pool_row_scatter_compiles_on_the_head_sharded_mesh(v5e_2x2):
+    """The tp=4 server's form: each chip writes its 8 heads' pieces into
+    its slice of the pool; no collective, no gathered pool."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.ops.pallas.paged_attention import kernel_pool_scatter
+
+    mesh = Mesh(np.array(v5e_2x2), ("model",))
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, np.dtype(dtype),
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    pool = sds((32, 1280, PAGE_SIZE, HEAD_DIM), "float32", "model")
+    compiled = jax.jit(
+        lambda *a: kernel_pool_scatter(*a, mesh=mesh, tp_axis="model"),
+        donate_argnums=(0,), out_shardings=pool.sharding).lower(
+            pool, sds((80,), "int32"), sds((80,), "int32"),
+            sds((80, 32, HEAD_DIM), "float32", None, "model")).compile()
+    text = compiled.as_text()
+    assert "f32[8,1280,16,128]" in text and "tpu_custom_call" in text
+    assert not any(op in text for op in ("all-gather", "all-reduce",
+                                         "collective-permute"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _pool_sized_results(v5e, layout, pages_bucket, layers=2):
+    """TinyCausalLM's ragged step at opt-6.7b-d8's shapes (32 heads of
+    128, a 1280-page pool, 80 packed rows under 17 descriptors; depth and
+    vocabulary cut, weights as shapes), pools donated, compiled for v5e:
+    the opcodes of the instructions that yield a whole pool and are not
+    the in-place row write, the count of those that are, and the
+    program's temporaries in bytes."""
+    import re
+
+    from paddle_tpu.generation.model import TinyCausalLM
+
+    heads, pages, t, s, vocab = 32, 1280, 80, 17, 128
+    d = heads * HEAD_DIM
+    model = TinyCausalLM(vocab_size=8, num_layers=0, num_heads=heads,
+                         head_dim=HEAD_DIM, max_positions=8)
+
+    def sds(shape, dtype="float32"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    block = {"ln1_s": sds((d,)), "ln1_b": sds((d,)), "wq": sds((d, d)),
+             "wk": sds((d, d)), "wv": sds((d, d)), "wo": sds((d, d)),
+             "ln2_s": sds((d,)), "ln2_b": sds((d,)),
+             "w1": sds((d, 4 * d)), "b1": sds((4 * d,)),
+             "w2": sds((4 * d, d)), "b2": sds((d,))}
+    params = {"tok_emb": sds((vocab, d)), "pos_emb": sds((2048, d)),
+              "blocks": [block] * layers, "ln_f_s": sds((d,)),
+              "ln_f_b": sds((d,)), "head": sds((d, vocab))}
+    shape = ((heads, pages, PAGE_SIZE, HEAD_DIM) if layout == "kernel"
+             else (pages, PAGE_SIZE, heads, HEAD_DIM))
+    fixed = ([sds((t,), "int32")] * 4 + [sds((s, pages_bucket), "int32")]
+             + [sds((s,), "int32")] * 3)
+    fn = model.ragged_step_fn(PAGE_SIZE, pages, use_kernel=True,
+                              pool_layout=layout)
+    compiled = jax.jit(fn, donate_argnums=(9, 10)).lower(
+        params, *fixed, [sds(shape)] * layers, [sds(shape)] * layers
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= layers
+    either = "|".join(",".join(map(str, dims)) for dims in (
+        shape, (heads, pages, PAGE_SIZE, HEAD_DIM)))
+    whole = [(m.group(1), "output_to_operand_aliasing" in m.group(0))
+             for m in re.finditer(
+                 rf"^\s*(?:ROOT )?%?[\w.\-]+ = f32\[(?:{either})\]\S* "
+                 r"([\w\-]+)\(.*$", text, re.M)]
+    in_place = [op for op, aliased in whole
+                if op == "custom-call" and aliased]
+    return ({op for op, aliased in whole if op != "parameter"
+             and not (op == "custom-call" and aliased)},
+            len(in_place), compiled.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("pages_bucket", [16, 128])
+def test_ragged_step_moves_no_pool_in_kernel_layout(v5e, pages_bucket):
+    """The layout the engine picks for opt-6.7b-d8's pools: the only
+    instructions that yield a whole pool (335 MB) are the K and V row
+    writes of each layer, each aliased to its operand; no copy, no
+    transpose, and temporaries far under one pool."""
+    others, in_place, temp_bytes = _pool_sized_results(v5e, "kernel",
+                                                       pages_bucket)
+    assert (others, in_place) == (set(), 4)
+    assert temp_bytes < 64 << 20
+
+
+def test_the_pool_check_tells_the_token_layout(v5e, monkeypatch):
+    """The same check on what the engine ran until PR 29: each layer's
+    token-layout pools are transposed whole for the kernel (a fusion and
+    335 MB of temporaries each) — and in kernel layout XLA's own scatter
+    copies each pool into a layout of its choosing and back."""
+    from paddle_tpu.ops.pallas import paged_attention
+
+    pool_bytes = 32 * 1280 * PAGE_SIZE * HEAD_DIM * 4
+    others, in_place, temp_bytes = _pool_sized_results(v5e, "token", 16)
+    assert {"scatter", "fusion"} <= others and in_place == 0
+    assert temp_bytes > pool_bytes
+    monkeypatch.setattr(paged_attention, "pool_scatter_in_place",
+                        lambda shape, dtype: False)
+    others, in_place, temp_bytes = _pool_sized_results(v5e, "kernel", 16)
+    assert {"scatter", "copy"} <= others and in_place == 0
+    assert temp_bytes > pool_bytes
 
 
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
