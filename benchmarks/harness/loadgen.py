@@ -28,6 +28,15 @@ Serving mixes (`"kind": "serve"`):
                 the request's own part of the prompt, and the tokens it
                 asks for.  With "block": B every B consecutive draws take
                 one from each B-quantile stratum, in a seeded order
+    schedule_seed
+                optional: the arrival instants, both lengths and the choice
+                of prefix are drawn from this seed and not from the run's,
+                so every run offers the same requests' sizes at the same
+                instants: one realisation of the arrival process, the same
+                for the parent and the change.  The run's seed still draws
+                every token id (and the runner's weights).  For an open loop
+                under its knee, where how the arrivals happen to bunch
+                moves every percentile more than a PR does
     ramp_s      seconds of the same traffic offered before the window opens
     timeout_ms  a request not finished this long after it was due failed
 
@@ -136,21 +145,26 @@ def schedule(traffic, seed, vocab_size, horizon_s):
     seconds (ramp included), or a closed loop's pool, which is as long as
     `pool` says (clients take from it in order)."""
     rng = np.random.default_rng([int(seed), 0x5EED])
+    # `shape`: who draws the instants and the sizes.  Without a
+    # `schedule_seed` it is the run's own generator, and the draws below
+    # come from one stream in the order they always did
+    shape = rng if traffic.get("schedule_seed") is None else \
+        np.random.default_rng([int(traffic["schedule_seed"]), 0x5EED])
     if traffic["loop"] == "open":
-        due = arrival_times(rng, traffic["arrivals"], horizon_s)
+        due = arrival_times(shape, traffic["arrivals"], horizon_s)
         n = len(due)
     elif traffic["loop"] == "closed":
         n = int(traffic["pool"])
         due = [None] * n
     else:
         raise ValueError(f"unknown loop {traffic['loop']!r}")
-    own = draw(rng, traffic["prompt_tokens"], n)
-    out = draw(rng, traffic["output_tokens"], n)
+    own = draw(shape, traffic["prompt_tokens"], n)
+    out = draw(shape, traffic["output_tokens"], n)
     prefix = traffic.get("prefix")
     if prefix:
         prefixes = rng.integers(0, vocab_size,
                                 (int(prefix["count"]), int(prefix["tokens"])))
-        which = zipf_choice(rng, int(prefix["count"]), prefix["zipf_a"], n,
+        which = zipf_choice(shape, int(prefix["count"]), prefix["zipf_a"], n,
                             prefix.get("block"))
     if traffic["loop"] == "closed" and traffic.get("stagger_first"):
         # callers that all start at once stay in step for generations:
@@ -159,7 +173,7 @@ def schedule(traffic, seed, vocab_size, horizon_s):
         # evenly spread fraction, as if it had begun before the load did,
         # so the load starts where a long-running one would be.
         c = int(traffic["clients"])
-        out[:c] = np.maximum(1, out[:c] * (rng.permutation(c) + 0.5) // c)
+        out[:c] = np.maximum(1, out[:c] * (shape.permutation(c) + 0.5) // c)
     requests = []
     for i in range(n):
         body = rng.integers(0, vocab_size, int(own[i])).tolist()

@@ -23,6 +23,7 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import io  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -137,6 +138,7 @@ class Context:
         self.trace_seconds = float(self.traffic.get("trace_s", TRACE_SECONDS))
         self.trace_dir = os.path.join(OUT_DIR, "trace", cell.name)
         self.clock = {}
+        self.checks = {}    # what `correct` compared: {name: (value, limit)}
         self.compile_clock = CompileClock(jax)
         self._jax = jax
 
@@ -152,6 +154,12 @@ class Context:
 
     def open_window(self):
         return Window(self)
+
+
+def _plain(x):
+    """A compared number as JSON can carry it: inf and nan have no JSON."""
+    x = float(x)
+    return x if math.isfinite(x) else str(x)
 
 
 def end_to_end(ctx, result):
@@ -253,7 +261,16 @@ def main(argv=None):
         line["rehearsal"] = True
         print("REHEARSAL: tiny preset on the CPU; says nothing of the chip",
               flush=True)
+    # what `correct` compared, each number beside its limit: the last key
+    # of the line and the last lines of standard error, which is what the
+    # driver's record keeps of a run that was not correct
+    line["checks"] = {name: {"value": _plain(value), "limit": _plain(limit)}
+                      for name, (value, limit) in ctx.checks.items()}
     print(json.dumps(line), flush=True)
+    for name, check in line["checks"].items():
+        print(f"check {name}: {check['value']} (limit {check['limit']})",
+              file=sys.stderr)
+    print(f"correct: {line['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

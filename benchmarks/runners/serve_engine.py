@@ -97,6 +97,7 @@ def check_against_reference(ctx, engine, model, check):
         if len(got) != n_new:
             ctx.note(f"check request returned {len(got)} tokens, not "
                      f"{n_new}")
+            ctx.checks["served_tokens_a_check_request"] = (len(got), n_new)
             return False, float("inf")
         logits = np.asarray(reference.next_token_logits(
             model.decode_params(), prompt + got[:-1], model.num_heads,
@@ -109,6 +110,7 @@ def check_against_reference(ctx, engine, model, check):
                  f"below the reference's top logit {short.max():.4g} "
                  f"(logit std {logits.std():.3g})")
         worst = max(worst, float(short.max()))
+    ctx.checks["served_logit_shortfall_max"] = (worst, check["logit_margin"])
     return worst <= float(check["logit_margin"]), worst
 
 

@@ -162,6 +162,16 @@ def check_against_reference(ctx, engine, model, check, document):
         check, requests, hit_of_miss == 0 and hit_of_hit >= len(document))
     for line in lines:
         ctx.note("reference check: " + line)
+    margin = float(check["logit_margin"])
+    agree = [int(np.sum(np.asarray(r["short"]) <= margin)) for r in requests]
+    ctx.checks.update({
+        "agreeing_share": (
+            sum(agree) / sum(len(r["short"]) for r in requests),
+            check["min_agreeing_share"]),
+        "least_agreeing_tokens_a_request": (
+            min(agree), check["min_agreeing_tokens_a_request"]),
+        "prefix_cache_tokens_second_asking": (hit_of_hit, len(document)),
+        "prefix_cache_tokens_first_asking": (hit_of_miss, 0)})
     return ok, worst
 
 

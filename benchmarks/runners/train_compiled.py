@@ -108,12 +108,19 @@ def run(ctx):
         out["step_s"] = [(t1 - t0_) / (n1 - n0) for (t0_, n0), (t1, n1)
                          in zip(reads, reads[1:])]
     k = max(1, len(losses) // 5)
-    falls = len(losses) >= 2 and np.mean(losses[-k:]) < np.mean(losses[:k])
+    # the last fifth of the losses less the first: nan with fewer than two
+    change = float(np.mean(losses[-k:]) - np.mean(losses[:k])) \
+        if len(losses) >= 2 else float("nan")
+    falls = change < 0
     ctx.note(f"{steps - len(warm)} steps, global batch {global_batch}; loss "
              f"read every {read_every} steps: first {losses[:3]}, last "
              f"{losses[-3:]}; falls: {bool(falls)}")
     out["correct"] = bool(agrees and falls and out["failed"] == 0
                           and np.isfinite(warm).all())
+    ctx.checks.update({
+        "first_loss_rel_difference": (abs(got - want) / abs(want), tol),
+        "loss_last_fifth_minus_first": (change, 0.0),
+        "losses_not_finite": (out["failed"], 0)})
     out["global_batch"] = global_batch
     out["data_replicas"] = replicas
     return out

@@ -151,11 +151,12 @@ def test_grid_utilization_reads_nothing_without_a_grid(cell, counters):
 def test_the_manifest_accepts_the_new_entries():
     m = manifest.load(ROOT)                 # validate() raises on a breach
     entries = {e["name"]: e for e in m["per_layer"]}
-    assert list(entries)[-len(NEW):] == list(NEW)     # appended, in order
+    # present with what they say, wherever later entries left them standing
     for name, (layer, source) in NEW.items():
         e = entries[name]
-        assert (e["layer"], e["source"], e["moves"], e["workloads"]) == (
-            layer, source, "serve_gap_ms_p95", SERVING)
+        assert (e["layer"], e["source"], e["moves"]) == (
+            layer, source, "serve_gap_ms_p95")
+        assert set(SERVING) <= set(e["workloads"])
         assert os.path.isfile(os.path.join(
             ROOT, "benchmarks", "layer_metrics", name + ".py"))
     for name in SERVING:

@@ -35,6 +35,29 @@ def test_same_seed_same_schedule_lengths_and_prompts(mix):
     assert len(a) > 10
 
 
+def test_a_schedule_seed_fixes_the_instants_and_sizes_not_the_tokens():
+    traffic = dict(_mix("chat-steady"), schedule_seed=5)
+    a = loadgen.schedule(traffic, 7, 50272, 60.0)
+    b = loadgen.schedule(traffic, 8, 50272, 60.0)
+
+    def shape(reqs):
+        return [(r.due_s, len(r.prompt), r.max_new_tokens, r.prefix_id)
+                for r in reqs]
+
+    assert shape(a) == shape(b) and len(a) > 100
+    # every token id is the run's: system prompts and own parts alike
+    assert all(x.prompt[:8] != y.prompt[:8] and x.prompt[-8:] != y.prompt[-8:]
+               for x, y in zip(a, b))
+    assert _flat(a) == _flat(loadgen.schedule(traffic, 7, 50272, 60.0))
+    # another realisation under another schedule seed; and without one the
+    # run's seed draws the shape too
+    c = loadgen.schedule(dict(traffic, schedule_seed=6), 7, 50272, 60.0)
+    assert shape(c) != shape(a)
+    free = dict(traffic, schedule_seed=None)
+    assert shape(loadgen.schedule(free, 7, 50272, 60.0)) != \
+        shape(loadgen.schedule(free, 8, 50272, 60.0))
+
+
 def test_chat_steady_shape():
     traffic = _mix("chat-steady")
     reqs = loadgen.schedule(traffic, 3, 50272, 2000.0)
@@ -218,6 +241,26 @@ def test_blocked_arrivals_fix_the_count_not_the_instants():
     with pytest.raises(ValueError):
         loadgen.arrival_times(np.random.default_rng(1),
                               dict(spec, rate_per_s=0.5), 100.0)
+
+
+def _open_loop_mixes():
+    mixes = {name[:-5]: _mix(name[:-5])
+             for name in sorted(os.listdir(TRAFFIC)) if name.endswith(".json")}
+    return [pytest.param(mix, id=name) for name, mix in mixes.items()
+            if mix.get("loop") == "open" and mix["arrivals"].get("block_s")]
+
+
+@pytest.mark.parametrize("mix", _open_loop_mixes())
+def test_a_block_of_arrivals_holds_whole_sets_of_the_length_strata(mix):
+    """A re-rating changes `rate_per_s`: the arrivals of one block of
+    seconds must stay a whole number and whole sets of each length
+    distribution's strata, or two seeds' windows stop offering the same
+    work."""
+    per = mix["arrivals"]["rate_per_s"] * mix["arrivals"]["block_s"]
+    assert per >= 1 and abs(per - round(per)) < 1e-9
+    for key in ("prompt_tokens", "output_tokens"):
+        block = mix[key].get("block")
+        assert block and round(per) % int(block) == 0, (key, per, block)
 
 
 def test_closed_loop_callers_do_not_start_in_step():
