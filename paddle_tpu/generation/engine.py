@@ -874,6 +874,15 @@ class GenerationEngine:
         # and which layout the KV pool is stored in, beside it
         self.metrics.set_kv_pool_layout(
             "latent" if kv_rows is not None else self.cache.pool_layout)
+        # ... and, for a latent pool the kernel reads, the pages a grid
+        # step of that kernel holds (0: no latent kernel runs)
+        pages_per_cell = 0
+        if kv_rows is not None and self._use_kernel:
+            from ..ops.pallas.paged_attention import latent_pages_per_cell
+
+            pages_per_cell = latent_pages_per_cell(self.cache.page_size,
+                                                   self.cache.num_pages)
+        self.metrics.set_latent_pages_per_cell(pages_per_cell)
         # precision facts, stamped once like kernel_path: what dtype
         # the pools store, and whether the quantized ring ACTUALLY
         # carries the allreduces (a requested-but-inert flag reads 0)

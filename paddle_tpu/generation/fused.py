@@ -696,8 +696,9 @@ class RaggedStep:
     def count_kernel_cells(self, fixed):
         """The dispatch's grid and the part of it that computes, per
         head and layer, into last_grid_cells / last_score_blocks /
-        last_score_blocks_untiled.  The FLOP proxy mirrors the TILED
-        KERNEL's skip rule — only meaningful (and only paid) when the
+        last_score_blocks_untiled (for a latent pool, the page slots of
+        the latent kernel's grid: `generation.step_grid_cells`).  The
+        FLOP proxy mirrors the TILED KERNEL's skip rule — only meaningful (and only paid) when the
         kernel path actually dispatched; the jnp reference computes
         dense masked blocks, and reporting kernel skip statistics for
         it would make the gen_bench /ref-vs-/kernel score_blocks column
@@ -705,17 +706,27 @@ class RaggedStep:
         the step."""
         if not self._use_kernel:
             return
-        from ..ops.pallas.paged_attention import (ragged_grid_cells,
-                                                  ragged_score_blocks)
+        from ..ops.pallas import paged_attention as pa
 
         st, ln, kv = fixed[5:]
         bucket_p = self.last_pages_bucket
+        page_size = self._cache.page_size
         self.last_score_blocks, self.last_score_blocks_untiled = \
-            ragged_score_blocks(st, ln, kv, self._cache.page_size,
-                                bucket_p, self.max_tokens)
-        self.last_grid_cells = ragged_grid_cells(
-            self.max_seqs, bucket_p, self.max_tokens,
-            live=self.last_score_blocks)
+            pa.ragged_score_blocks(st, ln, kv, page_size, bucket_p,
+                                   self.max_tokens)
+        if self._cache.rows is None:
+            self.last_grid_cells = pa.ragged_grid_cells(
+                self.max_seqs, bucket_p, self.max_tokens,
+                live=self.last_score_blocks)
+            return
+        # the latent kernel walks GROUPS of pages: its grid in the
+        # score blocks' unit is the page slots of the steps it takes,
+        # so blocks over cells reads how full the groups are
+        self.last_grid_cells = pa.latent_pages_per_cell(
+            page_size, bucket_p) * pa.latent_grid_cells(
+                self.max_seqs, bucket_p, self.max_tokens, page_size,
+                live=pa.latent_score_groups(st, ln, kv, page_size, bucket_p,
+                                            self.max_tokens))
 
 
 class LoopedRaggedStep:

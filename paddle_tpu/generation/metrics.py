@@ -152,7 +152,26 @@ Metric names:
                                       denominator of step_score_blocks
                                       (same units, same 0 on the jnp
                                       reference); a ratio under 1 is
-                                      steps that computed nothing
+                                      steps that computed nothing.  An
+                                      engine on a LATENT pool counts
+                                      the page SLOTS its kernel walked
+                                      (latent_pages_per_cell x the
+                                      grid steps, ops/pallas
+                                      latent_grid_cells): a step there
+                                      holds a group of pages, a tile's
+                                      last group is padded, and the
+                                      ratio reads how full the groups
+                                      are
+- ``generation.latent_pages_per_cell``  gauge: pages one grid step of
+                                      the latent kernel holds (ops/
+                                      pallas latent_pages_per_cell at
+                                      the pool's page size; a pages
+                                      bucket under it holds itself).
+                                      Stamped at engine build beside
+                                      kv_pool_layout, 0 unless a
+                                      latent pool is read by the
+                                      kernel: which kernel a
+                                      snapshot's numbers belong to
 - ``generation.kv_quant_dtype``       gauge (string): the pool storage
                                       dtype ("float32" / "bfloat16" /
                                       "int8") stamped at engine build —
@@ -290,6 +309,7 @@ MESH_DEVICES = PREFIX + "mesh_devices"
 COLLECTIVE_BYTES_PER_STEP = PREFIX + "collective_bytes_per_step"
 KV_QUANT_DTYPE = PREFIX + "kv_quant_dtype"
 KV_TOKEN_BYTES = PREFIX + "kv_token_bytes"
+LATENT_PAGES_PER_CELL = PREFIX + "latent_pages_per_cell"
 KV_SCALE_BYTES = PREFIX + "kv_scale_bytes"
 COLLECTIVE_QUANTIZED = PREFIX + "collective_quantized"
 PREFIX_CACHE_HIT_TOKENS = PREFIX + "prefix_cache_hit_tokens"
@@ -481,6 +501,11 @@ class GenerationMetrics:
         """Gauge: bytes one cached token costs over all layers, stamped
         at engine build by a cache that knows it (a latent pool)."""
         self._stat(KV_TOKEN_BYTES).set(int(n))
+
+    def set_latent_pages_per_cell(self, n):
+        """Gauge: pages a grid step of the latent kernel holds, stamped
+        at engine build (0 by an engine that runs no latent kernel)."""
+        self._stat(LATENT_PAGES_PER_CELL).set(int(n))
 
     def set_kv_quant_dtype(self, dtype_name):
         """Gauge (string): the KV pool storage dtype, stamped once at

@@ -886,27 +886,100 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, page_tables, seq_lens,
 # `k_rope` — and every head of a query row attends the SAME row: as the
 # key whole, as the value in its leading `v_width` lanes.  There is no
 # head axis to put on the grid, so a cell here holds a tile's rows x
-# ALL heads against one page, which is what lets a page be read once.
-# That changes what stays resident: state for every row of the packed
-# axis x every head does not fit VMEM, so the list is walked TILE-major
-# (a tile's pages innermost) and the online-softmax state is one tile's.
-# The cells are the same set as `ragged_work_list`'s, in another order:
-# `ragged_grid_cells`, `ragged_score_blocks` and `_cell_bits` hold for
-# both.
+# ALL heads, which is what lets a page be read once.  That changes what
+# stays resident: state for every row of the packed axis x every head
+# does not fit VMEM, so the list is walked TILE-major (a tile's pages
+# innermost) and the online-softmax state is one tile's.
+#
+# A CELL is a GROUP of `latent_pages_per_cell` consecutive logical
+# pages of one descriptor for one query tile, fetched and multiplied
+# together: one score product over ~LATENT_CELL_TOKENS keys, one value
+# product and one softmax update a grid step.  A grid step costs ~0.35
+# us whatever it computes and a one-page product fills half an MXU
+# (PERF.md, PRs 25, 28, 33), so a 16.5 k-token row walks 17 cells, not
+# 260.  The live (descriptor, page, tile) cells are `ragged_work_list`'s
+# set, grouped; this list has its own capacity and grid rule below, and
+# the per-head kernel's functions above keep theirs.
 # ---------------------------------------------------------------------
+# Keys of one context a grid step multiplies.  Settled by a sweep of the
+# kernel alone at glm-4.7-flash-d7's shapes and by the cell (PERF.md
+# section 6, PR 33: 512 keys a cell were 8 % fewer tokens/s, 2,048 no
+# more); the double-buffered page block it implies, 2 x 1,024 rows of
+# 1,280 B, is 2.5 MiB of VMEM.
+LATENT_CELL_TOKENS = 1024
+
+
+def latent_pages_per_cell(page_size, n_pages):
+    """G, the pages of one cell of the latent list: what the pool's
+    page size makes of `LATENT_CELL_TOKENS`, at least one page and never
+    more than the page tables hold (the pages bucket).  The ONE
+    statement of the grouping rule, as `ragged_query_tiles` is of the
+    tiling: the list, the kernel, the engine's counters and gauge and
+    the tests read it here."""
+    return max(1, min(LATENT_CELL_TOKENS // int(page_size), int(n_pages)))
+
+
+def latent_grid_cells(n_seqs, n_pages, n_rows, page_size, live=None):
+    """Grid steps of the latent kernel: the CAPACITY of its list, or,
+    given the `live` (descriptor, page group, tile) cells of a step,
+    the steps the kernel walks for them, held to [1, capacity] like
+    `ragged_grid_cells`.  The (descriptor, tile) pairs that intersect
+    number at most ``n_tiles + n_seqs - 1`` (disjoint ascending row
+    ranges: the argument is `ragged_grid_cells`'), and a pair meets at
+    most ``ceil(n_pages / G)`` groups."""
+    per = latent_pages_per_cell(page_size, n_pages)
+    capacity = (ragged_query_tiles(n_rows)[1] + n_seqs - 1) * -(-n_pages
+                                                                // per)
+    if live is None:
+        return capacity
+    if isinstance(live, jax.Array):
+        return jnp.clip(live, 1, capacity)
+    return min(max(int(live), 1), capacity)
+
+
+def latent_score_groups(starts, lens, kv_lens, page_size, n_pages, n_rows):
+    """Host-side mirror of `latent_work_list`'s count, by its rule in
+    numpy: the live (descriptor, page group, tile) cells of these
+    descriptors, which is what the engine's
+    `generation.step_grid_cells` is set from on a latent pool (times G:
+    the page SLOTS walked, full or padded)."""
+    qb, n_tiles = ragged_query_tiles(n_rows)
+    st, ln, kv = (np.asarray(x, np.int64)[:, None]
+                  for x in (starts, lens, kv_lens))            # [S, 1]
+    end = st + ln
+    qt = np.arange(n_tiles)[None, :]                           # [1, Q]
+    meets = (ln > 0) & (qt >= st // qb) & (qt <= (end - 1) // qb)
+    horizon = kv - ln + (np.minimum((qt + 1) * qb, end) - 1 - st)
+    seen = np.where(meets, np.clip(horizon // int(page_size) + 1, 0,
+                                   int(n_pages)), 0)
+    return int((-(-seen // latent_pages_per_cell(page_size, n_pages))).sum())
+
+
 def latent_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows):
-    """`ragged_work_list`'s cells, ordered for the latent kernel:
-    descriptors as given, a descriptor's query tiles ascending, the
-    pages a tile sees innermost.  Descriptors own disjoint ASCENDING
-    row ranges (`RaggedStep.pad`'s packing), so the tile of the cells is
-    monotone along the list and each tile's cells are one run: the
-    kernel opens its state at a run's first cell and writes the tile's
-    output at its last.  Same return contract: ``(pages [W], cells [W],
-    count [1])``, entries past `count` repeating the last live one."""
+    """The latent kernel's grid, in the trace: every live (descriptor,
+    page group, query tile) cell — descriptors as given, a descriptor's
+    query tiles ascending, the groups a tile sees innermost.
+    Descriptors own disjoint ASCENDING row ranges (`RaggedStep.pad`'s
+    packing), so the tile of the cells is monotone along the list and
+    each tile's cells are one run: the kernel opens its state at a
+    run's first cell and writes the tile's output at its last.
+
+    Returns ``(pages [W * G], cells [W], count [1])`` int32, W the
+    capacity `latent_grid_cells` states and G `latent_pages_per_cell`:
+    cell w's packed ``descriptor | group | tile`` word (`_cell_bits`
+    over the groups) and, at ``pages[w * G + g]``, the physical page of
+    its g-th slot, logical page ``group * G + g``.  A tile's LAST group
+    is filled by repeating its last visible page: the fetch stays valid
+    and the kernel's ``col <= qpos`` mask drops those columns, since a
+    column is reckoned from its slot's logical page, which starts past
+    the tile's horizon.  Entries past `count` repeat the last live
+    cell.  Built once a step and shared by the layers."""
     pt = jnp.asarray(page_tables, jnp.int32)
     n_seqs, n_pages = pt.shape
+    per = latent_pages_per_cell(page_size, n_pages)
+    n_groups = -(-n_pages // per)
     qb, n_tiles = ragged_query_tiles(n_rows)
-    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
+    tile_bits, group_bits = _cell_bits(n_seqs, n_groups, n_tiles)
     st, ln, kv = (jnp.asarray(x, jnp.int32)[:, None]
                   for x in (starts, lens, kv_lens))            # [S, 1]
     end = st + ln
@@ -917,36 +990,46 @@ def latent_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows):
     horizon = kv - ln + (jnp.minimum((qt + 1) * qb, end) - 1 - st)
     seen = jnp.where(meets, jnp.clip(horizon // page_size + 1, 0, n_pages),
                      0).reshape(-1)                            # [S * Q]
-    upto = jnp.cumsum(seen)
+    steps = -(-seen // per)
+    upto = jnp.cumsum(steps)
     count = upto[-1]
-    capacity = ragged_grid_cells(n_seqs, n_pages, n_rows)
+    capacity = latent_grid_cells(n_seqs, n_pages, n_rows, page_size)
     w = jnp.minimum(jnp.arange(capacity, dtype=jnp.int32),
                     jnp.maximum(count - 1, 0))
-    # the group of cell w is the last one that starts at or under w: a
-    # mark at every group's start and a running sum, not a search (a
-    # `searchsorted` over this cell's 42 k entries is a 3-4 ms loop a
+    # the (descriptor, tile) pair of cell w is the last one that starts
+    # at or under w: a mark at every pair's start and a running sum, not
+    # a search (a `searchsorted` over 42 k entries was a 3-4 ms loop a
     # step on the chip: PERF.md, PR 28); marks at or past the count
-    # (trailing empty groups) fall off the end
-    marks = jnp.zeros((capacity,), jnp.int32).at[upto - seen].add(
+    # (trailing empty pairs) fall off the end
+    marks = jnp.zeros((capacity,), jnp.int32).at[upto - steps].add(
         1, mode="drop")
-    group = jnp.clip(jnp.cumsum(marks)[w] - 1, 0, n_seqs * n_tiles - 1)
-    page = jnp.clip(w - (upto[group] - seen[group]), 0, n_pages - 1)
-    desc = group // n_tiles
-    cells = desc << page_bits | page << tile_bits | group % n_tiles
-    return pt[desc, page], cells, count.reshape(1)
+    pair = jnp.clip(jnp.cumsum(marks)[w] - 1, 0, n_seqs * n_tiles - 1)
+    group = jnp.clip(w - (upto[pair] - steps[pair]), 0, n_groups - 1)
+    desc = pair // n_tiles
+    cells = desc << group_bits | group << tile_bits | pair % n_tiles
+    slot = group[:, None] * per + jnp.arange(per, dtype=jnp.int32)[None, :]
+    slot = jnp.minimum(slot, jnp.maximum(seen[pair] - 1, 0)[:, None])
+    return pt[desc[:, None], slot].reshape(-1), cells, count.reshape(1)
 
 
 def _latent_ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref,
-                          q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                          page_size, q_block, tile_bits, page_bits,
-                          capacity, v_width):
-    """One (descriptor, page, query tile) cell of `latent_work_list`:
-    the tile's ``q_block`` rows x every head (head-major rows of the q
-    block: row ``h * q_block + r``) against one latent page, which is
-    the key whole and the value in its first `v_width` lanes.  The
-    masks and the online-softmax update are `_ragged_kernel`'s; the
-    state is one tile's, opened where the list's tile changes and
-    written out where it changes next."""
+                          q_ref, pool_ref, o_ref, buf_ref, sem, acc_ref,
+                          m_ref, l_ref, *, page_size, per, n_pages, q_block,
+                          tile_bits, group_bits, capacity, v_width):
+    """One (descriptor, page group, query tile) cell of
+    `latent_work_list`: the tile's ``q_block`` rows x every head
+    (head-major rows of the q block: row ``h * q_block + r``) against
+    the group's ``per`` latent pages as ONE ``[per * page_size, W]``
+    block, which is the key whole and the value in its first `v_width`
+    lanes.  The pool stays in HBM (`pool_ref`): a group's pages are not
+    neighbours there, so each is copied into its slot of one half of
+    `buf_ref` while the cell before multiplies the other half (Pallas's
+    own pipeline over G index maps of the pool read 1.27 ms a call at
+    the benchmark's shapes whether G was 8 or 16, this 1.31 and 1.13:
+    PERF.md, PR 33).  The masks and the online-softmax update are
+    `_ragged_kernel`'s, a group at a time; the state is one tile's,
+    opened where the list's tile changes and written out where it
+    changes next."""
     w = pl.program_id(1)
     count = cnt_ref[0]
     tile_mask = (1 << tile_bits) - 1
@@ -958,6 +1041,25 @@ def _latent_ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref,
     closes = (w >= count - 1) | (
         (cell_ref[jnp.minimum(w + 1, capacity - 1)] & tile_mask) != tile)
     rows = acc_ref.shape[0]
+    n_keys = per * page_size
+
+    def copies(step, act):
+        half = step % 2
+        for g in range(per):
+            act(pltpu.make_async_copy(
+                pool_ref.at[pg_ref[step * per + g]],
+                buf_ref.at[half, pl.ds(g * page_size, page_size)],
+                sem.at[half]))
+
+    @pl.when(w == 0)
+    def _first():
+        copies(w, lambda copy: copy.start())
+
+    @pl.when(w + 1 < pl.num_programs(1))
+    def _next():
+        copies(w + 1, lambda copy: copy.start())
+
+    copies(w, lambda copy: copy.wait())
 
     @pl.when(opens)
     def _init():
@@ -967,27 +1069,31 @@ def _latent_ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref,
 
     @pl.when(live)
     def _compute():
-        s = cell >> page_bits
-        i = (cell >> tile_bits) & ((1 << (page_bits - tile_bits)) - 1)
+        s = cell >> group_bits
+        group = (cell >> tile_bits) & ((1 << (group_bits - tile_bits)) - 1)
         start = st_ref[s]
         ln = ln_ref[s]
         kv_len = kv_ref[s]
         q = q_ref[0]                               # [H * q_block, W]
-        c = c_ref[0]                               # [page_size, W]
+        c = buf_ref[w % 2]                         # [n_keys, W]
         sc = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         row = tile * q_block + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0) % q_block
-        col = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
+            jnp.int32, (rows, 1), 0) % q_block
         mine = (row >= start) & (row < start + ln)
-        qpos = kv_len - ln + (row - start)
-        sc = jnp.where(mine & (col <= qpos), sc, NEG_INF)
+        # a row's last visible position, -1 for rows of other
+        # descriptors; held under the page tables' width, past which a
+        # slot repeats a page the row has already met
+        qpos = jnp.where(mine, jnp.minimum(kv_len - ln + (row - start),
+                                           n_pages * page_size - 1), -1)
+        col = group * n_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (1, n_keys), 1)
+        visible = col <= qpos                      # [rows, n_keys]
+        sc = jnp.where(visible, sc, NEG_INF)
         m_prev = jnp.max(m_ref[...], axis=1, keepdims=True)
         m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(sc - m_cur)
-        p = jnp.where(sc <= NEG_INF / 2, 0.0, p)   # masked keys: exactly 0
+        p = jnp.where(visible, jnp.exp(sc - m_cur), 0.0)  # masked: exactly 0
         l_prev = jnp.max(l_ref[...], axis=1, keepdims=True)
         l_cur = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(p.astype(c.dtype), c[:, :v_width],
@@ -1022,9 +1128,12 @@ def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
     that no descriptor claims come back 0; a tile no cell touches is
     never written, so the result is masked to the descriptors' rows.
 
-    The grid is ``(1, live cells)`` under the same traced bound and the
-    same scalar-prefetch list as `ragged_paged_attention_kernel` (the
-    list's SMEM limit is stated there)."""
+    ONE call a layer whatever the step carries, its grid ``(1, live
+    cells)`` under a traced bound (`latent_grid_cells`), the list and
+    the descriptors its scalar-prefetch operands.  The list lives whole
+    in SMEM: ``W * (G + 1)`` words, 55 KiB at the benchmark's 17
+    descriptors x 80 rows x 512 pages (the limit is the per-head
+    kernel's, stated in `ragged_paged_attention_kernel`)."""
     t, h, width = q.shape
     page_size = pool.shape[1]
     starts, lens, kv_lens = (jnp.asarray(x, jnp.int32)
@@ -1043,8 +1152,9 @@ def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
     qs = jnp.transpose(qs.reshape(n_tiles, qb, h, width),
                        (0, 2, 1, 3)).reshape(n_tiles, h * qb, width)
     n_seqs, n_pages = page_tables.shape
-    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
-    capacity = ragged_grid_cells(n_seqs, n_pages, t)
+    per = latent_pages_per_cell(page_size, n_pages)
+    tile_bits, group_bits = _cell_bits(n_seqs, -(-n_pages // per), n_tiles)
+    capacity = latent_grid_cells(n_seqs, n_pages, t, page_size)
     pages, cells, count = work
     prefetch = [pages, cells, count, starts, lens, kv_lens]
     tile_mask = (1 << tile_bits) - 1
@@ -1054,14 +1164,14 @@ def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(1, ragged_grid_cells(n_seqs, n_pages, t, live=count[0])),
-        in_specs=[
-            pl.BlockSpec((1, h * qb, width), tile_of),
-            pl.BlockSpec((1, page_size, width),
-                         lambda _, w, pg_ref, *rest: (pg_ref[w], 0, 0)),
-        ],
+        grid=(1, latent_grid_cells(n_seqs, n_pages, t, page_size,
+                                   live=count[0])),
+        in_specs=[pl.BlockSpec((1, h * qb, width), tile_of),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, h * qb, v_width), tile_of),
         scratch_shapes=[
+            pltpu.VMEM((2, per * page_size, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((h * qb, v_width), jnp.float32),
             pltpu.VMEM((h * qb, 128), jnp.float32),
             pltpu.VMEM((h * qb, 128), jnp.float32),
@@ -1069,9 +1179,9 @@ def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
     )
     out = pl.pallas_call(
         functools.partial(_latent_ragged_kernel, page_size=page_size,
-                          q_block=qb, tile_bits=tile_bits,
-                          page_bits=page_bits, capacity=capacity,
-                          v_width=v_width),
+                          per=per, n_pages=n_pages, q_block=qb,
+                          tile_bits=tile_bits, group_bits=group_bits,
+                          capacity=capacity, v_width=v_width),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, h * qb, v_width), q.dtype),
         interpret=resolve_interpret(interpret),

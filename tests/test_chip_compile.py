@@ -10,6 +10,7 @@ built is a failure, not a skip.  Shapes are the ones chip_smoke.py's server
 runs: 8 heads of 128, 4096 pages of 16 tokens.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,15 +148,11 @@ def test_pool_row_scatter_compiles_on_the_head_sharded_mesh(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def _pool_sized_results(v5e, layout, pages_bucket, layers=2):
+def _opt_step_lowered(v5e, layout, pages_bucket, layers=2):
     """TinyCausalLM's ragged step at opt-6.7b-d8's shapes (32 heads of
     128, a 1280-page pool, 80 packed rows under 17 descriptors; depth and
-    vocabulary cut, weights as shapes), pools donated, compiled for v5e:
-    the opcodes of the instructions that yield a whole pool and are not
-    the in-place row write, the count of those that are, and the
-    program's temporaries in bytes."""
-    import re
-
+    vocabulary cut, weights as shapes), pools donated, lowered for v5e;
+    and the pool's shape."""
     from paddle_tpu.generation.model import TinyCausalLM
 
     heads, pages, t, s, vocab = 32, 1280, 80, 17, 128
@@ -180,9 +177,17 @@ def _pool_sized_results(v5e, layout, pages_bucket, layers=2):
              + [sds((s,), "int32")] * 3)
     fn = model.ragged_step_fn(PAGE_SIZE, pages, use_kernel=True,
                               pool_layout=layout)
-    compiled = jax.jit(fn, donate_argnums=(9, 10)).lower(
-        params, *fixed, [sds(shape)] * layers, [sds(shape)] * layers
-    ).compile()
+    return jax.jit(fn, donate_argnums=(9, 10)).lower(
+        params, *fixed, [sds(shape)] * layers, [sds(shape)] * layers), shape
+
+
+def _pool_sized_results(v5e, layout, pages_bucket, layers=2):
+    """That step compiled: the opcodes of the instructions that yield a
+    whole pool and are not the in-place row write, the count of those
+    that are, and the program's temporaries in bytes."""
+    heads, pages = 32, 1280
+    lowered, shape = _opt_step_lowered(v5e, layout, pages_bucket, layers)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= layers
     either = "|".join(",".join(map(str, dims)) for dims in (
@@ -208,6 +213,41 @@ def test_ragged_step_moves_no_pool_in_kernel_layout(v5e, pages_bucket):
                                                        pages_bucket)
     assert (others, in_place) == (set(), 4)
     assert temp_bytes < 64 << 20
+
+
+# sha256 of the OPT step's lowered text with the kernels' source
+# locations dropped, by pages bucket; as PR 32's tree lowers it
+OPT_STEP_DIGESTS = {
+    16: "0403b9b78255bed19710606c5f416e0ad1eed21072a618b91efed296474d22e0",
+    128: "6e07098212d7b218f86a700a5b4b9d4e20b275cc60c22e80ab75c53b20fc2f6c",
+}
+
+
+@pytest.mark.parametrize("pages_bucket", sorted(OPT_STEP_DIGESTS))
+def test_the_opt_ragged_step_lowers_to_the_text_it_had(v5e, pages_bucket):
+    """The per-head kernel's step is not the latent kernel's: a change to
+    `latent_*` leaves opt-6.7b-d8's lowered step as it was.  The text
+    compared is the step's StableHLO with each Mosaic kernel's body (a
+    serialised module inside its custom call) read back and printed
+    without source locations, which name the checkout's path and every
+    line of the kernel; all else counts.  A PR that means to change what
+    the OPT cells run states the new digests here."""
+    import base64
+    import hashlib
+
+    from jax.extend.mlir import ir
+
+    lowered, _ = _opt_step_lowered(v5e, "kernel", pages_bucket)
+    body = re.compile(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+    text = lowered.as_text()
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        kernels = [ir.Module.parse(base64.b64decode(b)).operation.get_asm(
+            enable_debug_info=False) for b in body.findall(text)]
+    assert len(kernels) == 2 * 3        # a layer: two row writes, one attention
+    digest = hashlib.sha256(
+        (body.sub("BODY", text) + "".join(kernels)).encode()).hexdigest()
+    assert digest == OPT_STEP_DIGESTS[pages_bucket]
 
 
 def test_the_pool_check_tells_the_token_layout(v5e, monkeypatch):
@@ -276,6 +316,17 @@ def test_a_tpu_lowering_has_no_cost_analysis(v5e):
     assert lowered.compile().cost_analysis()["flops"] > 0
 
 
+def _custom_call_operands(text):
+    """The operands' types (``s32[17]``) of every `tpu_custom_call` of a
+    compiled module's text, which names its operands without them."""
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])",
+                            text, re.M))
+    return [[types.get(name, name) for name in m.group(1).split(", ")]
+            for m in re.finditer(
+                r" custom-call\(([^)]*)\), custom_call_target="
+                r'"tpu_custom_call"', text)]
+
+
 def _glm_cell():
     import json
     import os
@@ -292,10 +343,14 @@ def test_latent_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket):
     """glm-4.7-flash-d7.docqa-closed: 20 heads against one 640-lane latent
     row (576 numbers a token), 16 slots + a 64-token chunk = 80 packed
     rows under 17 descriptors, a 5760-page pool of 64-token pages.  The
-    tile-major work list, a q tile's 160 rows and an 80 KiB page block
-    lower, and the list fits SMEM at the 512-page bucket."""
+    tile-major list of 16-page cells (26 x bucket / 16 of them: 832 cell
+    words and 13,312 page words in SMEM at the 512-page bucket), a q
+    tile's 160 rows and the two 1.25 MiB halves of a cell's page block
+    lower, and nothing of the pool's size is made."""
     from paddle_tpu.generation.decode_attention import (
         latent_ragged_attention)
+    from paddle_tpu.ops.pallas.paged_attention import (
+        latent_grid_cells, latent_pages_per_cell)
 
     args, engine = _glm_cell()
     t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
@@ -314,6 +369,13 @@ def test_latent_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket):
     # the pool goes to the kernel as it is stored: a copy of it would
     # show as a temporary of the pool's size
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    per = latent_pages_per_cell(engine["page_size"], pages_bucket)
+    cells = latent_grid_cells(s, pages_bucket, t, engine["page_size"])
+    assert (per, cells) == (16, 26 * pages_bucket // 16)
+    # the list rides as the kernel's scalar-prefetch operands: the
+    # traced grid bound first, then the page slots and the cell words
+    (call,) = _custom_call_operands(compiled.as_text())
+    assert call[:3] == ["s32[]", f"s32[{cells * per}]", f"s32[{cells}]"]
 
 
 def test_latent_step_compiles_at_the_published_widths(v5e, monkeypatch):
@@ -323,8 +385,6 @@ def test_latent_step_compiles_at_the_published_widths(v5e, monkeypatch):
     operation but a layer's scatter produces a whole pool), and the
     kernels are there (7 latent calls, 6 layers x 3 grouped-product
     calls)."""
-    import re
-
     from paddle_tpu.generation import latent_moe_model as lm
 
     args, engine = _glm_cell()
@@ -356,6 +416,13 @@ def test_latent_step_compiles_at_the_published_widths(v5e, monkeypatch):
     assert memory.temp_size_in_bytes < 1 << 30
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 7 + 6 * 3
+    # ... which the benchmark tells apart by their first operand
+    # (benchmarks/trace/custom_calls.py): ONE latent call a layer, the
+    # s32 grid bound leading it and the list behind, or
+    # `kernel.latent_roofline` divides one call's floor by the wrong time
+    latent = [call for call in _custom_call_operands(text)
+              if call[:3] == ["s32[]", "s32[13312]", "s32[832]"]]
+    assert len(latent) == model.num_layers
     whole = [m.group(1) for m in re.finditer(
         r"^\s*%?([\w.\-]+) = bf16\[5760,64,640\]\S* (?!parameter)", text,
         re.M)]

@@ -169,19 +169,42 @@ def test_absorbed_attention_equals_the_expanded_form(model):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
+# pages a cell of the latent kernel holds in these tests: 4-token pages
+# under a 12- or 16-page table never fill the module's
+# `LATENT_CELL_TOKENS`, so the tests state a smaller one
+GROUP = 4
+
+
+@pytest.fixture
+def small_cells(monkeypatch):
+    monkeypatch.setattr(pa, "LATENT_CELL_TOKENS", GROUP * PAGE)
+
+
 MIXES = {
     "decode_rows": ([1, 1, 1], [5, 17, 1]),
     "chunk_beside_decode": ([1, 1, 9], [6, 12, 22]),
     "chunk_crossing_tiles": ([3, 11, 1], [3, 30, 9]),
     "all_padding": ([], []),
+    # one-token rows over contexts of 1, G - 1, G, G + 1 and 2G + 3 pages
+    "pages_1_g-1_g_g+1_2g+3": ([1, 1, 1, 1, 1], [
+        PAGE, (GROUP - 1) * PAGE, GROUP * PAGE, (GROUP + 1) * PAGE - 2,
+        (2 * GROUP + 3) * PAGE - 1]),
+    # a chunk that begins mid-tile (row 5) and whose rows cross a group
+    # boundary: positions 13..18, of which the first tile's rows see 4
+    # pages (one group) and the second tile's 5 (two)
+    "chunk_crossing_a_group": ([1, 1, 1, 1, 1, 6], [
+        3, GROUP * PAGE, 9, 2, (GROUP + 1) * PAGE, GROUP * PAGE + 3]),
+    "an_empty_descriptor_between": ([2, 0, 7], [
+        (GROUP + 1) * PAGE + 1, 0, 2 * GROUP * PAGE + 3]),
 }
 
 
 @pytest.mark.parametrize("mix", sorted(MIXES))
-def test_latent_kernel_in_interpret_mode_equals_the_jnp_form(mix):
+def test_latent_kernel_in_interpret_mode_equals_the_jnp_form(mix,
+                                                             small_cells):
     lens, kv = MIXES[mix]
     rng = np.random.default_rng(2)
-    h, width, v_width, pages, t, s_pad, mp = 3, 24, 16, 40, 20, 5, 8
+    h, width, v_width, pages, t, s_pad, mp = 3, 24, 16, 60, 20, 7, 12
     starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32) \
         if lens else np.zeros((0,), np.int32)
     pad = s_pad - len(lens)
@@ -199,18 +222,65 @@ def test_latent_kernel_in_interpret_mode_equals_the_jnp_form(mix):
         q, pool, pt, st, ln, kvl, 0.25, v_width)
     got = pa.latent_ragged_attention_kernel(
         q, pool, jnp.asarray(pt), st, ln, kvl, 0.25, v_width, interpret=True)
-    # the same float32 sums in another order (page by page, online)
+    # the same float32 sums in another order (a group at a time, online)
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
-    # the cells are the ragged kernel's, walked tile-major
-    _, cells, count = pa.latent_work_list(pt, st, ln, kvl, PAGE, t)
-    _, cells2, count2 = pa.ragged_work_list(pt, st, ln, kvl, PAGE, t)
+    # the live cells are the ragged kernel's, grouped and walked
+    # tile-major: each exactly once, beside its physical page
+    assert pa.latent_pages_per_cell(PAGE, mp) == GROUP
+    grouped = pa.latent_work_list(pt, st, ln, kvl, PAGE, t)
+    pages2, cells2, count2 = (np.asarray(x) for x in pa.ragged_work_list(
+        pt, st, ln, kvl, PAGE, t))
+    n2 = int(count2[0])
+    assert n2 == pa.ragged_score_blocks(st, ln, kvl, PAGE, mp, t)[0]
+    _assert_the_groups_hold(grouped, (pages2[:n2], cells2[:n2]), pt, st, ln,
+                            kvl, PAGE, t)
+    assert int(grouped[2][0]) == pa.latent_score_groups(
+        st, ln, kvl, PAGE, mp, t)
+
+
+def _assert_the_groups_hold(grouped, ragged, pt, st, ln, kvl, page_size, t):
+    """The grouped list against `ragged_work_list`'s live ``(pages,
+    cells)``: every live (descriptor, page, tile) cell sits in exactly
+    one slot beside its physical page; every other slot of a live group
+    is padding — a logical page past its tile's horizon, which the
+    kernel masks, holding a page of that descriptor's table (a valid
+    fetch); the tiles never go back; the tail repeats the last cell."""
+    s, mp = pt.shape
+    per = pa.latent_pages_per_cell(page_size, mp)
+    qb, n_tiles = pa.ragged_query_tiles(t)
+    tile_bits, group_bits = pa._cell_bits(s, -(-mp // per), n_tiles)
+    _, page_bits2 = pa._cell_bits(s, mp, n_tiles)
+    pages, cells, count = (np.asarray(x) for x in grouped)
     n = int(count[0])
-    assert n == int(count2[0]) == pa.ragged_score_blocks(
-        st, ln, kvl, PAGE, mp, t)[0]
-    assert sorted(np.asarray(cells)[:n]) == sorted(np.asarray(cells2)[:n])
-    tile_bits, _ = pa._cell_bits(s_pad, mp, pa.ragged_query_tiles(t)[1])
-    tiles = np.asarray(cells)[:n] & ((1 << tile_bits) - 1)
-    assert (np.diff(tiles) >= 0).all()
+    assert len(cells) == pa.latent_grid_cells(s, mp, t, page_size) >= n
+    assert len(pages) == len(cells) * per
+    pages = pages.reshape(-1, per)
+    want = {(int(c) >> page_bits2,
+             (int(c) >> tile_bits) & ((1 << (page_bits2 - tile_bits)) - 1),
+             int(c) & ((1 << tile_bits) - 1)): int(p)
+            for p, c in zip(*ragged)}
+    assert len(want) == len(ragged[0])
+    met = set()
+    for w in range(n):
+        cell = int(cells[w])
+        desc, tile = cell >> group_bits, cell & ((1 << tile_bits) - 1)
+        group = (cell >> tile_bits) & ((1 << (group_bits - tile_bits)) - 1)
+        # the tile's last in-span row and the pages it sees
+        last = min((tile + 1) * qb, st[desc] + ln[desc]) - 1
+        seen = (kvl[desc] - ln[desc] + last - st[desc]) // page_size + 1
+        for slot in range(per):
+            key = (desc, group * per + slot, tile)
+            if key in want:
+                assert key not in met and want[key] == pages[w, slot]
+                met.add(key)
+            else:
+                assert key[1] >= seen          # masked by col <= qpos
+                assert pages[w, slot] == pt[desc, seen - 1]
+    assert met == set(want)
+    if n:
+        assert (cells[n:] == cells[n - 1]).all()
+        assert (pages[n:] == pages[n - 1]).all()
+        assert (np.diff(cells[:n] & ((1 << tile_bits) - 1)) >= 0).all()
 
 
 def test_latent_step_with_the_kernel_equals_the_jnp_step(model):
@@ -481,15 +551,20 @@ def test_weights_are_seeded_and_a_large_seed_is_a_seed():
     assert 0.005 < bias.std() < 0.05
 
 
-def test_latent_work_list_holds_the_ragged_lists_cells_on_random_batches():
+def test_latent_work_list_holds_the_ragged_lists_cells_on_random_batches(
+        monkeypatch):
     """Random descriptor sets (padding descriptors, chunks that cross
-    tiles, contexts up to the table's width): the tile-major list holds
-    exactly the ragged list's (cell, page) pairs, its tail repeats the
-    last live cell, and its tiles never go back."""
+    tiles, contexts up to the table's width) under cells of 1 to 4
+    pages: the grouped tile-major list holds each of the ragged list's
+    (cell, page) pairs exactly once, its other slots are masked
+    padding, it never exceeds its capacity, its tail repeats the last
+    live cell, and its tiles never go back."""
     rng = np.random.default_rng(0)
-    for _ in range(16):
+    for _ in range(24):
         s, t = int(rng.integers(1, 7)), int(rng.integers(4, 60))
         ps, mp = int(rng.choice([2, 4, 8])), int(rng.integers(1, 12))
+        monkeypatch.setattr(pa, "LATENT_CELL_TOKENS",
+                            int(rng.integers(1, 5)) * ps)
         lens, kv = np.zeros(s, np.int32), np.zeros(s, np.int32)
         room = t
         for i in range(int(rng.integers(0, s + 1))):
@@ -500,15 +575,58 @@ def test_latent_work_list_holds_the_ragged_lists_cells_on_random_batches():
             room -= lens[i]
         starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
         pt = rng.integers(0, 50, (s, mp)).astype(np.int32)
-        pages, cells, count = (np.asarray(x) for x in pa.latent_work_list(
-            pt, starts, lens, kv, ps, t))
+        grouped = pa.latent_work_list(pt, starts, lens, kv, ps, t)
         pages2, cells2, count2 = (np.asarray(x) for x in pa.ragged_work_list(
             pt, starts, lens, kv, ps, t))
-        n = int(count[0])
-        assert n == int(count2[0])
-        assert sorted(zip(cells[:n], pages[:n])) == sorted(
-            zip(cells2[:n], pages2[:n]))
-        if n:
-            assert (cells[n:] == cells[n - 1]).all()
-            tile_bits, _ = pa._cell_bits(s, mp, pa.ragged_query_tiles(t)[1])
-            assert (np.diff(cells[:n] & ((1 << tile_bits) - 1)) >= 0).all()
+        n2 = int(count2[0])
+        _assert_the_groups_hold(grouped, (pages2[:n2], cells2[:n2]), pt,
+                                starts, lens, kv, ps, t)
+        assert int(grouped[2][0]) == pa.latent_score_groups(
+            starts, lens, kv, ps, mp, t) <= pa.latent_grid_cells(s, mp, t, ps)
+
+
+def test_the_grid_counter_of_a_latent_engine_counts_page_slots(model,
+                                                               small_cells):
+    """`generation.step_grid_cells` on the latent kernel: G x the grid
+    steps, so blocks over cells is live pages over page slots.  A
+    hand-built batch first — one-token rows over 3, 4 and 9 pages and a
+    6-row chunk over 5 that crosses from the first query tile into the
+    second, cells of 4 pages: 3 + 4 + 9 + 2 x 5 = 26 live pages in
+    1 + 1 + 3 + 2 x 2 = 9 groups, 36 slots — then an engine's own
+    steps; the gauge says which kernel the numbers belong to."""
+    eng = _engine(model, use_kernel=True)
+    step = eng._ragged
+    step.last_pages_bucket = 16
+    pad = [0] * (step.max_seqs - 4)
+    fixed = [None] * 5 + [np.array(x + pad, np.int32) for x in (
+        [0, 1, 2, 3], [1, 1, 1, 6],
+        [3 * PAGE, 4 * PAGE, 8 * PAGE + 1, 4 * PAGE + 2])]
+    step.count_kernel_cells(fixed)
+    assert (step.last_score_blocks, step.last_grid_cells) == (26, 9 * GROUP)
+    # nothing to attend: the kernel's one step, a group of empty slots
+    step.count_kernel_cells(
+        [None] * 5 + [np.zeros(step.max_seqs, np.int32)] * 3)
+    assert (step.last_score_blocks, step.last_grid_cells) == (0, GROUP)
+    h = eng.submit(PROMPT[:19], max_new_tokens=6)
+    blocks = slots = 0
+    while eng.scheduler.active() or eng.scheduler.pending_count():
+        eng.step()
+        shape = (step.last_pages_bucket, step.max_tokens)
+        per = pa.latent_pages_per_cell(PAGE, step.last_pages_bucket)
+        assert step.last_grid_cells % per == 0
+        assert step.last_score_blocks <= step.last_grid_cells <= (
+            per * pa.latent_grid_cells(step.max_seqs, *shape, PAGE))
+        blocks += step.last_score_blocks
+        slots += step.last_grid_cells
+    _assert_reference_argmax(model, PROMPT[:19],
+                             h.result(timeout=5).token_ids)
+    snap = eng.stats()
+    assert (snap["generation.step_score_blocks"],
+            snap["generation.step_grid_cells"]) == (blocks, slots)
+    assert 0 < blocks < slots          # 24 tokens: groups of 4 part full
+    assert snap["generation.latent_pages_per_cell"] == GROUP
+    assert snap["generation.kv_pool_layout"] == "latent"
+    eng.shutdown()
+    plain = _engine(model)             # the jnp form: no latent kernel
+    assert plain.stats()["generation.latent_pages_per_cell"] == 0
+    plain.shutdown()
