@@ -38,9 +38,11 @@ from .engine import (DEFAULT_PREFILL_CHUNK_TOKENS, GenerationConfig,
                      UnsupportedModelPathError)
 from .fused import (ChunkedPrefillStep, FusedDecodeStep,
                     LoopedRaggedStep, RaggedStep, decode_batch_menu)
-from .kv_cache import (DeviceKVPool, KVQuantMismatchError, LatentRows,
-                       OutOfPagesError, PagedKVCache,
-                       UnknownSequenceError, UnsupportedCachePathError)
+from .gqa_window_moe_model import GQAWindowMoELM
+from .kv_cache import (DeviceKVPool, HeadRows, KVQuantMismatchError,
+                       LatentRows, OutOfPagesError, PagedKVCache,
+                       UnknownSequenceError, UnsupportedCachePathError,
+                       WindowPageGroup)
 from .latent_moe_model import LatentMoELM
 from .metrics import GenerationMetrics
 from .model import TinyCausalLM
@@ -59,6 +61,7 @@ __all__ = [
     "GenerationRequest", "SequenceState", "SamplingParams", "sample_token",
     "sample_tokens_batch", "sample_tokens_device", "SampleStream",
     "GenerationMetrics", "TinyCausalLM", "LatentMoELM", "LatentRows",
+    "GQAWindowMoELM", "HeadRows", "WindowPageGroup",
     "UnsupportedModelPathError", "UnsupportedCachePathError",
     "FusedDecodeStep", "ChunkedPrefillStep", "RaggedStep",
     "LoopedRaggedStep", "decode_batch_menu",

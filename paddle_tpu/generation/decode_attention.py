@@ -439,3 +439,80 @@ def latent_ragged_attention(q, pool, page_tables, starts, lens, kv_lens,
         jnp.asarray(q), jnp.asarray(pool),
         jnp.asarray(page_tables, jnp.int32), starts, lens, kv_lens, scale,
         v_width, interpret=interpret, work=work)
+
+
+# ---------------- grouped-query heads over a row pool -----------------
+def gqa_ragged_attention_reference(q, pool, page_tables, starts, lens,
+                                   kv_lens, scale, kv_heads, window=None):
+    """Pure-jnp grouped-query attention over a paged row pool: the CPU
+    path and the oracle of `gqa_ragged_attention_kernel`, built like
+    `latent_ragged_attention_reference`.  q: [T, H, D], query head h
+    reading KV head ``h // (H / kv_heads)``; pool: [P, page_size, lanes]
+    rows ``[k_0 .. k_{n-1} | v_0 .. v_{n-1}]``; window: the keys a query
+    sees counting its own, ``qpos - window + 1 .. qpos`` (None: from 0).
+    A table entry behind a row's window may name a page that has gone
+    back to its free list: what is gathered from it is selected away
+    BEFORE any product, so nothing it holds (another sequence's rows, a
+    NaN) reaches an output.  Returns [T, H, D] float32; rows owned by no
+    descriptor come back exactly 0."""
+    q = jnp.asarray(q)
+    t, h, d = q.shape
+    rep = h // kv_heads
+    pt = jnp.asarray(page_tables, jnp.int32)
+    starts = jnp.asarray(starts, jnp.int32)
+    lens = jnp.asarray(lens, jnp.int32)
+    kv_lens = jnp.asarray(kv_lens, jnp.int32)
+    rows = jnp.asarray(pool)[pt]                  # [S, MP, page, lanes]
+    rows = rows.reshape(pt.shape[0], -1, rows.shape[-1])
+    row = jnp.arange(t, dtype=jnp.int32)[None, :]
+    mine = (row >= starts[:, None]) & (row < (starts + lens)[:, None])
+    qpos = (kv_lens - lens)[:, None] + (row - starts[:, None])   # [S, T]
+    col = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :]
+    visible = mine[:, :, None] & (col <= qpos[:, :, None])       # [S, T, K]
+    if window is not None:
+        visible = visible & (col > qpos[:, :, None] - window)
+    # a key some row of its descriptor sees; all else reads as zeros
+    used = jnp.any(visible, axis=1)[:, :, None]                  # [S, K, 1]
+    rows = jnp.where(used, rows, 0).astype(jnp.float32)
+    k = rows[..., :kv_heads * d].reshape(*rows.shape[:2], kv_heads, d)
+    v = rows[..., kv_heads * d:2 * kv_heads * d].reshape(k.shape)
+    qg = q.astype(jnp.float32).reshape(t, kv_heads, rep, d)
+    logits = jnp.einsum("tgrd,skgd->stgrk", qg, k,
+                        precision="highest") * scale
+    seen = visible[:, :, None, None, :]
+    weights = jax.nn.softmax(jnp.where(seen, logits, NEG_INF), axis=-1)
+    weights = jnp.where(seen, weights, 0.0)
+    return jnp.einsum("stgrk,skgd->tgrd", weights, v,
+                      precision="highest").reshape(t, h, d)
+
+
+def gqa_work_lists(starts, lens, kv_lens, page_size, n_pages, n_rows,
+                   window, use_kernel):
+    """`latent_work_list`'s sibling for a row pool with window and full
+    layers: ``{"window": list, "full": list}`` of the kernel's two
+    grids, each built once a step for all layers of its kind; Nones
+    where the jnp form runs."""
+    if not use_kernel:
+        return {"window": None, "full": None}
+    from ..ops.pallas.gqa_paged_attention import gqa_work_list as build
+
+    return {"window": build(starts, lens, kv_lens, page_size, n_pages,
+                            n_rows, window),
+            "full": build(starts, lens, kv_lens, page_size, n_pages,
+                          n_rows)}
+
+
+def gqa_ragged_attention(q, pool, page_tables, starts, lens, kv_lens, scale,
+                         kv_heads, window, use_kernel, interpret=None,
+                         work=None):
+    """Grouped-query attention over one layer's row pool: the Pallas
+    kernel, or the jnp form of the same."""
+    if not use_kernel:
+        return gqa_ragged_attention_reference(
+            q, pool, page_tables, starts, lens, kv_lens, scale, kv_heads,
+            window)
+    from ..ops.pallas.gqa_paged_attention import gqa_ragged_attention_kernel
+
+    return gqa_ragged_attention_kernel(
+        jnp.asarray(q), jnp.asarray(pool), page_tables, starts, lens,
+        kv_lens, scale, kv_heads, window, interpret=interpret, work=work)

@@ -71,7 +71,8 @@ from ..profiler import RecordEvent
 from ..serving.admission import RequestTooLargeError, ServingError
 from ..serving.bucketing import CompiledModelCache, ShapeBucketer
 from .decode_attention import paged_decode_attention
-from .kv_cache import DeviceKVPool, OutOfPagesError, PagedKVCache
+from .kv_cache import (DeviceKVPool, LatentRows, OutOfPagesError,
+                       PagedKVCache, WindowPageGroup)
 from .metrics import GenerationMetrics
 from .sampling import SamplingParams, sample_token, sample_tokens_batch
 from .scheduler import (ContinuousBatchingScheduler, GenerationRequest,
@@ -509,14 +510,19 @@ class GenerationHandle:
 
 class UnsupportedModelPathError(ValueError):
     """The configuration asks for a path this model does not implement
-    (a latent-cache model off the ragged step): refused when the engine
-    is built, never mis-served."""
+    (a model that keeps a row cache, off the ragged step): refused when
+    the engine is built, never mis-served."""
 
 
-def _refuse_latent_paths(model, config):
-    """A model with `kv_rows` (a latent cache) is served by the ragged
+def _refuse_paths_off_the_ragged_step(model, config, windowed):
+    """A model with `kv_rows` (one row a token and layer: a latent
+    cache, grouped-query heads side by side) is served by the ragged
     step over device pools and by nothing else; every option that
-    selects another path is refused here, once, by name."""
+    selects another path is refused here, once, by name.  `windowed`:
+    some of its layers keep only a window of tokens
+    (`kv_layer_kinds`), which also rules the prefix cache out: a hit
+    would need, for those layers, the last window of the matched
+    prefix, and its first owner has given those pages back."""
     asked = {
         "kv_backend='host'": config.kv_backend == "host",
         f"decode={config.decode!r}": config.decode is not None,
@@ -529,14 +535,17 @@ def _refuse_latent_paths(model, config):
             config.pool_layout not in (None, "token"),
         "mesh": config.mesh is not None,
         "prefill_chunk_tokens=0": config.prefill_chunk_tokens == 0,
+        "prefix_cache=True": windowed and config.prefix_cache is True,
     }
     bad = [name for name, hit in asked.items() if hit]
     if bad:
         raise UnsupportedModelPathError(
-            f"{type(model).__name__} keeps a latent cache and is served "
-            f"by the ragged step over a device pool in the model's own "
-            f"dtype, with chunked prefill; not carried for it: "
-            f"{', '.join(bad)}")
+            f"{type(model).__name__} keeps one row a token in its cache "
+            f"and is served by the ragged step over a device pool in the "
+            f"model's own dtype, with chunked prefill"
+            + (" and, its window layers giving pages back, without the "
+               "prefix cache" if windowed else "")
+            + f"; not carried for it: {', '.join(bad)}")
 
 
 class GenerationEngine:
@@ -555,8 +564,14 @@ class GenerationEngine:
         # cache): everything model-specific is settled here, while the
         # engine is built, and never asked again inside a step
         kv_rows = model.kv_rows() if hasattr(model, "kv_rows") else None
+        # ... and which of its layers keep only a window of tokens: the
+        # pool then holds a second page table and free list for them
+        kinds, window_tokens = (model.kv_layer_kinds()
+                                if hasattr(model, "kv_layer_kinds")
+                                else ((), 0))
+        windowed = "window" in kinds
         if kv_rows is not None:
-            _refuse_latent_paths(model, self.config)
+            _refuse_paths_off_the_ragged_step(model, self.config, windowed)
         # tensor-parallel mesh: sharded decode is device-pool + fused
         # by construction, so the mesh flips both auto policies
         mesh = self.config.mesh
@@ -600,12 +615,24 @@ class GenerationEngine:
                         self.config.kv_dtype):
                     pool_layout = "kernel"
         if backend == "device":
+            window = None
+            if windowed:
+                # `num_pages` is the full layers' group; the window
+                # group is sized so that it cannot run out: every slot
+                # and one sequence being admitted at the most pages a
+                # sequence holds there (its window and one chunk)
+                chunk = (self.config.prefill_chunk_tokens
+                         or DEFAULT_PREFILL_CHUNK_TOKENS)
+                cap = WindowPageGroup.pages_a_sequence(
+                    self.config.page_size, window_tokens, chunk)
+                window = (kinds, window_tokens,
+                          (self.config.max_decode_slots + 1) * cap, chunk)
             self.cache = DeviceKVPool(
                 model.num_layers, model.num_heads, model.head_dim,
                 num_pages=self.config.num_pages,
                 page_size=self.config.page_size,
                 dtype=self.config.kv_dtype, pool_layout=pool_layout,
-                mesh=mesh, tp_axis=tp_axis, rows=kv_rows)
+                mesh=mesh, tp_axis=tp_axis, rows=kv_rows, window=window)
         else:
             if pool_layout == "kernel":
                 raise ValueError(
@@ -780,7 +807,9 @@ class GenerationEngine:
         # identity is itself oracle-tested, tests/test_prefix_cache.py).
         prefix_ok = bool(chunk) or chunk_eager_ok
         prefix = self.config.prefix_cache
-        if prefix is None:
+        if windowed:
+            prefix = False   # True was refused above; auto resolves off
+        elif prefix is None:
             # auto requires chunked prefill to actually be ON, not just
             # an eager chunk protocol: with chunking off, a warm hit's
             # suffix runs the per-layer eager loop — the path the chunk
@@ -873,11 +902,18 @@ class GenerationEngine:
         self.metrics.set_kernel_path(self.decode_mode, self._use_kernel)
         # and which layout the KV pool is stored in, beside it
         self.metrics.set_kv_pool_layout(
-            "latent" if kv_rows is not None else self.cache.pool_layout)
+            kv_rows.layout if kv_rows is not None
+            else self.cache.pool_layout)
+        # ... which kinds of layer it serves, how many of each, and the
+        # window the window layers keep (0: every layer keeps all)
+        self.metrics.set_kv_layer_groups(
+            {kind: kinds.count(kind) for kind in sorted(set(kinds))}
+            or {"full": int(model.num_layers)},
+            window_tokens if windowed else 0)
         # ... and, for a latent pool the kernel reads, the pages a grid
         # step of that kernel holds (0: no latent kernel runs)
         pages_per_cell = 0
-        if kv_rows is not None and self._use_kernel:
+        if isinstance(kv_rows, LatentRows) and self._use_kernel:
             from ..ops.pallas.paged_attention import latent_pages_per_cell
 
             pages_per_cell = latent_pages_per_cell(self.cache.page_size,
@@ -1671,6 +1707,12 @@ class GenerationEngine:
         """A ragged step's closing counters, under their own span so
         that what the accounting costs is itself visible."""
         with RecordEvent("generation::account"):
+            if self.cache.window_group is not None:
+                # pages behind every live sequence's window go back to
+                # the window group, and its counters out
+                self.cache.release_window_pages()
+                self.metrics.count_window_pages(
+                    *self.cache.window_group.take_counters())
             self._drain_kv_bytes()
             self._observe_occupancy()
 
@@ -1838,10 +1880,15 @@ class GenerationEngine:
                                 lens)
         pages = pt[desc_of_row, pos_all // ps]
         rows = pos_all % ps
-        fixed = self._ragged.pad(
-            np.asarray(tokens, np.int32), pos_all, pages, rows, pt,
-            starts, lens, kv_lens)
-        return fixed, spec_rows
+        packed = (np.asarray(tokens, np.int32), pos_all, pages, rows, pt,
+                  starts, lens, kv_lens)
+        if self.cache.window_group is not None:
+            # the same rows and descriptors through the window group's
+            # tables: where the window layers write and read them
+            w_pt = self.cache.window_group.gather_tables(desc_ids,
+                                                         pt.shape[1])
+            packed += ((w_pt[desc_of_row, pos_all // ps], w_pt),)
+        return self._ragged.pad(*packed), spec_rows
 
     def _apply_ragged_spec(self, samplers, spec_rows, b, fetched, greedy):
         """The speculative step's sampling half, over its ONE host
@@ -2416,7 +2463,12 @@ class GenerationEngine:
             # evicts refcount-0 cache pages (LRU) before failing, so a
             # resident prefix cache is never a reason to preempt a live
             # sequence
-            if need <= self.cache.available_pages:
+            wg = self.cache.window_group
+            if need <= self.cache.available_pages and (
+                    wg is None or sum(
+                        wg.pages_needed(s.seq_id,
+                                        self.cache.seq_len(s.seq_id) + 1)
+                        for s in active) <= wg.free_pages):
                 return active
             victim = self.scheduler.preempt_youngest()
             if victim is not None:

@@ -177,9 +177,10 @@ def _state_structs(jax_mod, cache, mesh, num_layers, quant):
     pre-warm lowers the REAL signature."""
     sds = jax_mod.ShapeDtypeStruct
     if cache.rows is not None:
-        # a latent cache: one pool a layer, never sharded
-        pool = cache.latent_pool(0)
-        return [sds(tuple(pool.shape), pool.dtype)] * num_layers
+        # a row cache: one pool a layer (a window layer's holds the
+        # window group's pages), never sharded
+        return [sds(tuple(pool.shape), pool.dtype)
+                for pool in map(cache.latent_pool, range(num_layers))]
     pool = cache.layer_pools(0)[0]
     if mesh is not None:
         pool_sds = sds(tuple(pool.shape), pool.dtype,
@@ -564,8 +565,12 @@ class RaggedStep:
             pool_layout=cache.pool_layout, **step_kw)
         # fixed args: (tokens, positions, pages, rows, page_tables,
         #              starts, lens, kv_lens); pool state donated after
-        # them (scale groups trail the pools for quantized caches)
-        self._n_fixed = 8
+        # them (scale groups trail the pools for quantized caches).  A
+        # cache with a window group adds that group's (pages,
+        # page_tables): its rows are written, and its layers read,
+        # through a table of their own
+        self._window_group = cache.window_group
+        self._n_fixed = 8 if self._window_group is None else 10
         wrapped = _wrap_donating(
             self._num_layers, self._param_tree, jax,
             lambda params, f, *gs: fn(params, *f, *gs),
@@ -605,9 +610,12 @@ class RaggedStep:
         sds = self._jax.ShapeDtypeStruct
         i32 = np.dtype(np.int32)
         t, s = self.max_tokens, self.max_seqs
-        return [sds((t,), i32), sds((t,), i32), sds((t,), i32),
-                sds((t,), i32), sds((s, bucket_p), i32),
-                sds((s,), i32), sds((s,), i32), sds((s,), i32)]
+        fixed = [sds((t,), i32), sds((t,), i32), sds((t,), i32),
+                 sds((t,), i32), sds((s, bucket_p), i32),
+                 sds((s,), i32), sds((s,), i32), sds((s,), i32)]
+        if self._window_group is not None:
+            fixed += [sds((t,), i32), sds((s, bucket_p), i32)]
+        return fixed
 
     def prewarm(self, pages_cols):
         """AOT-compile the executable for a pages bucket WITHOUT
@@ -627,13 +635,15 @@ class RaggedStep:
         return self._exec.compile_count > before
 
     def pad(self, tokens, positions, pages, rows, page_tables, starts,
-            lens, kv_lens):
+            lens, kv_lens, window=None):
         """The executable's eight fixed arguments from the PACKED host
         arrays (the engine built them at exact sizes): the token axis
         padded to `max_tokens` with inert slots (sentinel page,
         position 0), the descriptor axis to `max_seqs` with len-0
         descriptors, and the page-table axis to its pages bucket.  Host
-        work only — what `dispatch` takes."""
+        work only — what `dispatch` takes.  `window`: the window
+        group's ``(pages, page_tables)`` of the same rows and
+        descriptors, padded alike into a ninth and tenth argument."""
         t_real = len(tokens)
         s_real = len(starts)
         if t_real > self.max_tokens:
@@ -663,13 +673,23 @@ class RaggedStep:
         ln[:s_real] = lens
         kv = np.zeros((s,), np.int32)
         kv[:s_real] = kv_lens
+        extra = []
+        if window is not None:
+            w_pages, w_tables = window
+            wpg = np.full((t,), self._window_group.num_pages, np.int32)
+            wpg[:t_real] = w_pages
+            wpt = np.zeros((s, bucket_p), np.int32)
+            w_tables = np.asarray(w_tables, np.int32)
+            if w_tables.size:
+                wpt[:s_real, :w_tables.shape[1]] = w_tables
+            extra = [wpg, wpt]
         self.last_pages_bucket = bucket_p
         self.last_rows_useful = t_real
         self.last_rows_dispatched = t
         self.last_collective_bytes = _collective_bytes_estimate(
             self._num_layers, t, self._d_model, self._tp,
             quantized=self._quant_collectives)
-        return [tok, pos, pg, rw, pt, st, ln, kv]
+        return [tok, pos, pg, rw, pt, st, ln, kv, *extra]
 
     def dispatch(self, fixed):
         """The ONE donated dispatch of a step over `pad`'s arguments.
@@ -708,13 +728,17 @@ class RaggedStep:
             return
         from ..ops.pallas import paged_attention as pa
 
-        st, ln, kv = fixed[5:]
+        st, ln, kv = fixed[5:8]
         bucket_p = self.last_pages_bucket
         page_size = self._cache.page_size
+        rows = self._cache.rows
+        if getattr(rows, "kv_heads", None):
+            self._count_gqa_cells(st, ln, kv, bucket_p, page_size)
+            return
         self.last_score_blocks, self.last_score_blocks_untiled = \
             pa.ragged_score_blocks(st, ln, kv, page_size, bucket_p,
                                    self.max_tokens)
-        if self._cache.rows is None:
+        if rows is None:
             self.last_grid_cells = pa.ragged_grid_cells(
                 self.max_seqs, bucket_p, self.max_tokens,
                 live=self.last_score_blocks)
@@ -727,6 +751,36 @@ class RaggedStep:
                 self.max_seqs, bucket_p, self.max_tokens, page_size,
                 live=pa.latent_score_groups(st, ln, kv, page_size, bucket_p,
                                             self.max_tokens))
+
+    def _count_gqa_cells(self, st, ln, kv, bucket_p, page_size):
+        """The grouped-query kernel's two lists (window, full), each
+        weighed by the layers that walk it and the sum brought back to
+        one layer: `last_score_blocks` the (tile, page) pairs between a
+        tile's horizons, `last_grid_cells` the page SLOTS of the cells
+        walked for them (G a cell), so blocks over cells reads how full
+        the groups are; the untiled count is what the full list would
+        be were every layer a full one."""
+        from ..ops.pallas import gqa_paged_attention as gq
+
+        per = gq.gqa_pages_per_cell(page_size, bucket_p)
+        kinds = self._cache.layer_kinds or ("full",) * self._num_layers
+        blocks = cells = 0
+        for kind, window in (("full", None),
+                             ("window", getattr(self._window_group,
+                                                "window", None))):
+            layers = kinds.count(kind)
+            if not layers and kind == "window":
+                continue
+            pages, live = gq.gqa_score_cells(
+                st, ln, kv, page_size, bucket_p, self.max_tokens, window)
+            if kind == "full":
+                self.last_score_blocks_untiled = pages
+            blocks += layers * pages
+            cells += layers * per * gq.gqa_grid_cells(
+                self.max_seqs, bucket_p, self.max_tokens, page_size, window,
+                live=live)
+        self.last_score_blocks = blocks // len(kinds)
+        self.last_grid_cells = max(cells // len(kinds), 1)
 
 
 class LoopedRaggedStep:

@@ -108,13 +108,14 @@ class UnknownSequenceError(KeyError):
                 f"already freed ({self.live_count} live sequence(s))")
 
 
-class LatentRows:
-    """What a latent-attention model tells `DeviceKVPool` a token's
-    cache row is: ONE row of `width` numbers a layer (the compressed kv
-    and the rotated shared key, side by side), in `dtype`, of which the
-    first `value_width` are also every head's value.  No head axis and
-    no V pool: a layer's pool is ``[num_pages, page_size, lanes]``, as
-    the latent kernel reads it, so no step relays the pool out.
+class TokenRows:
+    """What a model tells `DeviceKVPool` a token's cache row is: ONE row
+    of `width` numbers a layer in `dtype`, no head axis and no V pool.
+    A layer's pool is ``[num_pages, page_size, lanes]``, as its kernel
+    reads it, so no step relays the pool out; `layout` is what the
+    `generation.kv_pool_layout` gauge says of such a pool.  The ONE
+    description of a row: the pool's constructor, the step's shapes and
+    the `kv_token_bytes` gauge read it here.
 
     `lanes` is the stored width: `width` up to a whole number of
     128-lane vregs, zeros past `width`.  A trailing dimension that is
@@ -123,13 +124,11 @@ class LatentRows:
     (compile-only for v5e, PR 28: 576 wide copied 424 MB a call, 640
     wide none).  The tiled layout would pad to the same bytes anyway."""
 
-    def __init__(self, width, value_width, dtype):
+    layout = "rows"
+
+    def __init__(self, width, dtype):
         self.width = int(width)
-        self.value_width = int(value_width)
         self.dtype = np.dtype(dtype)
-        if not 0 < self.value_width <= self.width:
-            raise ValueError(
-                f"value_width {value_width} outside (0, width={width}]")
 
     @property
     def lanes(self):
@@ -138,6 +137,182 @@ class LatentRows:
     def token_bytes(self, num_layers):
         """Logical bytes a cached token costs over `num_layers`."""
         return self.width * self.dtype.itemsize * int(num_layers)
+
+
+class LatentRows(TokenRows):
+    """A latent-attention model's row: the compressed kv and the
+    rotated shared key side by side, of which the first `value_width`
+    numbers are also every head's value."""
+
+    layout = "latent"
+
+    def __init__(self, width, value_width, dtype):
+        super().__init__(width, dtype)
+        self.value_width = int(value_width)
+        if not 0 < self.value_width <= self.width:
+            raise ValueError(
+                f"value_width {value_width} outside (0, width={width}]")
+
+
+class HeadRows(TokenRows):
+    """A grouped-query model's row: the keys of its `kv_heads` heads
+    and then their values, ``[k_0 .. k_{n-1} | v_0 .. v_{n-1}]``,
+    `head_dim` numbers each.  With heads of 128 every head's key and
+    value is a whole 128-lane slice of the row, which is how
+    `gqa_ragged_attention_kernel` splits a fetched block."""
+
+    layout = "kv_rows"
+
+    def __init__(self, kv_heads, head_dim, dtype):
+        super().__init__(2 * int(kv_heads) * int(head_dim), dtype)
+        self.kv_heads = int(kv_heads)
+        self.head_dim = int(head_dim)
+
+
+class WindowPageGroup:
+    """The page table and the free list of the layers that keep only
+    the last `window` tokens of a sequence (a query at position p sees
+    keys p - window + 1 .. p), beside the cache's own, which serve the
+    layers that keep everything.
+
+    A sequence's table maps LOGICAL pages as the full group's does
+    (position t at ``table[t // page_size]``), so both groups share
+    positions, rows and descriptors; what differs is that a page whose
+    last token no later query can see goes back to this group's free
+    list while the sequence lives (`release_behind`), in work
+    proportional to the pages released.  Entries under `first_live`
+    are stale page ids: the window kernel's list starts at its horizon
+    and never reads them.
+
+    `reserve_tokens` is the most one reservation appends (the engine's
+    prefill chunk): a sequence then never holds more than
+    `sequence_cap` pages, which is what admission charges a long
+    prompt here."""
+
+    def __init__(self, num_pages, page_size, window, reserve_tokens):
+        self.num_pages = int(num_pages)
+        self.page_size = int(page_size)
+        self.window = int(window)
+        self.reserve_tokens = max(int(reserve_tokens), 1)
+        if self.num_pages < 1 or self.window < 1:
+            raise ValueError("num_pages and window must be >= 1")
+        self._free = list(range(self.num_pages - 1, -1, -1))
+        self._tables = {}    # seq_id -> [page ids], logical order
+        self._first = {}     # seq_id -> first live logical page
+        # cumulative; `take_counters` drains what is new
+        self.pages_reserved = 0
+        self.pages_released = 0   # behind the window, sequence alive
+        self._taken = (0, 0)
+        self.peak_held = 0        # most pages one sequence ever held
+
+    @staticmethod
+    def pages_a_sequence(page_size, window, reserve_tokens):
+        """The most pages one sequence holds: its window, one
+        reservation ahead of it, and the partial pages at both ends."""
+        return -(-(int(window) + int(reserve_tokens)) // int(page_size)) + 1
+
+    @property
+    def sequence_cap(self):
+        return self.pages_a_sequence(self.page_size, self.window,
+                                     self.reserve_tokens)
+
+    @property
+    def free_pages(self):
+        return len(self._free)
+
+    def pages_for(self, tokens):
+        """What admission charges a context of `tokens` here."""
+        return min(-(-int(tokens) // self.page_size), self.sequence_cap)
+
+    def held(self, seq_id):
+        return len(self._tables[seq_id]) - self._first[seq_id]
+
+    def first_live(self, seq_id):
+        return self._first[seq_id]
+
+    def table(self, seq_id):
+        return self._tables[seq_id]
+
+    def allocate(self, seq_id):
+        self._tables[seq_id] = []
+        self._first[seq_id] = 0
+
+    def free(self, seq_id):
+        """Every live page of `seq_id` back to the free list (not
+        counted as released: the sequence is gone)."""
+        table = self._tables.pop(seq_id)
+        first = self._first.pop(seq_id)
+        self._free.extend(reversed(table[first:]))
+
+    def pages_needed(self, seq_id, new_len):
+        return max(-(-int(new_len) // self.page_size)
+                   - len(self._tables[seq_id]), 0)
+
+    def check(self, seq_id, new_len):
+        need = self.pages_needed(seq_id, new_len)
+        if need > len(self._free):
+            raise OutOfPagesError(
+                f"need {need} window-group pages for {seq_id!r}, only "
+                f"{len(self._free)} of {self.num_pages} free")
+
+    def grow(self, seq_id, new_len):
+        """Pages for positions up to `new_len` (checked by `check`)."""
+        table = self._tables[seq_id]
+        need = self.pages_needed(seq_id, new_len)
+        for _ in range(need):
+            table.append(self._free.pop())
+        self.pages_reserved += need
+        self.peak_held = max(self.peak_held,
+                             len(table) - self._first[seq_id])
+
+    def release_behind(self, seq_id, length):
+        """Give back the pages wholly behind the window of the NEXT
+        query of a sequence `length` tokens long (position `length`
+        sees keys from ``length - window + 1``).  Returns the count."""
+        first = self._first[seq_id]
+        upto = min(max(0, (int(length) - self.window + 1)
+                       // self.page_size), len(self._tables[seq_id]))
+        if upto <= first:
+            return 0
+        table = self._tables[seq_id]
+        self._free.extend(table[first:upto])
+        self._first[seq_id] = upto
+        self.pages_released += upto - first
+        return upto - first
+
+    def truncate(self, seq_id, new_len):
+        """Tail pages past `new_len` back to the free list.  A rewind
+        whose next query would see a released page is refused."""
+        first = self._first[seq_id]
+        if first and int(new_len) - self.window + 1 < first * self.page_size:
+            raise ValueError(
+                f"truncate({seq_id!r}) to {new_len} tokens would read "
+                f"pages released behind the {self.window}-token window "
+                f"(the first kept page starts at "
+                f"{first * self.page_size})")
+        table = self._tables[seq_id]
+        keep = max(-(-int(new_len) // self.page_size), first)
+        dropped = table[keep:]
+        del table[keep:]
+        self._free.extend(reversed(dropped))
+        return len(dropped)
+
+    def take_counters(self):
+        """(reserved, released) pages since the last take."""
+        now = (self.pages_reserved, self.pages_released)
+        out = (now[0] - self._taken[0], now[1] - self._taken[1])
+        self._taken = now
+        return out
+
+    def gather_tables(self, seq_ids, max_pages):
+        """``[B, max_pages]`` int32 of the sequences' logical tables;
+        released and unused slots hold page 0 (never read: the list
+        starts at the horizon; a valid DMA target all the same)."""
+        pt = np.zeros((len(seq_ids), max_pages), np.int32)
+        for i, sid in enumerate(seq_ids):
+            table, first = self._tables[sid], self._first[sid]
+            pt[i, first:len(table)] = table[first:]
+        return pt
 
 
 class UnsupportedCachePathError(NotImplementedError):
@@ -204,6 +379,11 @@ class PagedKVCache:
     # fleet adopted once outlives a local run untouched for this many
     # recency events.  Zero disables the fold (pure-LRU ablation).
     fleet_demand_boost = 256
+
+    # a WindowPageGroup where some layers keep only a window of tokens
+    # (DeviceKVPool(layer_kinds=...)); every other cache has ONE group,
+    # this class's own tables and free list
+    window_group = None
 
     def __init__(self, num_layers, num_heads, head_dim, num_pages=256,
                  page_size=16, dtype=np.float32):
@@ -324,6 +504,8 @@ class PagedKVCache:
             raise ValueError(f"sequence {seq_id!r} already allocated")
         self._tables[seq_id] = []
         self._lens[seq_id] = 0
+        if self.window_group is not None:
+            self.window_group.allocate(seq_id)
 
     def free(self, seq_id):
         """Release `seq_id`'s hold on its pages — a DECREF per page, not
@@ -340,6 +522,8 @@ class PagedKVCache:
         del self._lens[seq_id]
         for page in reversed(pages):   # reversed: LIFO warm reuse
             self._decref(page)
+        if self.window_group is not None:
+            self.window_group.free(seq_id)
 
     def has(self, seq_id):
         return seq_id in self._tables
@@ -376,6 +560,11 @@ class PagedKVCache:
         never touch storage another sequence (or the prefix index)
         still reads."""
         need = self.pages_needed(seq_id, new_tokens)
+        wg = self.window_group
+        if wg is not None:
+            # both groups or neither: the window group's shortfall is
+            # raised before anything is taken here
+            wg.check(seq_id, self._lens[seq_id] + new_tokens)
         if need > len(self._free):
             self._evict_prefix(need - len(self._free))
         if need > len(self._free):
@@ -390,7 +579,20 @@ class PagedKVCache:
             table.append(self._take_owned_page())
         start = self._lens[seq_id]
         self._lens[seq_id] = start + new_tokens
+        if wg is not None:
+            wg.grow(seq_id, start + new_tokens)
         return start
+
+    def release_window_pages(self):
+        """Give back, for every live sequence, the window-group pages
+        that no later query of it can see; returns the pages released.
+        Host work over the live sequences and the pages released, never
+        over a context.  0 from a cache with one group."""
+        wg = self.window_group
+        if wg is None:
+            return 0
+        return sum(wg.release_behind(seq_id, length)
+                   for seq_id, length in self._lens.items())
 
     def truncate(self, seq_id, new_len):
         """REWIND `seq_id` to exactly `new_len` resident tokens — the
@@ -439,6 +641,10 @@ class PagedKVCache:
             return 0
         keep = math.ceil(new_len / self.page_size)
         dropped = table[keep:]
+        if self.window_group is not None:
+            # refuses, before anything moves, a rewind behind what the
+            # window layers still hold
+            self.window_group.truncate(seq_id, new_len)
         for page in dropped:
             if self._page_shared(page):
                 raise ValueError(
@@ -1746,23 +1952,41 @@ class DeviceKVPool(PagedKVCache):
 
     def __init__(self, num_layers, num_heads, head_dim, num_pages=256,
                  page_size=16, dtype=np.float32, pool_layout="token",
-                 mesh=None, tp_axis=None, rows=None):
+                 mesh=None, tp_axis=None, rows=None, window=None):
         if pool_layout not in ("token", "kernel"):
             raise ValueError(
                 f"pool_layout must be 'token' or 'kernel', got "
                 f"{pool_layout!r}")
         self.pool_layout = pool_layout
-        # a LatentRows: ONE pool a layer, [P, page_size, lanes], written
-        # only inside the ragged step (see "latent pools" below)
+        # a TokenRows: ONE pool a layer, [P, page_size, lanes], written
+        # only inside the ragged step (see "row pools" below)
         self.rows = rows
+        # window: (layer kinds, window tokens, window-group pages, the
+        # most tokens one reservation appends) where some layers keep
+        # only a window: those layers' pools hold the window group's
+        # pages, the others' this cache's own `num_pages`
+        self.layer_kinds = None
         if rows is not None:
             if mesh is not None or pool_layout != "token":
                 raise UnsupportedCachePathError(
-                    "a latent pool has no head axis to shard or to "
+                    "a row pool has no head axis to shard or to "
                     "transpose: mesh and pool_layout='kernel' do not "
                     "apply")
             dtype = rows.dtype
             self._count_write_payload = self._count_latent_payload
+        if window is not None:
+            if rows is None:
+                raise UnsupportedCachePathError(
+                    "window layers are carried by row pools alone")
+            kinds, tokens, pages, reserve_tokens = window
+            if len(kinds) != int(num_layers) or set(kinds) - {
+                    "window", "full"}:
+                raise ValueError(
+                    f"layer kinds {kinds!r}: one of 'window' / 'full' "
+                    f"for each of {num_layers} layers")
+            self.layer_kinds = tuple(kinds)
+            self.window_group = WindowPageGroup(
+                pages, page_size, tokens, reserve_tokens)
         self.mesh = mesh
         self.tp_axis = None
         self.tp_degree = 1
@@ -1819,9 +2043,13 @@ class DeviceKVPool(PagedKVCache):
                 z = jax.device_put(z, self._sharding)
             return z
 
+        if self.rows is not None:
+            self._k = [jnp.zeros(self._row_pool_shape(layer), self.dtype)
+                       for layer in range(self.num_layers)]
+            self._v = []
+            return
         self._k = [zeros() for _ in range(self.num_layers)]
-        self._v = ([] if self.rows is not None
-                   else [zeros() for _ in range(self.num_layers)])
+        self._v = [zeros() for _ in range(self.num_layers)]
         if self.quantized:
             def zscale():
                 z = jnp.zeros((self.num_pages, self.num_heads),
@@ -1847,8 +2075,7 @@ class DeviceKVPool(PagedKVCache):
             if self.quantized:
                 raise UnsupportedCachePathError(
                     "int8 latent pools are not carried")
-            self._materialize_pools(
-                (self.num_pages, self.page_size, self.rows.lanes))
+            self._materialize_pools(None)
             return
         if self.pool_layout == "kernel":
             shape = (self.num_heads, self.num_pages, self.page_size,
@@ -2121,11 +2348,21 @@ class DeviceKVPool(PagedKVCache):
     # over) and copied page to page on a copy-on-write.  What reads or
     # writes per-head K and V (the eager and fused-decode paths, the
     # disaggregated fleet's page payloads) is refused by name.
+    def _row_pool_shape(self, layer):
+        """[pages, page_size, lanes] of one layer's row pool: the
+        window group's pages for a window layer, else this cache's."""
+        pages = (self.window_group.num_pages
+                 if self.layer_kinds is not None
+                 and self.layer_kinds[layer] == "window"
+                 else self.num_pages)
+        return (pages, self.page_size, self.rows.lanes)
+
     def _refuse_latent(self, what):
         if self.rows is not None:
             raise UnsupportedCachePathError(
-                f"{what} was asked of a latent pool, which holds one "
-                f"[{self.rows.lanes}]-lane row a token and no K or V")
+                f"{what} was asked of a row pool, which holds one "
+                f"[{self.rows.lanes}]-lane row a token and no K or V "
+                f"pool")
 
     def latent_pool(self, layer):
         """One layer's live latent pool [P, page_size, lanes]."""
@@ -2223,7 +2460,8 @@ class DeviceKVPool(PagedKVCache):
         pages whose bytes were just zeroed, and a later warm hit
         against them would silently generate from garbage — stale
         cache entries must die with the content they indexed."""
-        self._materialize_pools(self._k[0].shape)
+        self._materialize_pools(None if self.rows is not None
+                                else self._k[0].shape)
         self.flush_prefix_cache()
 
     def _canonical(self, pool):

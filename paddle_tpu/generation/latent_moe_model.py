@@ -37,20 +37,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import decode_attention, moe
+from . import decode_attention
+from .blocks import STEP_COUNTERS, DeviceDraw, feed_forward, rms_norm
 from .kv_cache import LatentRows
-
-# what a step counts, in the order of its third output:
-# generation.moe_* summed over the expert layers (`moe.STATS`)
-STEP_COUNTERS = tuple(f"generation.moe_{name}" for name in (
-    "assignments_total", "assignments_max_expert", "experts_touched"))
-
-
-def rms_norm(x, gain, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + eps)
-    return (y * gain).astype(x.dtype)
 
 
 def rotate(x, positions, theta):
@@ -110,28 +99,8 @@ class LatentMoELM:
 
     # ----------------------------- weights ---------------------------
     def _draw(self, seed):
-        # any whole number up to a little over 2**31 is a seed
-        root = jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF),
-                                  seed >> 31)
-        count = iter(range(1 << 20))
-        dt = self.dtype
-
-        draws = {}      # one program a (shape, scale, dtype), not a tensor
-
-        def w(*shape, scale=None, dtype=dt):
-            scale = 1.0 / math.sqrt(shape[-2]) if scale is None else scale
-            key = jax.random.fold_in(root, next(count))
-            if (shape, scale, dtype) not in draws:
-                draws[shape, scale, dtype] = jax.jit(
-                    lambda k: (jax.random.normal(k, shape, jnp.float32)
-                               * scale).astype(dtype))
-            return draws[shape, scale, dtype](key)
-
-        def gain(n):
-            # not all ones: a norm whose gain is dropped has to show
-            key = jax.random.fold_in(root, next(count))
-            return 1.0 + 0.1 * jax.random.normal(key, (n,), jnp.float32)
-
+        draw = DeviceDraw(seed, self.dtype)
+        w, gain = draw.w, draw.gain
         d, h = self.d_model, self.num_heads
         layers = []
         for li in range(self.num_layers):
@@ -149,16 +118,8 @@ class LatentMoELM:
                 layer["w_gate_up"] = w(d, 2 * self.dense_width)
                 layer["w_down"] = w(self.dense_width, d)
             else:
-                f, fs = self.expert_width, self.n_shared * self.expert_width
-                layer["w_router"] = w(d, self.n_experts, dtype=jnp.float32)
-                # the correction biases of `noaux_tc`: about a tenth of
-                # the scores' spread, so that they decide some choices
-                layer["router_bias"] = w(
-                    self.n_experts, scale=0.02, dtype=jnp.float32)
-                layer["experts_gate_up"] = w(self.n_experts, d, 2 * f)
-                layer["experts_down"] = w(self.n_experts, f, d)
-                layer["shared_gate_up"] = w(d, 2 * fs)
-                layer["shared_down"] = w(fs, d)
+                layer.update(draw.expert_layer(
+                    d, self.expert_width, self.n_experts, self.n_shared))
             layers.append(layer)
         return {"embed": w(self.vocab_size, d, scale=0.5), "layers": layers,
                 "norm_f": gain(d), "head": w(d, self.vocab_size)}
@@ -176,13 +137,6 @@ class LatentMoELM:
     def _mm(self, a, w):
         return jnp.dot(a, w, preferred_element_type=jnp.float32).astype(
             self.dtype)
-
-    def _gated(self, x, w_gate_up, w_down):
-        gate_up = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
-        f = gate_up.shape[-1] // 2
-        hidden = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(
-            self.dtype)
-        return jnp.dot(hidden, w_down, preferred_element_type=jnp.float32)
 
     def _queries_and_row(self, lp, x, positions):
         """(q_abs [T, H, lanes], row [T, lanes]): the absorbed queries
@@ -215,21 +169,6 @@ class LatentMoELM:
         o = jnp.einsum("thc,chv->thv", o_abs.astype(self.dtype), w_v,
                        preferred_element_type=jnp.float32).astype(self.dtype)
         return self._mm(o.reshape(t, h * self.v_dim), lp["w_o"])
-
-    def _ffn(self, lp, x, valid):
-        """(y [T, d] in dtype, stats [3] int32 or None)."""
-        if "w_router" not in lp:
-            return self._gated(x, lp["w_gate_up"], lp["w_down"]).astype(
-                self.dtype), None
-        with jax.named_scope("experts"):
-            experts, weights = moe.route(
-                x, lp["w_router"], lp["router_bias"], self.top_k,
-                self.scaling)
-            y, stats = moe.expert_ffn(
-                x, experts, weights, valid, lp["experts_gate_up"],
-                lp["experts_down"])
-            y = y + self._gated(x, lp["shared_gate_up"], lp["shared_down"])
-        return y.astype(self.dtype), stats
 
     # --------------------------- the ragged step ---------------------
     def ragged_step_fn(self, page_size, num_pages, use_kernel=False,
@@ -277,8 +216,9 @@ class LatentMoELM:
                         rows_spec.value_width, use_kernel,
                         interpret=interpret, work=work)
                     x = x + self._attention_out(lp, o_abs)
-                y, stats = self._ffn(lp, rms_norm(x, lp["norm2"], self.eps),
-                                     valid)
+                y, stats = feed_forward(
+                    lp, rms_norm(x, lp["norm2"], self.eps), valid,
+                    self.top_k, self.scaling)
                 if stats is not None:
                     counters = counters + stats
                 x = x + y

@@ -118,10 +118,27 @@ Metric names:
                                       is stored — ``"kernel"``
                                       ([H, P, page, D], read by the
                                       Pallas kernels as stored),
-                                      ``"token"`` ([P, page, H, D]) or
+                                      ``"token"`` ([P, page, H, D]),
                                       ``"latent"`` (a latent cache's
-                                      [P, page, lanes]).  Stamped at
-                                      engine build beside kernel_path
+                                      [P, page, lanes]) or
+                                      ``"kv_rows"`` (grouped-query
+                                      heads' keys then values in one
+                                      [P, page, lanes] row).  Stamped
+                                      at engine build beside
+                                      kernel_path
+- ``generation.kv_layer_groups``      gauge (string, JSON): the kinds
+                                      of layer the pool serves and how
+                                      many of each, e.g. ``{"full": 2,
+                                      "window": 6}``: one page table
+                                      and free list a kind
+- ``generation.kv_window_tokens``     gauge: keys a window layer keeps
+                                      (0: every layer keeps all)
+- ``generation.kv_window_pages_reserved`` / ``_released``
+                                      window-group pages taken by
+                                      reservations, and given back
+                                      BEHIND the window while their
+                                      sequence lives (what a finished
+                                      sequence returns is not counted)
 - ``generation.step_score_blocks``    [q_block, page_size] score-block
                                       computations per head the TILED
                                       ragged kernel performs (the
@@ -186,7 +203,7 @@ Metric names:
                                       summed — counted inside the step
                                       by a model with `step_counters`
 - ``generation.kv_token_bytes``       gauge: bytes one cached token
-                                      costs over all layers (a latent
+                                      costs over all layers (a row
                                       pool stamps it at engine build)
 - ``generation.kv_scale_bytes``       int8 scale bytes in flight
                                       (writes, exports, imports, COW)
@@ -264,6 +281,8 @@ Metric names:
                                       latency-vs-waste cost of big N
                                       the gen_bench loop A/B watches
 """
+import json
+
 from ..profiler.monitor import StatRegistry
 
 PREFIX = "generation."
@@ -309,6 +328,10 @@ MESH_DEVICES = PREFIX + "mesh_devices"
 COLLECTIVE_BYTES_PER_STEP = PREFIX + "collective_bytes_per_step"
 KV_QUANT_DTYPE = PREFIX + "kv_quant_dtype"
 KV_TOKEN_BYTES = PREFIX + "kv_token_bytes"
+KV_LAYER_GROUPS = PREFIX + "kv_layer_groups"
+KV_WINDOW_TOKENS = PREFIX + "kv_window_tokens"
+KV_WINDOW_PAGES_RESERVED = PREFIX + "kv_window_pages_reserved"
+KV_WINDOW_PAGES_RELEASED = PREFIX + "kv_window_pages_released"
 LATENT_PAGES_PER_CELL = PREFIX + "latent_pages_per_cell"
 KV_SCALE_BYTES = PREFIX + "kv_scale_bytes"
 COLLECTIVE_QUANTIZED = PREFIX + "collective_quantized"
@@ -472,11 +495,25 @@ class GenerationMetrics:
         self._stat(KERNEL_PATH).set(f"{mode}:{path}")
 
     def set_kv_pool_layout(self, layout):
-        """Gauge (string): ``"kernel"`` / ``"token"`` / ``"latent"`` —
-        the layout the KV pool is stored in, stamped once at engine
-        build beside kernel_path, so every snapshot says which layout
-        produced its numbers."""
+        """Gauge (string): ``"kernel"`` / ``"token"`` / ``"latent"`` /
+        ``"kv_rows"`` — the layout the KV pool is stored in, stamped
+        once at engine build beside kernel_path, so every snapshot says
+        which layout produced its numbers."""
         self._stat(KV_POOL_LAYOUT).set(str(layout))
+
+    def set_kv_layer_groups(self, groups, window_tokens):
+        """Gauges, stamped at engine build: ``{kind: layers}`` as JSON
+        and the window the window layers keep (0 without any)."""
+        self._stat(KV_LAYER_GROUPS).set(json.dumps(groups, sort_keys=True))
+        self._stat(KV_WINDOW_TOKENS).set(int(window_tokens))
+
+    def count_window_pages(self, reserved, released):
+        """Window-group pages reserved, and released behind a live
+        sequence's window, since the last step's accounting."""
+        if reserved:
+            self._stat(KV_WINDOW_PAGES_RESERVED).increase(int(reserved))
+        if released:
+            self._stat(KV_WINDOW_PAGES_RELEASED).increase(int(released))
 
     def count_score_blocks(self, tiled, untiled, grid_cells):
         """FLOP-proxy accounting for one ragged dispatch: score blocks

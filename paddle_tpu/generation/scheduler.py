@@ -308,6 +308,8 @@ class ContinuousBatchingScheduler:
         prompt's cached run typically survives them, turning a
         recompute-preemption re-prefill into a warm resume."""
         admitted = []
+        wg = self.cache.window_group  # None: every layer keeps all
+        committed_window = 0
         committed = 0  # pages promised to THIS call's earlier admits
         # (their prefills run after admit() returns, so available pages
         # alone would let several admits all claim the same free pages)
@@ -346,13 +348,21 @@ class ContinuousBatchingScheduler:
             # the gate passed instead of waiting in line
             avail = (self.cache.available_pages
                      - self.cache.evictable_pages_in(match_pages))
-            if need > avail - committed \
+            # where some layers keep only a window, a context is
+            # charged there for its window and a chunk at the most, so
+            # a long prompt is admitted on its full-group need and a
+            # window's worth of the other group
+            need_window = wg.pages_for(tokens + 1) if wg is not None else 0
+            if (need > avail - committed or (
+                    wg is not None
+                    and need_window > wg.free_pages - committed_window)) \
                     and (self.active() or self._pending or admitted):
                 # not enough pages now, but retiring sequences will free
                 # some — wait in line rather than rejecting
                 self._pending.appendleft(item)
                 break
             committed += need
+            committed_window += need_window
             if state is None:
                 state = SequenceState(self._next_seq, req)
                 self._next_seq += 1

@@ -232,22 +232,74 @@ def test_the_opt_ragged_step_lowers_to_the_text_it_had(v5e, pages_bucket):
     without source locations, which name the checkout's path and every
     line of the kernel; all else counts.  A PR that means to change what
     the OPT cells run states the new digests here."""
+    lowered, _ = _opt_step_lowered(v5e, "kernel", pages_bucket)
+    # a layer: two row writes, one attention
+    digest = _step_digest(lowered, 2 * 3)
+    assert digest == OPT_STEP_DIGESTS[pages_bucket]
+
+
+def _step_digest(lowered, n_kernels):
+    """sha256 of a lowered step's StableHLO with each Mosaic kernel's
+    body read back and printed without source locations."""
     import base64
     import hashlib
 
     from jax.extend.mlir import ir
 
-    lowered, _ = _opt_step_lowered(v5e, "kernel", pages_bucket)
     body = re.compile(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22')
     text = lowered.as_text()
     with ir.Context() as ctx:
         ctx.allow_unregistered_dialects = True
         kernels = [ir.Module.parse(base64.b64decode(b)).operation.get_asm(
             enable_debug_info=False) for b in body.findall(text)]
-    assert len(kernels) == 2 * 3        # a layer: two row writes, one attention
-    digest = hashlib.sha256(
+    assert len(kernels) == n_kernels
+    return hashlib.sha256(
         (body.sub("BODY", text) + "".join(kernels)).encode()).hexdigest()
-    assert digest == OPT_STEP_DIGESTS[pages_bucket]
+
+
+def _shapes_only(monkeypatch, cls):
+    """`cls` draws its weights as shapes: a whole model for lowering."""
+    draw = cls._draw
+    monkeypatch.setattr(
+        cls, "_draw",
+        lambda self, seed: jax.eval_shape(lambda: draw(self, seed)))
+
+
+# as PR 33's tree lowers it (one dense and one expert layer, a
+# 1,024-row vocabulary, the 512-page bucket; all else the cell's)
+GLM_STEP_DIGEST = (
+    "03fbc9c54b1e2478c898525ab5784373f925486ea1e35a1b2a46f89afeb5b5e7")
+
+
+def test_the_glm_ragged_step_lowers_to_the_text_it_had(v5e, monkeypatch):
+    """`LatentMoELM`'s step is not touched by what it now shares with
+    the third served model (`generation/blocks.py`) nor by that model's
+    cache and kernel: glm-4.7-flash-d7's lowered step, cut to two layers
+    and a small vocabulary, is the text PR 33 left."""
+    from paddle_tpu.generation import latent_moe_model as lm
+
+    args, engine = _glm_cell()
+    _shapes_only(monkeypatch, lm.LatentMoELM)
+    model = lm.LatentMoELM(**dict(args, num_layers=2, vocab_size=1024),
+                           seed=1)
+    t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
+    s = engine["max_decode_slots"] + 1
+    rows = model.kv_rows()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    model.params)
+    pool = sds((engine["num_pages"], engine["page_size"], rows.lanes),
+               rows.dtype)
+    fixed = [sds((t,), "int32")] * 4 + [sds((s, 512), "int32")] + [
+        sds((s,), "int32")] * 3
+    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                              use_kernel=True)
+    lowered = jax.jit(fn, donate_argnums=(9,)).lower(
+        params, *fixed, [pool] * model.num_layers)
+    assert _step_digest(lowered, 2) == GLM_STEP_DIGEST
 
 
 def test_the_pool_check_tells_the_token_layout(v5e, monkeypatch):
@@ -427,3 +479,99 @@ def test_latent_step_compiles_at_the_published_widths(v5e, monkeypatch):
         r"^\s*%?([\w.\-]+) = bf16\[5760,64,640\]\S* (?!parameter)", text,
         re.M)]
     assert len(whole) == model.num_layers, whole
+
+
+def _trinity_cell():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "trinity-mini-d8.json")) as f:
+        builder = json.load(f)["builder"]
+    return builder["model_args"], builder["engine"]
+
+
+@pytest.mark.parametrize("pages_bucket,pool_pages,window", [
+    (1024, 4096, None), (1024, 697, 2048), (64, 697, 2048), (1, 4096, None)])
+def test_gqa_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket,
+                                                     pool_pages, window):
+    """trinity-mini-d8.mixed-closed: 32 query heads over 4 KV heads of
+    128 against 1,024-lane bf16 rows, 16 slots + a 512-token chunk = 528
+    packed rows under 17 descriptors, 64-token pages; the full layers'
+    4,096-page pool and the window layers' 697-page one.  At the cell's
+    largest bucket (1,024 pages: 33,280 tokens) the flat page tables
+    (17,408 words) and the list (3,136 cell words, 196 with a window) ride
+    in SMEM, a q tile's 4 x 128 rows and the two 2 MiB halves of a cell's
+    page block lower, and nothing of the pool's size is made."""
+    from paddle_tpu.generation.decode_attention import gqa_ragged_attention
+    from paddle_tpu.ops.pallas.gqa_paged_attention import gqa_grid_cells
+
+    args, engine = _trinity_cell()
+    t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
+    s = engine["max_decode_slots"] + 1
+    lanes = 2 * args["num_kv_heads"] * args["head_dim"]
+
+    def fn(q, pool, pt, starts, lens, kv_lens):
+        return gqa_ragged_attention(q, pool, pt, starts, lens, kv_lens,
+                                    args["head_dim"] ** -0.5,
+                                    args["num_kv_heads"], window, True)
+
+    compiled = _compile(
+        fn, v5e, ((t, args["num_heads"], args["head_dim"]), "bfloat16"),
+        ((pool_pages, engine["page_size"], lanes), "bfloat16"),
+        ((s, pages_bucket), "int32"), ((s,), "int32"), ((s,), "int32"),
+        ((s,), "int32"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    cells = gqa_grid_cells(s, pages_bucket, t, engine["page_size"], window)
+    # the scalar-prefetch operands: the traced grid bound, the flat page
+    # tables, the cell words
+    (call,) = _custom_call_operands(compiled.as_text())
+    assert call[:3] == ["s32[]", f"s32[{s * pages_bucket}]", f"s32[{cells}]"]
+
+
+def test_gqa_step_compiles_at_the_published_widths(v5e, monkeypatch):
+    """The whole ragged step of trinity-mini-d8 at the cell's largest
+    pages bucket, weights as shapes: it fits the chip beside its 12.6 GiB
+    of weights and pools; no operation but a layer's row write produces a
+    whole pool, of either group (no copy, no transpose); and the kernels
+    are there: 8 attention calls, 6 layers x 3 grouped-product calls."""
+    from paddle_tpu.generation import gqa_window_moe_model as gm
+
+    args, engine = _trinity_cell()
+    _shapes_only(monkeypatch, gm.GQAWindowMoELM)
+    model = gm.GQAWindowMoELM(**args, seed=1)
+    t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
+    s = engine["max_decode_slots"] + 1
+    rows = model.kv_rows()
+    assert (rows.lanes, rows.token_bytes(1)) == (1024, 2048)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    model.params)
+    pages = {"full": engine["num_pages"], "window": 697}
+    pools = [sds((pages[kind], engine["page_size"], rows.lanes), rows.dtype)
+             for kind in model.layer_kinds]
+    tables = sds((s, 1024), "int32")
+    fixed = ([sds((t,), "int32")] * 4 + [tables] + [sds((s,), "int32")] * 3
+             + [sds((t,), "int32"), tables])
+    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                              use_kernel=True)
+    compiled = jax.jit(fn, donate_argnums=(11,)).lower(
+        params, *fixed, pools).compile()
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert memory.temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 8 + 6 * 3
+    attention = [call for call in _custom_call_operands(text)
+                 if call[:2] == ["s32[]", f"s32[{s * 1024}]"]]
+    assert len(attention) == model.num_layers
+    for kind, count in (("full", 2), ("window", 6)):
+        whole = [m.group(1) for m in re.finditer(
+            rf"^\s*%?([\w.\-]+) = bf16\[{pages[kind]},64,1024\]\S* "
+            r"(?!parameter)", text, re.M)]
+        assert len(whole) == count, (kind, whole)

@@ -8,6 +8,9 @@ precision from a worse one.
     chiprun -- python tools/precision_control.py \\
         --workload glm-4.7-flash-d7.docqa-closed --seed 2147484301 [--rehearse]
 
+(or `--workload trinity-mini-d8.mixed-closed`: any cell whose runner has
+`_load`, `verdict` and the check's prompt lengths)
+
 The control system is the cell's plain reference with every matrix
 rounded to float8 (e4m3; the configuration states bfloat16): at each of
 the last `check.new_tokens` positions of the check's prompts (seeded as
@@ -67,9 +70,14 @@ def main(argv=None):
                                            seed=args.seed)
     params, n_new = model.decode_params(), int(check["new_tokens"])
     rng = np.random.default_rng([args.seed, 0xC0DE])
-    lengths = [int(n) for n in check["prompt_tokens"]] + [
-        int(traffic["prefix"]["tokens"])
-        + int(check["document_question_tokens"])]
+    # the check's prompt lengths: the runner's own list where it has one
+    # (`check_lengths`), else `serve_model.py`'s plain prompts and its
+    # question behind one of the traffic's documents
+    lengths = runner.check_lengths(check, traffic) if hasattr(
+        runner, "check_lengths") else [
+            int(n) for n in check["prompt_tokens"]] + [
+                int(traffic["prefix"]["tokens"])
+                + int(check["document_question_tokens"])]
     # prompt + the tokens the positions are read behind
     texts = [rng.integers(0, model.vocab_size, n + n_new - 1).tolist()
              for n in lengths]
