@@ -227,7 +227,8 @@ def ragged_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows,
         return None
     from ..ops.pallas.paged_attention import ragged_work_list as build
 
-    return build(page_tables, starts, lens, kv_lens, page_size, n_rows)
+    return build(starts, lens, kv_lens, page_size,
+                 jnp.asarray(page_tables).shape[1], n_rows)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_tables, starts, lens,

@@ -919,6 +919,19 @@ class GenerationEngine:
             pages_per_cell = latent_pages_per_cell(self.cache.page_size,
                                                    self.cache.num_pages)
         self.metrics.set_latent_pages_per_cell(pages_per_cell)
+        # ... and, for a per-head pool the ragged kernel reads, the
+        # pages and the heads (of a mesh shard's) a grid step holds
+        cell = (0, 0)
+        if (self._ragged is not None and kv_rows is None
+                and self._use_kernel):
+            from ..ops.pallas.paged_attention import ragged_cell_shape
+
+            cell = ragged_cell_shape(
+                self.cache.page_size, self.cache.num_pages,
+                self.step_token_budget,
+                int(model.num_heads) // self.tp_degree, model.head_dim,
+                np.dtype(self.cache.dtype).itemsize)[:2]
+        self.metrics.set_ragged_cell(*cell)
         # precision facts, stamped once like kernel_path: what dtype
         # the pools store, and whether the quantized ring ACTUALLY
         # carries the allreduces (a requested-but-inert flag reads 0)

@@ -716,8 +716,8 @@ class RaggedStep:
     def count_kernel_cells(self, fixed):
         """The dispatch's grid and the part of it that computes, per
         head and layer, into last_grid_cells / last_score_blocks /
-        last_score_blocks_untiled (for a latent pool, the page slots of
-        the latent kernel's grid: `generation.step_grid_cells`).  The
+        last_score_blocks_untiled (the grid in page SLOTS: G a cell of
+        the kernel that runs: `generation.step_grid_cells`).  The
         FLOP proxy mirrors the TILED KERNEL's skip rule — only meaningful (and only paid) when the
         kernel path actually dispatched; the jnp reference computes
         dense masked blocks, and reporting kernel skip statistics for
@@ -735,22 +735,23 @@ class RaggedStep:
         if getattr(rows, "kv_heads", None):
             self._count_gqa_cells(st, ln, kv, bucket_p, page_size)
             return
-        self.last_score_blocks, self.last_score_blocks_untiled = \
-            pa.ragged_score_blocks(st, ln, kv, page_size, bucket_p,
-                                   self.max_tokens)
-        if rows is None:
-            self.last_grid_cells = pa.ragged_grid_cells(
-                self.max_seqs, bucket_p, self.max_tokens,
-                live=self.last_score_blocks)
-            return
-        # the latent kernel walks GROUPS of pages: its grid in the
-        # score blocks' unit is the page slots of the steps it takes,
-        # so blocks over cells reads how full the groups are
-        self.last_grid_cells = pa.latent_pages_per_cell(
-            page_size, bucket_p) * pa.latent_grid_cells(
+        # both kernels walk GROUPS of pages: the grid in the score
+        # blocks' unit is the page slots of the steps it takes, so blocks
+        # over cells reads how full the groups are
+        shape = (page_size, bucket_p, self.max_tokens)
+        if rows is None:    # the per-head kernel, under its own tile
+            per, _, qb = pa.ragged_cell_shape(*shape)
+            steps = pa.ragged_grid_cells(
                 self.max_seqs, bucket_p, self.max_tokens, page_size,
-                live=pa.latent_score_groups(st, ln, kv, page_size, bucket_p,
-                                            self.max_tokens))
+                live=pa.ragged_score_groups(st, ln, kv, *shape))
+        else:               # the latent kernel
+            per, qb = pa.latent_pages_per_cell(page_size, bucket_p), None
+            steps = pa.latent_grid_cells(
+                self.max_seqs, bucket_p, self.max_tokens, page_size,
+                live=pa.latent_score_groups(st, ln, kv, *shape))
+        self.last_score_blocks, self.last_score_blocks_untiled = \
+            pa.ragged_score_blocks(st, ln, kv, *shape, qb)
+        self.last_grid_cells = per * steps
 
     def _count_gqa_cells(self, st, ln, kv, bucket_p, page_size):
         """The grouped-query kernel's two lists (window, full), each

@@ -139,13 +139,14 @@ Metric names:
                                       BEHIND the window while their
                                       sequence lives (what a finished
                                       sequence returns is not counted)
-- ``generation.step_score_blocks``    [q_block, page_size] score-block
-                                      computations per head the TILED
-                                      ragged kernel performs (the
-                                      query-axis tiling skip rule,
-                                      mirrored host-side per dispatch
-                                      — ops/pallas
-                                      ragged_score_blocks).  Emitted
+- ``generation.step_score_blocks``    visible (query tile, page) pairs
+                                      per head the TILED ragged kernel
+                                      multiplies (the query-axis tiling
+                                      skip rule, mirrored host-side per
+                                      dispatch — ops/pallas
+                                      ragged_score_blocks, under the
+                                      query tile of the kernel that
+                                      runs).  Emitted
                                       ONLY when the kernel path
                                       dispatched; 0 on the jnp
                                       reference, which runs no tiled
@@ -157,28 +158,33 @@ Metric names:
                                       dispatches, in the same tile
                                       units — tiled < untiled is the
                                       measured out-of-span skip
-- ``generation.step_grid_cells``      grid steps per head the ragged
-                                      kernel WALKED, dispatch by
-                                      dispatch (ops/pallas
-                                      ragged_grid_cells): its grid is a
-                                      compacted list of the live
-                                      (descriptor, page, query tile)
-                                      cells under a traced bound, so
-                                      this is step_score_blocks held to
-                                      [1, the list's capacity] — the
-                                      denominator of step_score_blocks
+- ``generation.step_grid_cells``      page SLOTS per head the ragged
+                                      kernel's grid WALKED, dispatch by
+                                      dispatch: its grid is a compacted
+                                      list of the live (descriptor,
+                                      page group, query tile) cells
+                                      under a traced bound, a cell
+                                      holds G pages (ops/pallas
+                                      ragged_cell_shape x
+                                      ragged_grid_cells on a per-head
+                                      pool, latent_pages_per_cell x
+                                      latent_grid_cells on a latent
+                                      one), a descriptor's last group
+                                      is padded, and so
+                                      step_score_blocks over this
+                                      reads how full the groups are
                                       (same units, same 0 on the jnp
-                                      reference); a ratio under 1 is
-                                      steps that computed nothing.  An
-                                      engine on a LATENT pool counts
-                                      the page SLOTS its kernel walked
-                                      (latent_pages_per_cell x the
-                                      grid steps, ops/pallas
-                                      latent_grid_cells): a step there
-                                      holds a group of pages, a tile's
-                                      last group is padded, and the
-                                      ratio reads how full the groups
-                                      are
+                                      reference)
+- ``generation.ragged_pages_per_cell`` / ``_heads_per_cell``
+                                      gauges: G and Hb, the pages and
+                                      the heads one grid step of the
+                                      per-head ragged kernel holds
+                                      (ops/pallas ragged_cell_shape at
+                                      the engine's shapes; a pages
+                                      bucket under G holds itself).
+                                      Stamped at engine build beside
+                                      latent_pages_per_cell, 0 unless
+                                      that kernel runs
 - ``generation.latent_pages_per_cell``  gauge: pages one grid step of
                                       the latent kernel holds (ops/
                                       pallas latent_pages_per_cell at
@@ -333,6 +339,8 @@ KV_WINDOW_TOKENS = PREFIX + "kv_window_tokens"
 KV_WINDOW_PAGES_RESERVED = PREFIX + "kv_window_pages_reserved"
 KV_WINDOW_PAGES_RELEASED = PREFIX + "kv_window_pages_released"
 LATENT_PAGES_PER_CELL = PREFIX + "latent_pages_per_cell"
+RAGGED_PAGES_PER_CELL = PREFIX + "ragged_pages_per_cell"
+RAGGED_HEADS_PER_CELL = PREFIX + "ragged_heads_per_cell"
 KV_SCALE_BYTES = PREFIX + "kv_scale_bytes"
 COLLECTIVE_QUANTIZED = PREFIX + "collective_quantized"
 PREFIX_CACHE_HIT_TOKENS = PREFIX + "prefix_cache_hit_tokens"
@@ -518,7 +526,7 @@ class GenerationMetrics:
     def count_score_blocks(self, tiled, untiled, grid_cells):
         """FLOP-proxy accounting for one ragged dispatch: score blocks
         the query-TILED kernel computes vs what the untiled kernel
-        would have, and the steps its grid walked (same units;
+        would have, and the page slots its grid walked (same units;
         ops/pallas ragged_score_blocks, ragged_grid_cells).  All 0 on
         the jnp reference path."""
         if grid_cells:
@@ -543,6 +551,13 @@ class GenerationMetrics:
         """Gauge: pages a grid step of the latent kernel holds, stamped
         at engine build (0 by an engine that runs no latent kernel)."""
         self._stat(LATENT_PAGES_PER_CELL).set(int(n))
+
+    def set_ragged_cell(self, pages, heads):
+        """Gauges: pages and heads a grid step of the per-head ragged
+        kernel holds, stamped at engine build (0 by an engine that
+        runs another kernel, or none)."""
+        self._stat(RAGGED_PAGES_PER_CELL).set(int(pages))
+        self._stat(RAGGED_HEADS_PER_CELL).set(int(heads))
 
     def set_kv_quant_dtype(self, dtype_name):
         """Gauge (string): the KV pool storage dtype, stamped once at

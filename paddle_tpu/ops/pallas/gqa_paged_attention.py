@@ -44,8 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import NEG_INF, resolve_interpret
-from .paged_attention import (_cell_bits, _reject_mesh_sharded_pool,
-                              ragged_query_tiles)
+from .paged_attention import (_cell_bits, _held_to, _in_hbm,
+                              _reject_mesh_sharded_pool, ragged_query_tiles)
 
 # Query rows of a tile.  With 8 query heads a KV head a tile's product
 # has 8 x 16 = 128 rows, a full MXU pass on v5e; a chunk's context is
@@ -86,11 +86,7 @@ def gqa_grid_cells(n_seqs, n_pages, n_rows, page_size, window=None,
     qb, n_tiles = ragged_query_tiles(n_rows, GQA_Q_BLOCK)
     capacity = (n_tiles + n_seqs - 1) * _groups_a_pair(
         n_pages, page_size, per, qb, window)
-    if live is None:
-        return capacity
-    if isinstance(live, jax.Array):
-        return jnp.clip(live, 1, capacity)
-    return min(max(int(live), 1), capacity)
+    return _held_to(capacity, live)
 
 
 def _pair_spans(xp, starts, lens, kv_lens, page_size, n_pages, n_rows,
@@ -293,16 +289,6 @@ def _gqa_kernel(pt_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref, q_ref,
             l = jnp.max(l_ref[g], axis=1, keepdims=True)
             safe_l = jnp.where(l > 0.0, l, 1.0)  # unclaimed rows: zeros
             o_ref[0, g] = (acc_ref[g] / safe_l * fits).astype(o_ref.dtype)
-
-
-def _in_hbm(pool, interpret):
-    """The pool held in HBM by name.  Left to itself XLA:TPU's
-    memory-space assignment copies a whole window pool in front of the
-    call (91 MB fit v5e's 128 MiB of VMEM): a pool-sized operation a step
-    for three of six window layers (compile-only for v5e, PR 34)."""
-    if resolve_interpret(interpret):
-        return pool
-    return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
 
 
 def gqa_ragged_attention_kernel(q, pool, page_tables, starts, lens, kv_lens,

@@ -23,16 +23,21 @@ packed token axis under ``[start, len, kv_len]`` descriptors.  Its grid
 is NOT the bounding box descriptors x pages x query tiles (of which 96 %
 did nothing at the benchmark's shapes: PERF.md, PR 25) but a COMPACTED
 list of the cells that compute, built in the trace from the descriptors
-(`ragged_work_list`) and walked under a traced bound:
+(`ragged_work_list`) and walked under a traced bound, and a cell is not
+one 16-token page of one head (0.3 us a grid step whatever it computes,
+a sixteenth of an MXU pass: 5 % of the memory roofline, PERF.md, PR 35)
+but a GROUP of pages x a BLOCK of heads (`ragged_cell_shape`):
 
-    grid = (H, live cells)            # count traced, <= ragged_grid_cells
-    cell w = (descriptor s, page i, query tile qt)      # one SMEM word
-    k block = pool_t[h, pages[w]]                       # one SMEM word
-    order: descriptors as given, a descriptor's pages ascending, the
-           tiles that see a page innermost (its block is fetched once)
+    grid = (H / Hb, live cells)       # count traced, <= ragged_grid_cells
+    cell w = (descriptor s, page group g, query tile qt)   # one SMEM word
+    k block = pool_t[h0:h0 + Hb, pages of group g]   # G strided DMAs from
+                                      # the pool in HBM, double-buffered,
+                                      # once for all the tiles of (s, g)
+    order: descriptors as given, a descriptor's groups ascending, the
+           tiles that see a group innermost
 
-A 1-token decode row costs one grid step per page of its context; a
-padding descriptor, a page past a row's horizon and a tile outside a
+A 1-token decode row costs one grid step per G pages of its context; a
+padding descriptor, a group past a row's horizon and a tile outside a
 descriptor's rows cost none.
 
 Online softmax state (m, l, acc) lives in VMEM scratch across the page
@@ -41,23 +46,26 @@ axis exactly like the flash forward kernel.
 Layouts are chosen Mosaic tile-legal by construction: pools are read as
 [H, P, page_size, D] so every block's trailing two dims are full array
 dims (page_size, D); decode q/out ride as [B, H, 1, D] with (1, 1, 1, D)
-blocks, ragged q/out as one whole-axis [1, T, D] block a head.
+blocks, ragged q/out as one whole-axis [Hb, T, D] block a head block.
 
 INT8 POOLS: every public kernel takes optional ``k_scale``/``v_scale``
 [P, H] per-page per-head abs-max arrays (generation.quantized_kv).
-They ride as two more blocked VMEM operands, re-laid per call as one
-128-lane row per 128 pages (``_scale_rows``) and indexed through the
-page table like K/V, so their footprint does not grow with the pool
-(as scalar-prefetch operands the two [P, H] arrays overflowed the
-1 MiB SMEM at num_pages >= 1024).  Each live grid cell dequantizes its
-page block in-kernel — ``int8 * (scale * 1/127)`` with the exact
-expression the jnp gather references use, so kernel-vs-reference
-operands stay bitwise equal — before the score matmul.  The jnp
-references dequantize their gathered O(tokens) views; the kernels
-dequantize per block; nobody ever materializes a dequantized pool.
+In the decode and chunk kernels they ride as two more blocked VMEM
+operands, re-laid per call as one 128-lane row per 128 pages
+(``_scale_rows``) and indexed through the page table like K/V, so their
+footprint does not grow with the pool (as scalar-prefetch operands the
+two [P, H] arrays overflowed the 1 MiB SMEM at num_pages >= 1024), and
+each live grid cell dequantizes its page block in-kernel — ``int8 *
+(scale * 1/127)`` with the exact expression the jnp gather references
+use — before the score matmul.  The ragged kernel gathers the scales of
+each descriptor's pages in front of the call (``_group_scales``) and
+multiplies a page's COLUMNS of the scores and of the weights by them:
+the same numbers up to rounding, no int8 block is rewritten.  The jnp
+references dequantize their gathered O(tokens) views; nobody ever
+materializes a dequantized pool.
 
-SMEM holds what the index maps read: the decode and chunk kernels' page
-tables, the ragged kernel's work list (two words a cell; its limit is in
+SMEM holds what the kernels look pages up in: the page tables, the
+ragged kernel's work list (one word a cell; its limit is in
 `ragged_paged_attention_kernel`'s docstring) and the descriptors.
 
 MESH-NATIVE dispatch: every public kernel takes ``mesh`` / ``tp_axis``.
@@ -166,14 +174,29 @@ def _split_refs(refs, quantized):
 _STATE_ROWS = 8  # scratch rows; every row holds the same value so all
 # scratch traffic is full-width vector ops (the Mosaic-proven layout)
 
-# query-axis tile of the RAGGED kernel (RPA-paper waste fix #1): a grid
-# cell computes a [RAGGED_Q_BLOCK, page_size] score block for ONE query
-# tile of one (descriptor, page) instead of the full packed
-# [T, page_size] axis, and tiles outside the descriptor's row span are
-# not on the work list at all — a 1-token decode descriptor computes 1
-# tile per page, not T/8.  8 is the Mosaic sublane width (the flash
-# kernels' proven minor-axis tile).
+# query-axis tile of the LATENT kernel and the default of
+# `ragged_query_tiles`: 8 is the Mosaic sublane width (the flash
+# kernels' proven minor-axis tile).  The per-head ragged kernel states
+# its own tile in `ragged_cell_shape`.
 RAGGED_Q_BLOCK = 8
+
+# ---- the per-head RAGGED kernel's cell (`ragged_cell_shape`) ----------
+# Keys of one context a cell multiplies: a grid step costs ~0.35 us
+# whatever it computes and a 16-key product fills an eighth of an MXU
+# pass (PERF.md, PRs 25, 33), so a cell holds a GROUP of pages.
+RAGGED_CELL_TOKENS = 128
+# Query rows of a tile.  A product's cost is loading the K tile into
+# the MXU, not streaming the rows through it, so a wider tile costs a
+# decode row little and saves a chunk whole cells.
+RAGGED_CELL_ROWS = 8
+# The most heads whose pages one strided DMA brings, and the VMEM a
+# cell's blocks may take (the double-buffered K and V groups, the q and
+# output blocks Pallas double-buffers, the online-softmax state over
+# the packed axis); the call raises Mosaic's scoped limit to
+# `RAGGED_VMEM_LIMIT`, under v5e's 128 MiB.
+RAGGED_CELL_HEADS = 32
+RAGGED_VMEM_BUDGET = 40 << 20
+RAGGED_VMEM_LIMIT = 96 << 20
 
 
 def _reject_mesh_sharded_pool(pool):
@@ -248,24 +271,61 @@ def _head_shard_map(body, mesh, tp_axis, layout, q, k_pool, v_pool,
 
 
 def ragged_query_tiles(n_rows, q_block=None):
-    """``(q_block, n_tiles)`` the ragged kernel cuts a packed axis of
-    `n_rows` rows into.  The ONE statement of the tiling rule — the
-    kernel's work list, the skip-rule mirror below and the engine's grid
-    counter (`generation.step_grid_cells`) all read it here."""
+    """``(q_block, n_tiles)`` a ragged kernel cuts a packed axis of
+    `n_rows` rows into: `q_block` rows a tile (RAGGED_Q_BLOCK, the
+    latent kernel's, when None), the whole axis when it is shorter."""
     qb = max(1, min(int(q_block or RAGGED_Q_BLOCK), int(n_rows)))
     return qb, -(-int(n_rows) // qb)
 
 
-def ragged_grid_cells(n_seqs, n_pages, n_rows, live=None):
-    """Grid steps a head of the ragged kernel: the CAPACITY of its work
-    list, or, given the `live` (descriptor, page, tile) cells of a
-    step's descriptors, the steps the kernel walks for them — exactly
-    those (the grid's bound is traced), but never fewer than the one
-    step that writes the output and never more than the list holds.
-    The ONE home of the grid's size: the kernel's `grid=` (on the
-    list's traced count), the engine's `generation.step_grid_cells` (on
-    `ragged_score_blocks`, the host's mirror of that count) and the
-    tests call it.
+def ragged_cell_shape(page_size, n_pages, n_rows, n_heads=1, head_dim=128,
+                      itemsize=4):
+    """``(G, Hb, q_block)`` — what one cell of the per-head ragged
+    kernel holds, from what the call can observe: G consecutive logical
+    pages of one descriptor (`RAGGED_CELL_TOKENS` in pages, at least
+    one, never more than the page tables hold: the pages bucket), a
+    tile of `q_block` packed rows, and a block of Hb heads: the largest
+    divisor of `n_heads` (after a mesh has split them) up to
+    `RAGGED_CELL_HEADS` whose blocks fit `RAGGED_VMEM_BUDGET` —
+
+        K and V groups, double-buffered   4 x G x page_size x lanes x itemsize
+        q, output (x 2 each), acc, m, l   7 x padded rows x lanes x 4
+
+    a head, lanes the head's width in whole 128-lane rows.  The ONE
+    statement of the rule: the work list and the grid's capacity (which
+    read G and the tile alone: they do not depend on the heads), the
+    kernel, the engine's counters and gauges and the tests read it
+    here.  Nothing else sets it: no option, no keyword."""
+    per = max(1, min(RAGGED_CELL_TOKENS // int(page_size), int(n_pages)))
+    qb, n_tiles = ragged_query_tiles(n_rows, RAGGED_CELL_ROWS)
+    lanes = -(-int(head_dim) // 128) * 128
+    a_head = (4 * per * int(page_size) * lanes * int(itemsize)
+              + 7 * n_tiles * qb * lanes * 4)
+    fits = [hb for hb in range(1, min(int(n_heads), RAGGED_CELL_HEADS) + 1)
+            if n_heads % hb == 0 and hb * a_head <= RAGGED_VMEM_BUDGET]
+    return per, max(fits, default=1), qb
+
+
+def _held_to(capacity, live):
+    """A list's capacity, or its `live` count (traced or not) held to
+    [1, capacity]: the steps a grid walks."""
+    if live is None:
+        return capacity
+    if isinstance(live, jax.Array):
+        return jnp.clip(live, 1, capacity)
+    return min(max(int(live), 1), capacity)
+
+
+def ragged_grid_cells(n_seqs, n_pages, n_rows, page_size, live=None):
+    """Grid steps a head block of the ragged kernel: the CAPACITY of
+    its work list, or, given the `live` (descriptor, page group, tile)
+    cells of a step's descriptors, the steps the kernel walks for them
+    — exactly those (the grid's bound is traced), but never fewer than
+    the one step that writes the output and never more than the list
+    holds.  The ONE home of the grid's size: the kernel's `grid=` (on
+    the list's traced count), the engine's `generation.step_grid_cells`
+    (G x this, on `ragged_score_groups`, the host's mirror of that
+    count) and the tests call it.
 
     THE CAPACITY, and what it assumes.  Descriptors own DISJOINT row
     ranges of the packed axis (`RaggedStep.pad` hands the engine's
@@ -274,21 +334,20 @@ def ragged_grid_cells(n_seqs, n_pages, n_rows, live=None):
     inside exactly one tile, so two of them share at most the tile the
     later one begins in: the (descriptor, tile) pairs that intersect
     number at most ``n_tiles + n_seqs - 1``, each meets at most
-    `n_pages` pages, and the list is sized to that product.  Ranges
-    that overlap can exceed it; the kernel then returns NaN, not the
-    attention of the cells that fitted."""
-    capacity = (ragged_query_tiles(n_rows)[1] + n_seqs - 1) * n_pages
-    if live is None:
-        return capacity
-    if isinstance(live, jax.Array):
-        return jnp.clip(live, 1, capacity)
-    return min(max(int(live), 1), capacity)
+    ``ceil(n_pages / G)`` groups, and the list is sized to that
+    product.  Ranges that overlap can exceed it; the kernel then
+    returns NaN, not the attention of the cells that fitted."""
+    per, _, qb = ragged_cell_shape(page_size, n_pages, n_rows)
+    capacity = ((ragged_query_tiles(n_rows, qb)[1] + n_seqs - 1)
+                * -(-n_pages // per))
+    return _held_to(capacity, live)
 
 
 def _cell_bits(n_seqs, n_pages, n_tiles):
     """Shifts of a packed work-list cell ``descriptor | page | tile``
-    (tile in the low bits).  One int32 a cell keeps the list at one
-    SMEM word per entry beside its physical page."""
+    (tile in the low bits; `n_pages` counts page GROUPS where a list's
+    cells are groups).  One int32 a cell keeps the list at one SMEM
+    word per entry."""
     tile_bits = (n_tiles - 1).bit_length()
     page_bits = (n_pages - 1).bit_length()
     if tile_bits + page_bits + (n_seqs - 1).bit_length() > 31:
@@ -298,90 +357,109 @@ def _cell_bits(n_seqs, n_pages, n_tiles):
     return tile_bits, tile_bits + page_bits
 
 
-def ragged_work_list(page_tables, starts, lens, kv_lens, page_size, n_rows):
-    """The ragged kernel's grid, IN THE TRACE: every (descriptor, page,
-    query tile) cell that computes, in the order the kernel walks them —
-    descriptors as given, a descriptor's pages ascending, the tiles
-    that see a page innermost (so a page block shared by a chunk's
-    tiles is fetched once, and each row meets its pages in ascending
-    order).  A cell is live by the rule `ragged_score_blocks` mirrors
-    on the host: the tile meets the descriptor's rows and the page
-    starts at or under the horizon of the tile's last in-span row.
-    Tiles are monotone in that horizon, so the tiles of a (descriptor,
-    page) are a suffix ``[first, t1]`` of the descriptor's tiles: a
-    count per (descriptor, page), a running sum, and a search give the
-    w-th cell in closed form.
+def ragged_work_list(starts, lens, kv_lens, page_size, n_pages, n_rows):
+    """The per-head ragged kernel's grid, IN THE TRACE: every (descriptor,
+    page group, query tile) cell that computes, in the order the kernel
+    walks them — descriptors as given, a descriptor's groups ascending,
+    the tiles that see a group innermost (so the kernel fetches a
+    group's pages once for all the tiles of a chunk, and each row meets
+    its pages in ascending order).  A cell is live by the rule
+    `ragged_score_groups` mirrors on the host: the tile meets the
+    descriptor's rows and the group's FIRST page starts at or under the
+    horizon of the tile's last in-span row.  Tiles are monotone in that
+    horizon, so the tiles of a (descriptor, group) are a suffix
+    ``[first, t1]`` of the descriptor's tiles: a count per (descriptor,
+    group) and a running sum give the w-th cell in closed form.
 
-    Returns ``(pages [W], cells [W], count [1])`` int32, W the capacity
-    `ragged_grid_cells` states: cell w's physical page (what the k/v
-    index maps read) and its packed ``descriptor | page | tile`` word
-    (`_cell_bits`).  Entries past `count` repeat the last live one, so
-    their blocks are already resident; the kernel never computes them.
-    Pure jnp over traced descriptors: built once a step (model
-    `_ragged_core_fn`) or once an iteration of the host-free loop, and
-    shared by the layers."""
-    pt = jnp.asarray(page_tables, jnp.int32)
-    n_seqs, n_pages = pt.shape
-    qb, n_tiles = ragged_query_tiles(n_rows)
-    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
+    Returns ``(cells [W], count [1])`` int32, W the capacity
+    `ragged_grid_cells` states: cell w's packed ``descriptor | group |
+    tile`` word (`_cell_bits` over the groups).  Its physical pages are
+    not on the list: the kernel looks them up in the page tables, which
+    ride whole in SMEM beside it.  Entries past `count` repeat the last
+    live one, whose pages are already resident; the kernel never
+    computes them.  Pure jnp over traced descriptors: built once a step
+    (model `_ragged_core_fn`) or once an iteration of the host-free
+    loop, and shared by the layers."""
+    n_seqs = jnp.asarray(starts).shape[0]
+    per, _, qb = ragged_cell_shape(page_size, n_pages, n_rows)
+    n_tiles = ragged_query_tiles(n_rows, qb)[1]
+    n_groups = -(-n_pages // per)
+    tile_bits, group_bits = _cell_bits(n_seqs, n_groups, n_tiles)
     st, ln, kv = (jnp.asarray(x, jnp.int32)[:, None]
                   for x in (starts, lens, kv_lens))            # [S, 1]
     end = st + ln
     t1 = jnp.minimum((end - 1) // qb, n_tiles - 1)
-    # page i starts under the horizon of tile qt's last in-span row iff
-    # min((qt + 1) * qb, end) >= need
-    need = (jnp.arange(n_pages, dtype=jnp.int32)[None, :] * page_size
-            - (kv - ln) + st + 1)                              # [S, P]
+    # group g's first page starts under the horizon of tile qt's last
+    # in-span row iff min((qt + 1) * qb, end) >= need
+    need = (jnp.arange(n_groups, dtype=jnp.int32)[None, :]
+            * (per * page_size) - (kv - ln) + st + 1)          # [S, G]
     first = jnp.maximum(st // qb, -(-need // qb) - 1)
     tiles = jnp.where((ln > 0) & (need <= end),
                       jnp.maximum(t1 - first + 1, 0), 0).reshape(-1)
     upto = jnp.cumsum(tiles)
     count = upto[-1]
-    capacity = ragged_grid_cells(n_seqs, n_pages, n_rows)
+    capacity = ragged_grid_cells(n_seqs, n_pages, n_rows, page_size)
     w = jnp.minimum(jnp.arange(capacity, dtype=jnp.int32),
                     jnp.maximum(count - 1, 0))
-    group = jnp.minimum(jnp.searchsorted(upto, w, side="right"),
-                        n_seqs * n_pages - 1).astype(jnp.int32)
-    tile = jnp.clip(first.reshape(-1)[group]
-                    + w - (upto[group] - tiles[group]), 0, n_tiles - 1)
-    cells = ((group // n_pages) << page_bits
-             | (group % n_pages) << tile_bits | tile)
-    return pt.reshape(-1)[group], cells, count.reshape(1)
+    # the (descriptor, group) of cell w is the last one that starts at
+    # or under w: a mark at every pair's start and a running sum, not a
+    # search (`latent_work_list` says what the search cost)
+    marks = jnp.zeros((capacity,), jnp.int32).at[upto - tiles].add(
+        1, mode="drop")
+    pair = jnp.clip(jnp.cumsum(marks)[w] - 1, 0, n_seqs * n_groups - 1)
+    tile = jnp.clip(first.reshape(-1)[pair]
+                    + w - (upto[pair] - tiles[pair]), 0, n_tiles - 1)
+    cells = ((pair // n_groups) << group_bits
+             | (pair % n_groups) << tile_bits | tile)
+    return cells.astype(jnp.int32), count.reshape(1).astype(jnp.int32)
+
+
+def _pages_seen(starts, lens, kv_lens, page_size, n_pages, n_rows, q_block):
+    """[S, Q] in numpy: the pages of descriptor s that query tile q
+    sees — those that start at or under the position of the tile's last
+    in-span row — and 0 where the tile misses the descriptor's rows.
+    The host's mirror of the lists' skip rule."""
+    qb, n_tiles = ragged_query_tiles(n_rows, q_block)
+    st, ln, kv = (np.asarray(x, np.int64)[:, None]
+                  for x in (starts, lens, kv_lens))            # [S, 1]
+    end = st + ln
+    qt = np.arange(n_tiles)[None, :]                           # [1, Q]
+    meets = (ln > 0) & (qt >= st // qb) & (qt <= (end - 1) // qb)
+    horizon = kv - ln + (np.minimum((qt + 1) * qb, end) - 1 - st)
+    return np.where(meets, np.clip(horizon // int(page_size) + 1, 0,
+                                   int(n_pages)), 0)
 
 
 def ragged_score_blocks(starts, lens, kv_lens, page_size, n_pages, n_rows,
                         q_block=RAGGED_Q_BLOCK):
-    """Host-side mirror of the tiled ragged kernel's skip rule — the
-    FLOP-proxy counter `generation.step_score_blocks` is set from.
+    """Host-side mirror of the tiled kernels' skip rule — the FLOP-proxy
+    counter `generation.step_score_blocks` is set from.
 
-    Returns ``(tiled, untiled)``: the number of [q_block, page_size]
-    score-block computations per head the TILED kernel performs for
-    these descriptors, and the number the UNTILED kernel (full packed
-    token axis per live (descriptor, page) cell) would have performed,
-    expressed in the same tile units so "tiled < untiled" is the
-    measured statement that out-of-span work was skipped."""
-    import numpy as np
-
-    qb, n_tiles = ragged_query_tiles(n_rows, q_block)
-    ps = int(page_size)
-    starts = np.asarray(starts, np.int64)
+    Returns ``(tiled, untiled)``: the (query tile, page) pairs a kernel
+    with `q_block`-row tiles has to multiply for these descriptors per
+    head, and the number an UNTILED kernel (full packed token axis per
+    live (descriptor, page)) would, in the same tile units, so "tiled <
+    untiled" is the measured statement that out-of-span work was
+    skipped.  The engine passes the tile of the kernel it runs
+    (`ragged_cell_shape` for the per-head one)."""
+    seen = _pages_seen(starts, lens, kv_lens, page_size, n_pages, n_rows,
+                       q_block)
     lens = np.asarray(lens, np.int64)
     kv_lens = np.asarray(kv_lens, np.int64)
-    live = (lens > 0) & (kv_lens > 0)
-    pages_live = np.minimum(-(-kv_lens // ps), int(n_pages))
-    untiled = int((n_tiles * pages_live)[live].sum())
-    tiled = 0
-    # this runs in the engine's hot step loop (once per ragged kernel
-    # dispatch): descriptors are few (<= slots + 1), so loop those, but
-    # the tile axis — the factor that grows with the packed axis — is
-    # closed-form vectorized, never a Python loop
-    for start, ln, kv in zip(starts[live], lens[live], kv_lens[live]):
-        qt = np.arange(start // qb,
-                       min((start + ln - 1) // qb, n_tiles - 1) + 1)
-        last = np.minimum((qt + 1) * qb, start + ln) - 1
-        qpos_max = kv - ln + (last - start)
-        tiled += int((qpos_max // ps + 1).sum())
-    return tiled, untiled
+    pages_live = np.minimum(-(-kv_lens // int(page_size)), int(n_pages))
+    untiled = int((seen.shape[1] * pages_live)[(lens > 0)
+                                               & (kv_lens > 0)].sum())
+    return int(seen.sum()), untiled
+
+
+def ragged_score_groups(starts, lens, kv_lens, page_size, n_pages, n_rows):
+    """Host-side mirror of `ragged_work_list`'s count: the live
+    (descriptor, page group, tile) cells of these descriptors, which is
+    what the engine's `generation.step_grid_cells` is set from on a
+    per-head pool (times G: the page SLOTS walked, full or padded)."""
+    per, _, qb = ragged_cell_shape(page_size, n_pages, n_rows)
+    seen = _pages_seen(starts, lens, kv_lens, page_size, n_pages, n_rows, qb)
+    return int((-(-seen // per)).sum())
 
 
 def _decode_kernel(pt_ref, sl_ref, *refs, page_size, n_pages,
@@ -502,9 +580,19 @@ def _chunk_kernel(pt_ref, info_ref, *refs, page_size, n_pages, n_rows,
         o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
 
 
-def _ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref, *refs,
-                   page_size, q_block, tile_bits, page_bits, capacity,
-                   quantized=False):
+def _in_hbm(pool, interpret):
+    """The pool held in HBM by name.  Left to itself XLA:TPU's
+    memory-space assignment copies a whole pool that fits v5e's 128 MiB
+    of VMEM there in front of the call: a pool-sized operation a layer
+    a step (compile-only for v5e, PR 34)."""
+    if resolve_interpret(interpret):
+        return pool
+    return pltpu.with_memory_space_constraint(pool, pltpu.HBM)
+
+
+def _ragged_kernel(pt_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref, *refs,
+                   page_size, per, heads, n_pages, q_block, tile_bits,
+                   group_bits, capacity, quantized=False):
     """RAGGED mixed-batch paged attention, QUERY-TILED (the RPA paper's
     kernel shape), over a COMPACTED grid: packed query rows (decode
     singletons AND prefill-chunk runs in one token axis) attend through
@@ -513,85 +601,201 @@ def _ragged_kernel(pg_ref, cell_ref, cnt_ref, st_ref, ln_ref, kv_ref, *refs,
     position kv_ref[s] - ln_ref[s] + (r - st_ref[s]) and sees keys
     [0, position].
 
-    The grid is (head, w): step w is the w-th LIVE (descriptor, page,
-    query tile) cell of `ragged_work_list` — cell_ref[w] names it,
-    pg_ref[w] is its physical page (what the k/v index maps read) — and
-    computes one [q_block, page_size] score block.  A cell is on the
-    list only when its tile intersects the descriptor's row span AND
-    its page holds a key some in-span row of the tile can see, so a
-    1-token decode descriptor costs one step per visible page and a
-    padding descriptor (ln == 0) none; the tiles of one page are
-    consecutive, so Pallas elides the repeated page-block DMA.  The
-    second grid bound is TRACED (cnt_ref[0], held to [1, capacity]):
-    steps at or past the count — the lone step of an all-padding batch
-    — compute nothing.
+    The grid is (head block, w): step w is the w-th LIVE (descriptor,
+    page group, query tile) cell of `ragged_work_list` — cell_ref[w]
+    names it — for `heads` heads at once: one batched
+    ``[heads, q_block, D] x [heads, per * page_size, D]`` score product,
+    one softmax update and one value product over the group's keys.
+    The pools stay in HBM: a cell's `per` pages are not neighbours
+    there, so each is brought by one strided DMA ``pool[h0:h0 + heads,
+    page]`` (a page of every head of the block) into its slot of one
+    half of `kbuf` / `vbuf`, while the group before multiplies out of
+    the other half.  The tiles of one (descriptor, group) are
+    consecutive on the list and share the fetch: a cell whose
+    predecessor names the same group starts and waits for no copy, so a
+    chunk's context is read once a call, not once a tile.  For a slot
+    past the descriptor's last page nothing is fetched: its columns,
+    reckoned from the slot's own logical page, lie past every row's
+    horizon and are masked, and its V rows are zeroed (`_arrived`).
+    `slot_ref` holds which half the current group lives in.  The second
+    grid bound is TRACED (cnt_ref[0], held to [1, capacity]): steps at
+    or past the count — the lone step of an all-padding batch — compute
+    nothing.
     Online-softmax state spans the whole (tile-padded) token axis in
     scratch; each cell updates ITS tile's row slice.  Rows of a tile
     the descriptor doesn't own see an all-NEG_INF score row, whose
     update is the exact identity (alpha == exp(0) == 1, sum(p) == 0),
-    so tiles straddling a descriptor boundary stay exact.  Quantized
-    pools add the scale lane-row refs after q/k/v and each cell
-    dequantizes its page block in-kernel (see _decode_kernel)."""
-    q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = \
-        _split_refs(refs, quantized)
+    so tiles straddling a descriptor boundary stay exact.  int8 pools
+    add two refs after q/k/v: the group's per-(head, page) scales
+    ``[heads, 1, per]``, gathered through the page tables in front of
+    the call; a scale is constant over a page's keys, so it multiplies
+    the page's COLUMNS of the scores (K) and of the weights (V) instead
+    of the int8 blocks."""
+    if quantized:
+        q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref, *scratch = refs
+    else:
+        q_ref, k_hbm, v_hbm, o_ref, *scratch = refs
+    kbuf, vbuf, sem, slot_ref, acc_ref, m_ref, l_ref = scratch
+    h0 = pl.program_id(0) * heads
     w = pl.program_id(1)
+    count = cnt_ref[0]
+    n_keys = per * page_size
+    group_mask = (1 << (group_bits - tile_bits)) - 1
+
+    def group_of(step):
+        """The (descriptor | group) bits of the list's cell `step`."""
+        return cell_ref[step] >> tile_bits
+
+    fresh = (w == 0) | (group_of(w) != group_of(jnp.maximum(w - 1, 0)))
+
+    def slots(step, live, dead=None):
+        """`live(g, page)` for the slots of the list's cell `step` that
+        hold a page of its descriptor's context, `dead(g)` for the
+        slots past its last page: nothing is fetched for those."""
+        word = cell_ref[step]
+        s = word >> group_bits
+        first = ((word >> tile_bits) & group_mask) * per
+        last = jnp.clip((kv_ref[s] - 1) // page_size, 0, n_pages - 1)
+        for g in range(per):
+            # slot 0 is live on every cell: a group is on the list by
+            # its first page, and an all-padding batch's lone step
+            # fetches page 0 of its descriptor's table
+            @pl.when(first + g <= last)
+            def _():
+                live(g, pt_ref[s * n_pages + first + g])
+
+            if dead is not None:
+                @pl.when(first + g > last)
+                def _():
+                    dead(g)
+
+    def copies(half, act):
+        """`slots`' `live`: start, or wait for, a slot's K and V pages
+        of the head block into `half` of the buffers."""
+        def live(g, page):
+            for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                act(pltpu.make_async_copy(
+                    pool.at[pl.ds(h0, heads), page],
+                    buf.at[half, :, pl.ds(g * page_size, page_size)],
+                    sem.at[half]))
+
+        return live
 
     @pl.when(w == 0)
-    def _init():
+    def _first():
+        slot_ref[0] = 0
+        slots(w, copies(0, lambda copy: copy.start()))
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(w < cnt_ref[0])
+    @pl.when(fresh & (w > 0))
+    def _flip():
+        slot_ref[0] = 1 - slot_ref[0]
+
+    half = slot_ref[0]
+
+    @pl.when((w + 1 < pl.num_programs(1))
+             & (group_of(jnp.minimum(w + 1, capacity - 1)) != group_of(w)))
+    def _next():
+        slots(w + 1, copies(1 - half, lambda copy: copy.start()))
+
+    @pl.when(fresh)
+    def _arrived():
+        def dead(g):
+            # what an earlier group (or no one) left in a slot nothing
+            # was fetched for: its columns are masked, but a weight of
+            # exactly 0 times a stale NaN is no 0.  The scores need no
+            # such care: the mask SELECTS them away
+            vbuf[half, :, g * page_size:(g + 1) * page_size, :] = jnp.zeros(
+                (heads, page_size, vbuf.shape[3]), vbuf.dtype)
+
+        slots(w, copies(half, lambda copy: copy.wait()), dead)
+
+    @pl.when(w < count)
     def _compute():
         cell = cell_ref[w]
-        s = cell >> page_bits
-        i = (cell >> tile_bits) & ((1 << (page_bits - tile_bits)) - 1)
+        s = cell >> group_bits
+        group = (cell >> tile_bits) & group_mask
         row0 = (cell & ((1 << tile_bits) - 1)) * q_block
+        if q_block % 8 == 0:
+            row0 = pl.multiple_of(row0, 8)
         start = st_ref[s]
         ln = ln_ref[s]
         kv_len = kv_ref[s]
-        rows_sl = pl.dslice(row0, q_block)
-        q = q_ref[0, rows_sl]                      # [q_block, D]
-        k = k_ref[0, 0]                            # [page_size, D]
-        v = v_ref[0, 0]
+        rows_sl = pl.ds(row0, q_block)
+        q = q_ref[:, rows_sl, :]                   # [heads, q_block, D]
+        k = kbuf[half]                             # [heads, n_keys, D]
+        v = vbuf[half]
         if quantized:
-            page = pg_ref[w]
-            k = _dequant_page(k, ks_ref, page)
-            v = _dequant_page(v, vs_ref, page)
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
+        sc = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
+        slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_keys),
+                                        2) // page_size
+
+        def key_scales(s_ref):
+            """[heads, 1, n_keys]: each key's page's ``scale * 1/127``,
+            by a one-hot select a slot and a sum of exact zeros."""
+            scales = s_ref[0, 0, 0] * INV_QMAX     # [heads, 1, per]
+            return sum(jnp.where(slot == g, scales[:, :, g:g + 1], 0.0)
+                       for g in range(per))
+
+        if quantized:
+            sc = sc * key_scales(ks_ref)
         row = row0 + jax.lax.broadcasted_iota(
-            jnp.int32, (q_block, page_size), 0)
-        col = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (q_block, page_size), 1)
+            jnp.int32, (q_block, n_keys), 0)
+        col = group * n_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (q_block, n_keys), 1)
         mine = (row >= start) & (row < start + ln)
-        qpos = kv_len - ln + (row - start)
-        sc = jnp.where(mine & (col <= qpos), sc, NEG_INF)
-        m_prev = jnp.max(m_ref[rows_sl], axis=1, keepdims=True)  # [qb, 1]
-        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        # held under the page tables' width, past which a slot repeats
+        # a page the row has already met
+        qpos = jnp.minimum(kv_len - ln + (row - start),
+                           n_pages * page_size - 1)
+        visible = (mine & (col <= qpos))[None]     # [1, q_block, n_keys]
+        sc = jnp.where(visible, sc, NEG_INF)
+        m_prev = jnp.max(m_ref[:, rows_sl, :], axis=2, keepdims=True)
+        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(sc - m_cur)                    # [qb, page_size]
-        p = jnp.where(sc <= NEG_INF / 2, 0.0, p)   # masked keys: exactly 0
-        l_prev = jnp.max(l_ref[rows_sl], axis=1, keepdims=True)
-        l_cur = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        p = jnp.where(visible, jnp.exp(sc - m_cur), 0.0)  # masked: exactly 0
+        l_prev = jnp.max(l_ref[:, rows_sl, :], axis=2, keepdims=True)
+        l_cur = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        if quantized:
+            p = p * key_scales(vs_ref)
         pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
+                                 (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
-        acc_ref[rows_sl] = acc_ref[rows_sl] * alpha + pv
-        m_ref[rows_sl] = jnp.broadcast_to(m_cur, (q_block,
-                                                  m_ref.shape[1]))
-        l_ref[rows_sl] = jnp.broadcast_to(l_cur, (q_block,
-                                                  l_ref.shape[1]))
+        acc_ref[:, rows_sl, :] = acc_ref[:, rows_sl, :] * alpha + pv
+        state = (heads, q_block, m_ref.shape[2])
+        m_ref[:, rows_sl, :] = jnp.broadcast_to(m_cur, state)
+        l_ref[:, rows_sl, :] = jnp.broadcast_to(l_cur, state)
 
     @pl.when(w == pl.num_programs(1) - 1)
     def _finalize():
-        l = jnp.max(l_ref[...], axis=1, keepdims=True)
+        l = jnp.max(l_ref[...], axis=2, keepdims=True)
         safe_l = jnp.where(l > 0.0, l, 1.0)  # unclaimed rows: zeros
         # more live cells than the list holds (descriptors that overlap:
         # ragged_grid_cells) must not pass for attention
-        fits = jnp.where(cnt_ref[0] > capacity, jnp.nan, 1.0)
-        o_ref[0] = (acc_ref[...] / safe_l * fits).astype(o_ref.dtype)
+        fits = jnp.where(count > capacity, jnp.nan, 1.0)
+        o_ref[...] = (acc_ref[...] / safe_l * fits).astype(o_ref.dtype)
+
+
+def _group_scales(scale, page_tables, per, heads):
+    """[P, H] per-page per-head scales -> ``[S, groups, H / heads,
+    heads, 1, per]``: the scales of each descriptor's logical pages a
+    group, a head block at a time (the kernel's `key_scales` reads one
+    ``[heads, 1, per]`` block a cell).  O(descriptors x pages x heads)
+    numbers, gathered through the page tables."""
+    n_seqs, n_pages = page_tables.shape
+    n_groups = -(-n_pages // per)
+    by_page = jnp.asarray(scale, jnp.float32)[page_tables]   # [S, P', H]
+    by_page = jnp.pad(by_page,
+                      ((0, 0), (0, n_groups * per - n_pages), (0, 0)))
+    h = by_page.shape[2]
+    return jnp.transpose(
+        by_page.reshape(n_seqs, n_groups, per, h // heads, heads),
+        (0, 1, 3, 4, 2)).reshape(n_seqs, n_groups, h // heads, heads, 1, per)
 
 
 def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
@@ -612,21 +816,20 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
     builds it once for all its layers; built here when None.
     Returns [T, H, D].
 
-    The grid is (heads, live cells): the work list names each step's
-    (descriptor, page, query tile) and physical page, and rides with
-    the descriptors as scalar-prefetch operands, so the BlockSpec
-    index_map DMAs each cell's page straight out of the pool
-    (see _ragged_kernel; ragged_score_blocks mirrors the list's count
-    host-side for the FLOP-proxy counter).
+    ONE call a layer whatever the step carries.  The grid is (head
+    blocks, live cells): the work list names each step's (descriptor,
+    page group, query tile) and rides with the flat page tables and the
+    descriptors as scalar-prefetch operands; the kernel's own DMAs
+    bring each group's pages of a head block out of the pools, which
+    stay in HBM (see _ragged_kernel; `ragged_cell_shape` states the
+    cell, ragged_score_groups mirrors the list's count host-side).
 
-    LIMIT: the list lives whole in SMEM (1 MiB on v5e), two int32 words
-    a cell at the capacity ``(n_tiles + S - 1) * max_pages``
-    (`ragged_grid_cells`); the page tables themselves no longer do.
-    The benchmark's 17 descriptors x 80 rows take 26 KiB at 2k context
-    (128 pages) and 832 KiB at 64k (4096 pages); the compiler refuses
-    them at 128k (8192 pages: 1.63 MiB), and 65 descriptors x 128 rows
-    at 32k (2048 pages: 1.25 MiB; 16k fits) — compile-only for v5e,
-    PR 25.  Long-context serving needs the list blocked; not done here.
+    LIMIT: SMEM (1 MiB on v5e) holds the page tables (S x max_pages
+    words) and the list (one word a cell, ``(n_tiles + S - 1) x
+    ceil(max_pages / G)`` of them).  The benchmark's 17 descriptors x
+    80 rows take 10 KiB at 2k context (128 pages) and 325 KiB at 64k
+    (4,096 pages); 128k (8,192 pages: 650 KiB) fits where the one-page
+    list did not.  VMEM holds the blocks `ragged_cell_shape` counts.
 
     mesh / tp_axis runs the shard_map'd form: the same kernel per shard
     on num_heads/tp heads over that shard's pool slice (_head_shard_map),
@@ -640,25 +843,28 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
     page_size = k_pool.shape[2 if layout == "kernel" else 1]
     starts, lens, kv_lens = (jnp.asarray(x, jnp.int32)
                              for x in (starts, lens, kv_lens))
+    page_tables = jnp.asarray(page_tables, jnp.int32)
+    n_seqs, n_pages = page_tables.shape
     if work is None:
-        work = ragged_work_list(page_tables, starts, lens, kv_lens,
-                                page_size, t)
+        work = ragged_work_list(starts, lens, kv_lens, page_size, n_pages, t)
     if mesh is not None:
         def body(q_, kp_, vp_, *rest):
             # rest: (k_scale, v_scale) when quantized, then the scalars
-            *scales_, pt_, st_, ln_, kv_, pages_, cells_, count_ = rest
+            *scales_, pt_, st_, ln_, kv_, cells_, count_ = rest
             return ragged_paged_attention_kernel(
                 q_, kp_, vp_, pt_, st_, ln_, kv_, scale,
                 interpret=interpret, layout=layout,
-                work=(pages_, cells_, count_),
+                work=(cells_, count_),
                 **dict(zip(("k_scale", "v_scale"), scales_)))
 
         return _head_shard_map(
             body, mesh, tp_axis, layout, q, k_pool, v_pool,
-            jnp.asarray(page_tables, jnp.int32), starts, lens, kv_lens,
+            page_tables, starts, lens, kv_lens,
             *work, scales=((k_scale, v_scale) if quantized else None))
     _reject_mesh_sharded_pool(k_pool)
-    qb, n_tiles = ragged_query_tiles(t)
+    per, heads, qb = ragged_cell_shape(page_size, n_pages, t, h, d,
+                                       k_pool.dtype.itemsize)
+    n_tiles = ragged_query_tiles(t, qb)[1]
     tpad = n_tiles * qb
     qs = jnp.transpose((q * scale).astype(q.dtype), (1, 0, 2))  # [H, T, D]
     if tpad != t:
@@ -671,45 +877,85 @@ def ragged_paged_attention_kernel(q, k_pool, v_pool, page_tables, starts,
     else:
         kt = jnp.transpose(k_pool, (2, 0, 1, 3))
         vt = jnp.transpose(v_pool, (2, 0, 1, 3))
+    if d % 128 and not resolve_interpret(interpret):
+        # Mosaic cuts a DMA out of an HBM pool in whole 128-lane rows:
+        # a narrower head rides zero lanes (in the copy a token-layout
+        # pool costs anyway), which add exact zeros to every score
+        widen = [(0, 0)] * 3 + [(0, -d % 128)]
+        qs = jnp.pad(qs, widen[1:])
+        kt, vt = jnp.pad(kt, widen), jnp.pad(vt, widen)
+    out = _ragged_call(page_tables, *work, starts, lens, kv_lens, qs, kt, vt,
+                       (k_scale, v_scale) if quantized else (),
+                       cell=(per, heads, qb),
+                       interpret=resolve_interpret(interpret))
+    return jnp.transpose(out[:, :t, :d], (1, 0, 2))
+
+
+@functools.partial(jax.jit, static_argnames=("cell", "interpret"))
+def _ragged_call(page_tables, cells, count, starts, lens, kv_lens, qs, kt, vt,
+                 scales, *, cell, interpret):
+    """The `pallas_call` of `ragged_paged_attention_kernel`: qs [H, Tpad,
+    W] scaled, padded to whole tiles and lanes, kt / vt [H, P,
+    page_size, W], `cell` = `ragged_cell_shape`.  Jitted, so that the
+    layers of one step, which call it on the same shapes, trace and
+    lower the kernel ONCE (a step program's tracing and lowering went
+    from 1.0 to 2.6 s with the grouped cell's unrolled copies a layer,
+    +11 s of set-up over a cell's pages buckets: PERF.md, PR 35); the
+    cell rides as a static argument because the trace depends on it."""
+    per, heads, qb = cell
+    h, tpad, width = qs.shape
+    page_size = kt.shape[2]
     n_seqs, n_pages = page_tables.shape
-    tile_bits, page_bits = _cell_bits(n_seqs, n_pages, n_tiles)
-    pages, cells, count = work
+    capacity = cells.shape[0]
+    quantized = bool(scales)
+    n_groups = -(-n_pages // per)
+    tile_bits, group_bits = _cell_bits(n_seqs, n_groups, tpad // qb)
 
-    # scalar-prefetch operands (SMEM): the work list + descriptors
-    prefetch = [pages, cells, count, starts, lens, kv_lens]
+    # scalar-prefetch operands (SMEM): the page tables, the work list
+    # and the descriptors
+    prefetch = [page_tables.reshape(-1), cells, count, starts, lens,
+                kv_lens]
+    group_mask = (1 << (group_bits - tile_bits)) - 1
 
-    def page_of(h_, w, pg_ref, *_):
-        return h_, pg_ref[w]
+    def scales_of(hb, w, pt_ref, cell_ref, *_):
+        word = cell_ref[w]
+        return (word >> group_bits, (word >> tile_bits) & group_mask, hb,
+                0, 0, 0)
 
-    scales = ([_scale_rows(k_scale), _scale_rows(v_scale)]
-              if quantized else [])
+    whole = pl.BlockSpec((heads, tpad, width),
+                         lambda hb, w, *refs: (hb, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    scales = [_group_scales(x, page_tables, per, heads) for x in scales]
+    lanes = max(width, 128)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         # one step per live cell (a traced bound); q/out ride whole-axis
-        # blocks fetched once per head
-        grid=(h, ragged_grid_cells(n_seqs, n_pages, t, live=count[0])),
-        in_specs=[
-            pl.BlockSpec((1, tpad, d), lambda h_, w, *refs: (h_, 0, 0)),
-            *_pool_specs(page_of, page_size, d, len(scales)),
-        ],
-        out_specs=pl.BlockSpec((1, tpad, d),
-                               lambda h_, w, *refs: (h_, 0, 0)),
+        # blocks fetched once per head block
+        grid=(h // heads, _held_to(capacity, count[0])),
+        in_specs=[whole, in_hbm, in_hbm] + [
+            pl.BlockSpec((1, 1, 1, heads, 1, per), scales_of)] * len(scales),
+        out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((tpad, d), jnp.float32),
-            pltpu.VMEM((tpad, 128), jnp.float32),
-            pltpu.VMEM((tpad, 128), jnp.float32),
+            pltpu.VMEM((2, heads, per * page_size, width), kt.dtype),
+            pltpu.VMEM((2, heads, per * page_size, width), vt.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((heads, tpad, width), jnp.float32),
+            pltpu.VMEM((heads, tpad, lanes), jnp.float32),
+            pltpu.VMEM((heads, tpad, lanes), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
-        functools.partial(_ragged_kernel, page_size=page_size, q_block=qb,
-                          tile_bits=tile_bits, page_bits=page_bits,
-                          capacity=ragged_grid_cells(n_seqs, n_pages, t),
-                          quantized=quantized),
+    return pl.pallas_call(
+        functools.partial(_ragged_kernel, page_size=page_size, per=per,
+                          heads=heads, n_pages=n_pages, q_block=qb,
+                          tile_bits=tile_bits, group_bits=group_bits,
+                          capacity=capacity, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((h, tpad, d), q.dtype),
-        interpret=resolve_interpret(interpret),
-    )(*prefetch, qs, kt, vt, *scales)
-    return jnp.transpose(out[:, :t], (1, 0, 2))
+        out_shape=jax.ShapeDtypeStruct((h, tpad, width), qs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=RAGGED_VMEM_LIMIT),
+        interpret=interpret,
+    )(*prefetch, qs, _in_hbm(kt, interpret), _in_hbm(vt, interpret), *scales)
 
 
 def chunk_prefill_attention_kernel(q, k_pool, v_pool, page_table, start,
@@ -930,11 +1176,7 @@ def latent_grid_cells(n_seqs, n_pages, n_rows, page_size, live=None):
     per = latent_pages_per_cell(page_size, n_pages)
     capacity = (ragged_query_tiles(n_rows)[1] + n_seqs - 1) * -(-n_pages
                                                                 // per)
-    if live is None:
-        return capacity
-    if isinstance(live, jax.Array):
-        return jnp.clip(live, 1, capacity)
-    return min(max(int(live), 1), capacity)
+    return _held_to(capacity, live)
 
 
 def latent_score_groups(starts, lens, kv_lens, page_size, n_pages, n_rows):
@@ -943,15 +1185,8 @@ def latent_score_groups(starts, lens, kv_lens, page_size, n_pages, n_rows):
     descriptors, which is what the engine's
     `generation.step_grid_cells` is set from on a latent pool (times G:
     the page SLOTS walked, full or padded)."""
-    qb, n_tiles = ragged_query_tiles(n_rows)
-    st, ln, kv = (np.asarray(x, np.int64)[:, None]
-                  for x in (starts, lens, kv_lens))            # [S, 1]
-    end = st + ln
-    qt = np.arange(n_tiles)[None, :]                           # [1, Q]
-    meets = (ln > 0) & (qt >= st // qb) & (qt <= (end - 1) // qb)
-    horizon = kv - ln + (np.minimum((qt + 1) * qb, end) - 1 - st)
-    seen = np.where(meets, np.clip(horizon // int(page_size) + 1, 0,
-                                   int(n_pages)), 0)
+    seen = _pages_seen(starts, lens, kv_lens, page_size, n_pages, n_rows,
+                       None)
     return int((-(-seen // latent_pages_per_cell(page_size, n_pages))).sum())
 
 

@@ -81,16 +81,31 @@ def test_ragged_kernel_compiles_for_v5e(v5e, kv_dtype):
              ((s,), "int32"), *_pool_operands(kv_dtype))
 
 
-@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
-@pytest.mark.parametrize("pages_bucket", [16, 64, 128])
+# the pages buckets opt-6.7b-d8's cells dispatch: powers of two up to
+# its 2,048 positions in 16-token pages
+OPT_PAGES_BUCKETS = [1, 2, 4, 8, 16, 32, 64, 128]
+
+
+@pytest.mark.parametrize("pages_bucket,kv_dtype", [
+    *((b, "float32") for b in OPT_PAGES_BUCKETS),
+    (16, "int8"), (64, "int8"), (128, "int8")])
 def test_ragged_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket,
                                                         kv_dtype):
     """opt-6.7b-d8's serving cells (benchmarks/configs/opt-6.7b-d8.json):
     32 heads of 128, 16 decode slots + a 64-token chunk = 80 packed rows
     under 17 descriptors, a 1280-page pool, one executable a pages
-    bucket.  The work list ((10 tiles + 16) x bucket cells, two SMEM
-    words each, built in the same trace) and the traced grid bound lower,
-    and the list fits SMEM at the largest bucket."""
+    bucket.  A cell is 8 pages x 32 heads x 8 rows (`ragged_cell_shape`):
+    in VMEM the double-buffered K and V groups (4 x 32 x 128 keys x 512 B
+    = 8 MiB; 2 MiB in int8) beside q, the output and the online-softmax
+    state over the 80 rows (7 x 32 x 80 x 512 B = 8.75 MiB), under the
+    limit the call asks Mosaic for; in SMEM the flat page tables (17 x
+    bucket words) and the list ((10 tiles + 16) x bucket / 8 cell words,
+    built in the same trace): 2,176 + 416 words at the largest bucket.
+    The traced grid bound lowers, and the pools are read where they are
+    stored: nothing of a pool's size is made beside the token layout's
+    transposes."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
     heads, pages, t, s = 32, 1280, 80, 17
     pool = ((pages, PAGE_SIZE, heads, HEAD_DIM), kv_dtype)
     scale = ((pages, heads), np.float32)
@@ -99,10 +114,47 @@ def test_ragged_kernel_compiles_at_the_benchmark_shapes(v5e, pages_bucket,
         return ragged_paged_attention(q, kp, vp, pt, starts, lens, kv_lens,
                                       use_kernel=True, **_scales(rest))
 
-    _compile(fn, v5e, ((t, heads, HEAD_DIM), "float32"),
-             ((s, pages_bucket), "int32"), ((s,), "int32"), ((s,), "int32"),
-             ((s,), "int32"), pool, pool,
-             *([scale, scale] if kv_dtype == "int8" else []))
+    compiled = _compile(
+        fn, v5e, ((t, heads, HEAD_DIM), "float32"),
+        ((s, pages_bucket), "int32"), ((s,), "int32"), ((s,), "int32"),
+        ((s,), "int32"), pool, pool,
+        *([scale, scale] if kv_dtype == "int8" else []))
+    itemsize = np.dtype(kv_dtype).itemsize
+    per, hb, qb = pa.ragged_cell_shape(PAGE_SIZE, pages_bucket, t, heads,
+                                       HEAD_DIM, itemsize)
+    assert (per, hb, qb) == (min(8, pages_bucket), 32, 8)
+    vmem = (4 * hb * per * PAGE_SIZE * HEAD_DIM * itemsize
+            + 7 * hb * t * HEAD_DIM * 4)
+    assert vmem <= pa.RAGGED_VMEM_BUDGET < pa.RAGGED_VMEM_LIMIT
+    if pages_bucket == 128:
+        assert vmem == {4: (8 << 20) + 35 * (256 << 10),
+                        1: (2 << 20) + 35 * (256 << 10)}[itemsize]
+    cells = pa.ragged_grid_cells(s, pages_bucket, t, PAGE_SIZE)
+    assert cells == 26 * -(-pages_bucket // per)
+    # the scalar-prefetch operands lead the call: the traced grid bound,
+    # the flat page tables, the cell words (benchmarks/trace/kernels.py
+    # tells `pallas:ragged` by that s32 first operand)
+    (call,) = _custom_call_operands(compiled.as_text())
+    assert call[:3] == ["s32[]", f"s32[{s * pages_bucket}]", f"s32[{cells}]"]
+
+
+@pytest.mark.parametrize("pages_bucket", OPT_PAGES_BUCKETS)
+def test_the_opt_step_compiles_at_every_pages_bucket(v5e, pages_bucket):
+    """The whole 8-layer ragged step of opt-6.7b-d8 (vocabulary cut,
+    weights as shapes): it fits the chip beside its float32 weights and
+    5 GiB of pools, ONE attention call a layer, which alone leads with
+    an s32 operand (`kernel.ragged_roofline` divides a layer's floor by
+    that call's time), two in-place row writes a layer, and nothing else
+    that yields a pool."""
+    others, in_place, temp_bytes, calls = _pool_sized_results(
+        v5e, "kernel", pages_bucket, layers=8)
+    assert (others, in_place) == (set(), 16)
+    assert temp_bytes < 64 << 20
+    assert len(calls) == 8 * 3
+    leading_s32 = [call for call in calls if call[0].startswith("s32")]
+    assert len(leading_s32) == 8
+    assert all(call[1] == f"s32[{17 * pages_bucket}]"
+               for call in leading_s32)
 
 
 @pytest.mark.parametrize("heads,pages,rows", [
@@ -148,6 +200,39 @@ def test_pool_row_scatter_compiles_on_the_head_sharded_mesh(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def test_ragged_kernel_compiles_on_the_head_sharded_mesh(v5e_2x2):
+    """The tp=4 server's form of opt-6.7b-d8's attention call: each chip
+    runs the grouped cell over its 8 heads (Hb follows the shard) and its
+    slice of the pools; the list and the page tables are replicated; no
+    collective, no gathered pool."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(v5e_2x2), ("model",))
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, np.dtype(dtype),
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    pool = sds((32, 1280, PAGE_SIZE, HEAD_DIM), "float32", "model")
+
+    def fn(q, pt, starts, lens, kv_lens, kp, vp):
+        return ragged_paged_attention(q, kp, vp, pt, starts, lens, kv_lens,
+                                      use_kernel=True, layout="kernel",
+                                      mesh=mesh, tp_axis="model")
+
+    compiled = jax.jit(fn).lower(
+        sds((80, 32, HEAD_DIM), "float32", None, "model"),
+        sds((17, 64), "int32"), *[sds((17,), "int32")] * 3, pool,
+        pool).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "f32[8,1280,16,128]" in text
+    assert not any(op in text for op in ("all-gather", "all-reduce",
+                                         "collective-permute"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+    (call,) = _custom_call_operands(text)
+    assert call[:3] == ["s32[]", "s32[1088]", "s32[208]"]
+
+
 def _opt_step_lowered(v5e, layout, pages_bucket, layers=2):
     """TinyCausalLM's ragged step at opt-6.7b-d8's shapes (32 heads of
     128, a 1280-page pool, 80 packed rows under 17 descriptors; depth and
@@ -184,7 +269,8 @@ def _opt_step_lowered(v5e, layout, pages_bucket, layers=2):
 def _pool_sized_results(v5e, layout, pages_bucket, layers=2):
     """That step compiled: the opcodes of the instructions that yield a
     whole pool and are not the in-place row write, the count of those
-    that are, and the program's temporaries in bytes."""
+    that are, the program's temporaries in bytes, and the operand
+    types of its custom calls."""
     heads, pages = 32, 1280
     lowered, shape = _opt_step_lowered(v5e, layout, pages_bucket, layers)
     compiled = lowered.compile()
@@ -200,7 +286,8 @@ def _pool_sized_results(v5e, layout, pages_bucket, layers=2):
                 if op == "custom-call" and aliased]
     return ({op for op, aliased in whole if op != "parameter"
              and not (op == "custom-call" and aliased)},
-            len(in_place), compiled.memory_analysis().temp_size_in_bytes)
+            len(in_place), compiled.memory_analysis().temp_size_in_bytes,
+            _custom_call_operands(text))
 
 
 @pytest.mark.parametrize("pages_bucket", [16, 128])
@@ -209,17 +296,18 @@ def test_ragged_step_moves_no_pool_in_kernel_layout(v5e, pages_bucket):
     instructions that yield a whole pool (335 MB) are the K and V row
     writes of each layer, each aliased to its operand; no copy, no
     transpose, and temporaries far under one pool."""
-    others, in_place, temp_bytes = _pool_sized_results(v5e, "kernel",
-                                                       pages_bucket)
+    others, in_place, temp_bytes, _ = _pool_sized_results(v5e, "kernel",
+                                                          pages_bucket)
     assert (others, in_place) == (set(), 4)
     assert temp_bytes < 64 << 20
 
 
 # sha256 of the OPT step's lowered text with the kernels' source
-# locations dropped, by pages bucket; as PR 32's tree lowers it
+# locations dropped, by pages bucket; as PR 35's tree lowers it (the
+# grouped cell of the per-head kernel)
 OPT_STEP_DIGESTS = {
-    16: "0403b9b78255bed19710606c5f416e0ad1eed21072a618b91efed296474d22e0",
-    128: "6e07098212d7b218f86a700a5b4b9d4e20b275cc60c22e80ab75c53b20fc2f6c",
+    16: "1aefcf194a6f5ab6a6dd76196ff4ee29c949cd9714fa3d254264b784ad6b7972",
+    128: "2d44213ba105dcd89fa6a888350f27f6461a190af9c2f0dbdbe25984f9cd48a9",
 }
 
 
@@ -233,8 +321,9 @@ def test_the_opt_ragged_step_lowers_to_the_text_it_had(v5e, pages_bucket):
     line of the kernel; all else counts.  A PR that means to change what
     the OPT cells run states the new digests here."""
     lowered, _ = _opt_step_lowered(v5e, "kernel", pages_bucket)
-    # a layer: two row writes, one attention
-    digest = _step_digest(lowered, 2 * 3)
+    # a layer: two row writes; the attention call is one jitted
+    # function of the step, lowered once for its two layers
+    digest = _step_digest(lowered, 2 * 2 + 1)
     assert digest == OPT_STEP_DIGESTS[pages_bucket]
 
 
@@ -310,12 +399,12 @@ def test_the_pool_check_tells_the_token_layout(v5e, monkeypatch):
     from paddle_tpu.ops.pallas import paged_attention
 
     pool_bytes = 32 * 1280 * PAGE_SIZE * HEAD_DIM * 4
-    others, in_place, temp_bytes = _pool_sized_results(v5e, "token", 16)
+    others, in_place, temp_bytes, _ = _pool_sized_results(v5e, "token", 16)
     assert {"scatter", "fusion"} <= others and in_place == 0
     assert temp_bytes > pool_bytes
     monkeypatch.setattr(paged_attention, "pool_scatter_in_place",
                         lambda shape, dtype: False)
-    others, in_place, temp_bytes = _pool_sized_results(v5e, "kernel", 16)
+    others, in_place, temp_bytes, _ = _pool_sized_results(v5e, "kernel", 16)
     assert {"scatter", "copy"} <= others and in_place == 0
     assert temp_bytes > pool_bytes
 

@@ -17,8 +17,8 @@ import pytest
 from paddle_tpu import generation as gen
 from paddle_tpu import profiler
 from paddle_tpu.generation import metrics as gmetrics
-from paddle_tpu.ops.pallas.paged_attention import (ragged_grid_cells,
-                                                   ragged_query_tiles)
+from paddle_tpu.ops.pallas import paged_attention as pa
+from paddle_tpu.ops.pallas.paged_attention import ragged_grid_cells
 from paddle_tpu.profiler.monitor import StatRegistry
 from paddle_tpu.serving import fleet as fleet_mod
 from paddle_tpu.serving.disagg.worker import _StreamHandle
@@ -404,11 +404,17 @@ def test_the_relay_reads_and_writes_the_clients_stamps():
 # ----------------------- the kernel grid's denominator -------------------
 
 
-def test_grid_cells_per_dispatch_on_the_kernel_path(model):
+@pytest.mark.parametrize("cell_tokens", [8, 128])
+def test_grid_cells_per_dispatch_on_the_kernel_path(model, monkeypatch,
+                                                    cell_tokens):
+    """Groups of two pages, and the module's own 128 keys (here every
+    page of a bucket in one group)."""
+    monkeypatch.setattr(pa, "RAGGED_CELL_TOKENS", cell_tokens)
     eng = _engine(model, slots=6, chunk=16, use_kernel=True)
     handles = [eng.submit(p, max_new_tokens=8) for p in PROMPTS]
     step = eng._ragged
-    assert ragged_query_tiles(step.max_tokens)[1] == math.ceil(22 / 8)
+    page_size = eng.cache.page_size
+    assert pa.ragged_cell_shape(page_size, 64, step.max_tokens)[2] == 8
     cells = dispatches = 0       # chunk 16 + 6 slots, q_block 8
     while eng.scheduler.active() or eng.scheduler.pending_count():
         before = eng.metrics.snapshot()
@@ -420,19 +426,28 @@ def test_grid_cells_per_dispatch_on_the_kernel_path(model):
             dispatches += 1
             cells += grew
             shape = (step.max_seqs, step.last_pages_bucket, step.max_tokens)
-            assert grew == step.last_grid_cells == ragged_grid_cells(
-                *shape, live=step.last_score_blocks)
-            # (3 tiles + 7 descriptors - 1) x pages bucket: the list
-            assert grew <= ragged_grid_cells(*shape) == (
-                9 * step.last_pages_bucket)
+            per = pa.ragged_cell_shape(page_size, *shape[1:])[0]
+            assert per == min(cell_tokens // page_size, shape[1])
+            # page slots: G a cell the grid walked, which hold the
+            # visible (tile, page) pairs and padding
+            assert grew == step.last_grid_cells and grew % per == 0
+            # (3 tiles + 7 descriptors - 1) x the bucket's groups: the list
+            assert step.last_score_blocks <= grew <= per * ragged_grid_cells(
+                *shape, page_size) == per * 9 * -(-shape[1] // per)
     for h in handles:
         h.result(timeout=5)
     snap = eng.metrics.snapshot()
     assert dispatches > 5 and snap[gmetrics.STEP_GRID_CELLS] == cells
-    # the grid is the live cells: every step of it computes, and it is
-    # smaller than what the kernel without query tiles would compute
-    assert 0 < snap[gmetrics.STEP_SCORE_BLOCKS] == cells
-    assert cells < snap[gmetrics.STEP_SCORE_BLOCKS_UNTILED]
+    # the grid is the live cells' page slots: the part of them that
+    # computes is what the kernel without query tiles would compute, less
+    # the tiles outside a descriptor's rows
+    assert 0 < snap[gmetrics.STEP_SCORE_BLOCKS] < cells
+    assert (snap[gmetrics.STEP_SCORE_BLOCKS]
+            < snap[gmetrics.STEP_SCORE_BLOCKS_UNTILED])
+    assert (snap["generation.ragged_pages_per_cell"],
+            snap["generation.ragged_heads_per_cell"]) == (
+        cell_tokens // page_size, model.num_heads)
+    assert snap["generation.latent_pages_per_cell"] == 0
     eng.shutdown()
 
 
@@ -440,6 +455,8 @@ def test_grid_cells_are_zero_on_the_reference_path(model):
     eng = _engine(model, slots=6, chunk=16)
     _serve(eng, PROMPTS)
     snap = eng.metrics.snapshot()
+    assert (snap["generation.ragged_pages_per_cell"],
+            snap["generation.ragged_heads_per_cell"]) == (0, 0)
     assert snap.get(gmetrics.STEP_GRID_CELLS, 0) == 0
     assert snap.get(gmetrics.STEP_SCORE_BLOCKS, 0) == 0
     assert snap[gmetrics.STEPS_TOTAL] > 0

@@ -228,38 +228,48 @@ def test_latent_kernel_in_interpret_mode_equals_the_jnp_form(mix,
     # tile-major: each exactly once, beside its physical page
     assert pa.latent_pages_per_cell(PAGE, mp) == GROUP
     grouped = pa.latent_work_list(pt, st, ln, kvl, PAGE, t)
-    pages2, cells2, count2 = (np.asarray(x) for x in pa.ragged_work_list(
-        pt, st, ln, kvl, PAGE, t))
-    n2 = int(count2[0])
-    assert n2 == pa.ragged_score_blocks(st, ln, kvl, PAGE, mp, t)[0]
-    _assert_the_groups_hold(grouped, (pages2[:n2], cells2[:n2]), pt, st, ln,
-                            kvl, PAGE, t)
+    _assert_the_groups_hold(grouped, pt, st, ln, kvl, PAGE, t)
     assert int(grouped[2][0]) == pa.latent_score_groups(
         st, ln, kvl, PAGE, mp, t)
 
 
-def _assert_the_groups_hold(grouped, ragged, pt, st, ln, kvl, page_size, t):
-    """The grouped list against `ragged_work_list`'s live ``(pages,
-    cells)``: every live (descriptor, page, tile) cell sits in exactly
-    one slot beside its physical page; every other slot of a live group
-    is padding — a logical page past its tile's horizon, which the
-    kernel masks, holding a page of that descriptor's table (a valid
-    fetch); the tiles never go back; the tail repeats the last cell."""
+def _live_cells(pt, st, ln, kvl, page_size, t):
+    """The skip rule, spelled out: ``{(descriptor, page, tile): physical
+    page}`` over the cells whose tile meets the descriptor's rows and
+    whose page starts at or under the horizon of the tile's last
+    in-span row."""
+    qb, n_tiles = pa.ragged_query_tiles(t)
+    live = {}
+    for s in range(pt.shape[0]):
+        for i in range(pt.shape[1]):
+            for qt in range(n_tiles):
+                last = min((qt + 1) * qb, st[s] + ln[s]) - 1
+                if (ln[s] > 0 and qt * qb < st[s] + ln[s]
+                        and (qt + 1) * qb > st[s]
+                        and i * page_size <= kvl[s] - ln[s] + last - st[s]):
+                    live[s, i, qt] = int(pt[s, i])
+    return live
+
+
+def _assert_the_groups_hold(grouped, pt, st, ln, kvl, page_size, t):
+    """The grouped list against the live (descriptor, page, tile) set:
+    every live cell sits in exactly one slot beside its physical page;
+    every other slot of a live group is padding — a logical page past
+    its tile's horizon, which the kernel masks, holding a page of that
+    descriptor's table (a valid fetch); the tiles never go back; the
+    tail repeats the last cell."""
     s, mp = pt.shape
     per = pa.latent_pages_per_cell(page_size, mp)
     qb, n_tiles = pa.ragged_query_tiles(t)
     tile_bits, group_bits = pa._cell_bits(s, -(-mp // per), n_tiles)
-    _, page_bits2 = pa._cell_bits(s, mp, n_tiles)
     pages, cells, count = (np.asarray(x) for x in grouped)
     n = int(count[0])
     assert len(cells) == pa.latent_grid_cells(s, mp, t, page_size) >= n
     assert len(pages) == len(cells) * per
     pages = pages.reshape(-1, per)
-    want = {(int(c) >> page_bits2,
-             (int(c) >> tile_bits) & ((1 << (page_bits2 - tile_bits)) - 1),
-             int(c) & ((1 << tile_bits) - 1)): int(p)
-            for p, c in zip(*ragged)}
-    assert len(want) == len(ragged[0])
+    want = _live_cells(pt, st, ln, kvl, page_size, t)
+    assert len(want) == pa.ragged_score_blocks(st, ln, kvl, page_size, mp,
+                                               t)[0]
     met = set()
     for w in range(n):
         cell = int(cells[w])
@@ -576,11 +586,7 @@ def test_latent_work_list_holds_the_ragged_lists_cells_on_random_batches(
         starts = np.concatenate([[0], np.cumsum(lens)[:-1]]).astype(np.int32)
         pt = rng.integers(0, 50, (s, mp)).astype(np.int32)
         grouped = pa.latent_work_list(pt, starts, lens, kv, ps, t)
-        pages2, cells2, count2 = (np.asarray(x) for x in pa.ragged_work_list(
-            pt, starts, lens, kv, ps, t))
-        n2 = int(count2[0])
-        _assert_the_groups_hold(grouped, (pages2[:n2], cells2[:n2]), pt,
-                                starts, lens, kv, ps, t)
+        _assert_the_groups_hold(grouped, pt, starts, lens, kv, ps, t)
         assert int(grouped[2][0]) == pa.latent_score_groups(
             starts, lens, kv, ps, mp, t) <= pa.latent_grid_cells(s, mp, t, ps)
 
