@@ -39,6 +39,7 @@ from .engine import (DEFAULT_PREFILL_CHUNK_TOKENS, GenerationConfig,
 from .fused import (ChunkedPrefillStep, FusedDecodeStep,
                     LoopedRaggedStep, RaggedStep, decode_batch_menu)
 from .gqa_window_moe_model import GQAWindowMoELM
+from .hybrid_ssm_moe_model import HybridSSMMoELM
 from .kv_cache import (DeviceKVPool, HeadRows, KVQuantMismatchError,
                        LatentRows, OutOfPagesError, PagedKVCache,
                        UnknownSequenceError, UnsupportedCachePathError,
@@ -61,7 +62,8 @@ __all__ = [
     "GenerationRequest", "SequenceState", "SamplingParams", "sample_token",
     "sample_tokens_batch", "sample_tokens_device", "SampleStream",
     "GenerationMetrics", "TinyCausalLM", "LatentMoELM", "LatentRows",
-    "GQAWindowMoELM", "HeadRows", "WindowPageGroup",
+    "GQAWindowMoELM", "HeadRows", "WindowPageGroup", "HybridSSMMoELM",
+    "SlotState",
     "UnsupportedModelPathError", "UnsupportedCachePathError",
     "FusedDecodeStep", "ChunkedPrefillStep", "RaggedStep",
     "LoopedRaggedStep", "decode_batch_menu",

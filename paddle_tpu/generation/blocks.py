@@ -1,10 +1,12 @@
 """What the served models with experts share: the norm, the gated MLP,
 the expert half of a layer and the on-device draw of seeded weights.
 
-`latent_moe_model.py` (latent attention) and `gqa_window_moe_model.py`
-(grouped-query heads, window and full layers) differ in their attention
-and in what a cached token is; their feed-forward halves, their norms
-and the way their weights come to be are one thing, stated here once.
+`latent_moe_model.py` (latent attention), `gqa_window_moe_model.py`
+(grouped-query heads, window and full layers) and
+`hybrid_ssm_moe_model.py` (state-space layers beside one attention
+layer in ten) differ in their mixers and in what a layer caches; their
+feed-forward halves, their norms and the way their weights come to be
+are one thing, stated here once.
 Matrix products accumulate in float32 and round to the model's dtype;
 norms and the router are float32.
 """
@@ -16,9 +18,12 @@ import jax.numpy as jnp
 from . import moe
 
 # what a step counts, in the order of its third output:
-# generation.moe_* summed over the expert layers (`moe.STATS`)
-STEP_COUNTERS = tuple(f"generation.moe_{name}" for name in (
-    "assignments_total", "assignments_max_expert", "experts_touched"))
+# generation.moe_* summed over the expert layers (`moe.STATS`: the
+# fourth by a model whose layers hold a share of their experts)
+HELD_STEP_COUNTERS = tuple(f"generation.moe_{name}" for name in (
+    "assignments_total", "assignments_max_expert", "experts_touched",
+    "assignments_elsewhere"))
+STEP_COUNTERS = HELD_STEP_COUNTERS[:3]
 
 
 def rms_norm(x, gain, eps):
@@ -37,21 +42,25 @@ def gated_mlp(x, w_gate_up, w_down):
     return jnp.dot(hidden, w_down, preferred_element_type=jnp.float32)
 
 
-def feed_forward(lp, x, valid, top_k, scaling):
+def feed_forward(lp, x, valid, top_k, scaling, scoring="sigmoid_bias",
+                 experts_held=None):
     """A layer's feed-forward half over normed rows x [T, d]: the dense
     gated MLP where the layer has no router, else the routed experts
-    (`moe.route`, `moe.expert_ffn`) beside the shared one, under the
-    scope a profile tells the experts by.  Returns (y [T, d] in x's
-    dtype, stats [3] int32 as `moe.STATS` or None)."""
+    (`moe.route` in the scoring form `scoring`, `moe.expert_ffn` over
+    the experts `experts_held` names, all of them for None) beside the
+    shared one, under the scope a profile tells the experts by.
+    Returns (y [T, d] in x's dtype, stats int32 as `moe.STATS` or
+    None)."""
     if "w_router" not in lp:
         return gated_mlp(x, lp["w_gate_up"], lp["w_down"]).astype(
             x.dtype), None
     with jax.named_scope("experts"):
         experts, weights = moe.route(
-            x, lp["w_router"], lp["router_bias"], top_k, scaling)
+            x, lp["w_router"], lp.get("router_bias"), top_k, scaling,
+            scoring)
         y, stats = moe.expert_ffn(
             x, experts, weights, valid, lp["experts_gate_up"],
-            lp["experts_down"])
+            lp["experts_down"], experts_held)
         y = y + gated_mlp(x, lp["shared_gate_up"], lp["shared_down"])
     return y.astype(x.dtype), stats
 
@@ -89,6 +98,10 @@ class DeviceDraw:
         """A norm's gain, not all ones: a norm whose gain is dropped
         has to show."""
         return 1.0 + 0.1 * jax.random.normal(self._key(), (n,), jnp.float32)
+
+    def uniform(self, n, lo, hi):
+        """[n] float32, uniform in [lo, hi)."""
+        return jax.random.uniform(self._key(), (n,), jnp.float32, lo, hi)
 
     def expert_layer(self, d, width, n_experts, n_shared):
         """The router, its correction biases (about a tenth of the

@@ -514,7 +514,8 @@ class UnsupportedModelPathError(ValueError):
     the engine is built, never mis-served."""
 
 
-def _refuse_paths_off_the_ragged_step(model, config, windowed):
+def _refuse_paths_off_the_ragged_step(model, config, windowed,
+                                      stateful=False):
     """A model with `kv_rows` (one row a token and layer: a latent
     cache, grouped-query heads side by side) is served by the ragged
     step over device pools and by nothing else; every option that
@@ -522,7 +523,13 @@ def _refuse_paths_off_the_ragged_step(model, config, windowed):
     some of its layers keep only a window of tokens
     (`kv_layer_kinds`), which also rules the prefix cache out: a hit
     would need, for those layers, the last window of the matched
-    prefix, and its first owner has given those pages back."""
+    prefix, and its first owner has given those pages back.
+    `stateful`: some of its layers keep a recurrent state a slot and no
+    pages.  That rules the prefix cache out too (a hit would need the
+    state at the matched prefix's end, which nobody kept), and gives
+    speculation and `loop_steps` a second reason (`truncate()` cannot
+    rewind a recurrence); pages of such a model are exported and
+    imported by nothing (`DeviceKVPool._refuse_latent`)."""
     asked = {
         "kv_backend='host'": config.kv_backend == "host",
         f"decode={config.decode!r}": config.decode is not None,
@@ -535,7 +542,8 @@ def _refuse_paths_off_the_ragged_step(model, config, windowed):
             config.pool_layout not in (None, "token"),
         "mesh": config.mesh is not None,
         "prefill_chunk_tokens=0": config.prefill_chunk_tokens == 0,
-        "prefix_cache=True": windowed and config.prefix_cache is True,
+        "prefix_cache=True": ((windowed or stateful)
+                              and config.prefix_cache is True),
     }
     bad = [name for name, hit in asked.items() if hit]
     if bad:
@@ -545,6 +553,10 @@ def _refuse_paths_off_the_ragged_step(model, config, windowed):
             f"model's own dtype, with chunked prefill"
             + (" and, its window layers giving pages back, without the "
                "prefix cache" if windowed else "")
+            + (" and, its state layers keeping a recurrent state a slot "
+               "that no page holds and no truncate() rewinds, without "
+               "the prefix cache, speculation, loop_steps or page "
+               "export/import" if stateful else "")
             + f"; not carried for it: {', '.join(bad)}")
 
 
@@ -570,8 +582,12 @@ class GenerationEngine:
                                 if hasattr(model, "kv_layer_kinds")
                                 else ((), 0))
         windowed = "window" in kinds
+        # ... and which keep no pages at all but a recurrent state a
+        # decode slot, which the pool holds beside the pages
+        stateful = "state" in kinds
         if kv_rows is not None:
-            _refuse_paths_off_the_ragged_step(model, self.config, windowed)
+            _refuse_paths_off_the_ragged_step(model, self.config, windowed,
+                                              stateful)
         # tensor-parallel mesh: sharded decode is device-pool + fused
         # by construction, so the mesh flips both auto policies
         mesh = self.config.mesh
@@ -632,7 +648,10 @@ class GenerationEngine:
                 num_pages=self.config.num_pages,
                 page_size=self.config.page_size,
                 dtype=self.config.kv_dtype, pool_layout=pool_layout,
-                mesh=mesh, tp_axis=tp_axis, rows=kv_rows, window=window)
+                mesh=mesh, tp_axis=tp_axis, rows=kv_rows, window=window,
+                state=((kinds, model.kv_slot_state(),
+                        self.config.max_decode_slots)
+                       if stateful else None))
         else:
             if pool_layout == "kernel":
                 raise ValueError(
@@ -807,7 +826,7 @@ class GenerationEngine:
         # identity is itself oracle-tested, tests/test_prefix_cache.py).
         prefix_ok = bool(chunk) or chunk_eager_ok
         prefix = self.config.prefix_cache
-        if windowed:
+        if windowed or stateful:
             prefix = False   # True was refused above; auto resolves off
         elif prefix is None:
             # auto requires chunked prefill to actually be ON, not just
@@ -937,8 +956,15 @@ class GenerationEngine:
         # carries the allreduces (a requested-but-inert flag reads 0)
         self.metrics.set_kv_quant_dtype(str(self.cache.dtype))
         if kv_rows is not None:
-            self.metrics.set_kv_token_bytes(
-                kv_rows.token_bytes(self.cache.num_layers))
+            # the layers that keep pages: a state layer keeps none
+            state_layers = self.cache.state_layers
+            self.metrics.set_kv_token_bytes(kv_rows.token_bytes(
+                self.cache.num_layers - state_layers))
+            if stateful:
+                self.metrics.set_kv_state_bytes_a_slot(
+                    state_layers * self.cache.slot_state.bytes_a_slot)
+        if hasattr(model, "build_gauges"):
+            self.metrics.set_model_gauges(model.build_gauges())
         self.metrics.set_collective_quantized(self._quant_collectives)
         # the spec_mode build stamp (kernel_path pattern): engine
         # construction refuses unsupported spec combos, so the stamp
@@ -1901,7 +1927,12 @@ class GenerationEngine:
             w_pt = self.cache.window_group.gather_tables(desc_ids,
                                                          pt.shape[1])
             packed += ((w_pt[desc_of_row, pos_all // ps], w_pt),)
-        return self._ragged.pad(*packed), spec_rows
+        extra = {}
+        if self.cache.slot_state is not None:
+            # where each descriptor's recurrent state lives: its slot
+            extra["state_slots"] = ([s.slot for s in decoding]
+                                    + [state.slot for state, _, _ in pack])
+        return self._ragged.pad(*packed, **extra), spec_rows
 
     def _apply_ragged_spec(self, samplers, spec_rows, b, fetched, greedy):
         """The speculative step's sampling half, over its ONE host

@@ -53,7 +53,7 @@ from .metrics import DecodeCacheMetrics
 
 
 def _wrap_donating(num_layers, tree, jax_mod, call, n_fixed=4, n_out=1,
-                   n_groups=2):
+                   n_groups=2, group_sizes=None):
     """Flatten a pool-donating step fn to the positional-array calling
     convention CompiledModelCache keys and compiles on:
     ``(*fixed, *state_groups, *param_leaves)`` where the state is
@@ -62,14 +62,18 @@ def _wrap_donating(num_layers, tree, jax_mod, call, n_fixed=4, n_out=1,
     DeviceKVPool.take_pool_state layout).  `call(params, fixed,
     *groups)` adapts to the inner fn's own argument order and returns
     ``(out, *groups_out)`` — `out` a single array when n_out == 1,
-    else a tuple of n_out arrays (the ragged step's ids + logits)."""
+    else a tuple of n_out arrays (the ragged step's ids + logits).
+    `group_sizes`: the groups' lengths where they are not all L
+    (`DeviceKVPool.state_group_sizes`: a cache with state layers)."""
     unflatten = jax_mod.tree_util.tree_unflatten
+    sizes = tuple(group_sizes or (num_layers,) * n_groups)
+    ends = np.cumsum(sizes)
 
     def step(*flat):
         fixed, leaves = flat[:n_fixed], flat[n_fixed:]
-        groups = [list(leaves[g * num_layers:(g + 1) * num_layers])
-                  for g in range(n_groups)]
-        params = unflatten(tree, leaves[n_groups * num_layers:])
+        groups = [list(leaves[end - size:end])
+                  for size, end in zip(sizes, ends)]
+        params = unflatten(tree, leaves[ends[-1]:])
         out, *groups_out = call(params, fixed, *groups)
         outs = (out,) if n_out == 1 else tuple(out)
         flat_state = [a for grp in groups_out for a in grp]
@@ -81,8 +85,9 @@ def _wrap_donating(num_layers, tree, jax_mod, call, n_fixed=4, n_out=1,
 # the pool state sits at wrapper args n_fixed .. n_fixed+n_groups*L in
 # that convention: donated so XLA updates the KV storage (and, for int8
 # pools, the scale arrays) in place instead of copying every call
-def _pool_donate_plan(num_layers, n_fixed=4, n_groups=2):
-    return tuple(range(n_fixed, n_fixed + n_groups * num_layers))
+def _pool_donate_plan(num_layers, n_fixed=4, n_groups=2, group_sizes=None):
+    n_state = sum(group_sizes or (num_layers,) * n_groups)
+    return tuple(range(n_fixed, n_fixed + n_state))
 
 
 def _shard_params(model, mesh, tp_axis, jax_mod):
@@ -150,7 +155,7 @@ def _dispatch_donating(cache, exec_cache, args, num_layers, n_out=1):
     pool-donating step (fused decode, chunked prefill, ragged).
     Returns the non-pool output (a tuple when n_out > 1),
     unmaterialized (no host sync)."""
-    n_state = getattr(cache, "n_state_groups", 2) * num_layers
+    n_state = sum(getattr(cache, "state_group_sizes", (num_layers,) * 2))
     exe = exec_cache.get(args)
     try:
         outs = exe(*args)
@@ -178,9 +183,10 @@ def _state_structs(jax_mod, cache, mesh, num_layers, quant):
     sds = jax_mod.ShapeDtypeStruct
     if cache.rows is not None:
         # a row cache: one pool a layer (a window layer's holds the
-        # window group's pages), never sharded
-        return [sds(tuple(pool.shape), pool.dtype)
-                for pool in map(cache.latent_pool, range(num_layers))]
+        # window group's pages; a state layer's array is its state a
+        # slot, and the tails follow), never sharded
+        return [sds(tuple(a.shape), a.dtype)
+                for a in cache.take_pool_state()]
     pool = cache.layer_pools(0)[0]
     if mesh is not None:
         pool_sds = sds(tuple(pool.shape), pool.dtype,
@@ -534,7 +540,6 @@ class RaggedStep:
         self._use_kernel = bool(use_kernel)
         self._quant = bool(getattr(cache, "quantized", False))
         self._quant_collectives = bool(quant_collectives) and self._tp > 1
-        self._n_groups = cache.n_state_groups
         self._param_leaves, self._param_tree = _shard_params(
             model, mesh, tp_axis, jax)
         pages_menu = ShapeBucketer.geometric_menu(cache.num_pages, start=1)
@@ -568,19 +573,23 @@ class RaggedStep:
         # them (scale groups trail the pools for quantized caches).  A
         # cache with a window group adds that group's (pages,
         # page_tables): its rows are written, and its layers read,
-        # through a table of their own
+        # through a table of their own; a cache with state layers adds
+        # each descriptor's state slot, and its tails ride the donation
+        # chain as a group of their own behind the pools
         self._window_group = cache.window_group
-        self._n_fixed = 8 if self._window_group is None else 10
+        self._state_slots = (cache.state_slots
+                             if cache.slot_state is not None else None)
+        self._n_fixed = (8 + 2 * (self._window_group is not None)
+                         + (self._state_slots is not None))
+        sizes = cache.state_group_sizes
         wrapped = _wrap_donating(
             self._num_layers, self._param_tree, jax,
             lambda params, f, *gs: fn(params, *f, *gs),
-            n_fixed=self._n_fixed, n_out=self._n_out,
-            n_groups=self._n_groups)
+            n_fixed=self._n_fixed, n_out=self._n_out, group_sizes=sizes)
         self._exec = CompiledModelCache(
             wrapped, metrics=DecodeCacheMetrics(metrics), aot=True,
-            donate_argnums=_pool_donate_plan(self._num_layers,
-                                             self._n_fixed,
-                                             n_groups=self._n_groups))
+            donate_argnums=_pool_donate_plan(
+                self._num_layers, self._n_fixed, group_sizes=sizes))
         self.last_dispatches = 0
         self.last_collective_bytes = 0
         self.last_rows_useful = 0
@@ -615,6 +624,8 @@ class RaggedStep:
                  sds((s,), i32), sds((s,), i32), sds((s,), i32)]
         if self._window_group is not None:
             fixed += [sds((t,), i32), sds((s, bucket_p), i32)]
+        if self._state_slots is not None:
+            fixed.append(sds((s,), i32))
         return fixed
 
     def prewarm(self, pages_cols):
@@ -635,7 +646,7 @@ class RaggedStep:
         return self._exec.compile_count > before
 
     def pad(self, tokens, positions, pages, rows, page_tables, starts,
-            lens, kv_lens, window=None):
+            lens, kv_lens, window=None, state_slots=None):
         """The executable's eight fixed arguments from the PACKED host
         arrays (the engine built them at exact sizes): the token axis
         padded to `max_tokens` with inert slots (sentinel page,
@@ -643,7 +654,10 @@ class RaggedStep:
         descriptors, and the page-table axis to its pages bucket.  Host
         work only — what `dispatch` takes.  `window`: the window
         group's ``(pages, page_tables)`` of the same rows and
-        descriptors, padded alike into a ninth and tenth argument."""
+        descriptors, padded alike into a ninth and tenth argument.
+        `state_slots`: each descriptor's decode slot, for the state
+        layers; descriptors past the real ones point at the row behind
+        the last slot, which belongs to no sequence."""
         t_real = len(tokens)
         s_real = len(starts)
         if t_real > self.max_tokens:
@@ -683,6 +697,10 @@ class RaggedStep:
             if w_tables.size:
                 wpt[:s_real, :w_tables.shape[1]] = w_tables
             extra = [wpg, wpt]
+        if state_slots is not None:
+            sl = np.full((s,), self._state_slots, np.int32)
+            sl[:s_real] = state_slots
+            extra.append(sl)
         self.last_pages_bucket = bucket_p
         self.last_rows_useful = t_real
         self.last_rows_dispatched = t

@@ -169,6 +169,30 @@ class HeadRows(TokenRows):
         self.head_dim = int(head_dim)
 
 
+class SlotState:
+    """What a `state` layer keeps, told to `DeviceKVPool` by a model
+    whose `kv_layer_kinds()` names such layers: nothing a TOKEN, and for
+    each decode SLOT a pair of arrays whatever the context's length: the
+    `tail` (the last rows of a causal convolution's input, `tail_shape`
+    in `tail_dtype`) and the recurrent `state` (`state_shape` in
+    `state_dtype`).  The pool keeps both ``[slots + 1, ...]`` a state
+    layer (the last row is where descriptors that belong to no slot
+    point), zeroed when made; the STEP starts a slot from zero when a
+    sequence's first token arrives, so the host never resets one."""
+
+    def __init__(self, tail_shape, tail_dtype, state_shape, state_dtype):
+        self.tail_shape = tuple(int(n) for n in tail_shape)
+        self.tail_dtype = np.dtype(tail_dtype)
+        self.state_shape = tuple(int(n) for n in state_shape)
+        self.state_dtype = np.dtype(state_dtype)
+
+    @property
+    def bytes_a_slot(self):
+        """Bytes one slot costs in one state layer."""
+        return (int(np.prod(self.tail_shape)) * self.tail_dtype.itemsize
+                + int(np.prod(self.state_shape)) * self.state_dtype.itemsize)
+
+
 class WindowPageGroup:
     """The page table and the free list of the layers that keep only
     the last `window` tokens of a sequence (a query at position p sees
@@ -384,6 +408,9 @@ class PagedKVCache:
     # (DeviceKVPool(layer_kinds=...)); every other cache has ONE group,
     # this class's own tables and free list
     window_group = None
+    # a SlotState where some layers keep a recurrent state a decode
+    # slot and no pages (DeviceKVPool(state=...))
+    slot_state = None
 
     def __init__(self, num_layers, num_heads, head_dim, num_pages=256,
                  page_size=16, dtype=np.float32):
@@ -1904,6 +1931,15 @@ def _jitted_latent_page_copy():
     return _PAGE_COPY_JIT["latent"]
 
 
+def _checked_kinds(kinds, num_layers):
+    if len(kinds) != int(num_layers) or set(kinds) - {
+            "window", "full", "state"}:
+        raise ValueError(
+            f"layer kinds {kinds!r}: one of 'window' / 'full' / 'state' "
+            f"for each of {num_layers} layers")
+    return tuple(kinds)
+
+
 class DeviceKVPool(PagedKVCache):
     """PagedKVCache whose pools live on the device (HBM on TPU).
 
@@ -1952,7 +1988,8 @@ class DeviceKVPool(PagedKVCache):
 
     def __init__(self, num_layers, num_heads, head_dim, num_pages=256,
                  page_size=16, dtype=np.float32, pool_layout="token",
-                 mesh=None, tp_axis=None, rows=None, window=None):
+                 mesh=None, tp_axis=None, rows=None, window=None,
+                 state=None):
         if pool_layout not in ("token", "kernel"):
             raise ValueError(
                 f"pool_layout must be 'token' or 'kernel', got "
@@ -1965,6 +2002,8 @@ class DeviceKVPool(PagedKVCache):
         # most tokens one reservation appends) where some layers keep
         # only a window: those layers' pools hold the window group's
         # pages, the others' this cache's own `num_pages`
+        # state: (layer kinds, a SlotState, decode slots) where some
+        # layers keep a recurrent state a slot and no pages at all
         self.layer_kinds = None
         if rows is not None:
             if mesh is not None or pool_layout != "token":
@@ -1979,14 +2018,16 @@ class DeviceKVPool(PagedKVCache):
                 raise UnsupportedCachePathError(
                     "window layers are carried by row pools alone")
             kinds, tokens, pages, reserve_tokens = window
-            if len(kinds) != int(num_layers) or set(kinds) - {
-                    "window", "full"}:
-                raise ValueError(
-                    f"layer kinds {kinds!r}: one of 'window' / 'full' "
-                    f"for each of {num_layers} layers")
-            self.layer_kinds = tuple(kinds)
+            self.layer_kinds = _checked_kinds(kinds, num_layers)
             self.window_group = WindowPageGroup(
                 pages, page_size, tokens, reserve_tokens)
+        if state is not None:
+            if rows is None:
+                raise UnsupportedCachePathError(
+                    "state layers are carried beside row pools alone")
+            kinds, self.slot_state, slots = state
+            self.layer_kinds = _checked_kinds(kinds, num_layers)
+            self.state_slots = int(slots)
         self.mesh = mesh
         self.tp_axis = None
         self.tp_degree = 1
@@ -2044,9 +2085,14 @@ class DeviceKVPool(PagedKVCache):
             return z
 
         if self.rows is not None:
-            self._k = [jnp.zeros(self._row_pool_shape(layer), self.dtype)
+            # a layer's page pool, or a state layer's state a slot ...
+            self._k = [jnp.zeros(*self._row_pool_shape(layer))
                        for layer in range(self.num_layers)]
-            self._v = []
+            # ... and the state layers' tails beside them
+            self._v = [jnp.zeros((self.state_slots + 1,)
+                                 + self.slot_state.tail_shape,
+                                 self.slot_state.tail_dtype)
+                       for _ in range(self.state_layers)]
             return
         self._k = [zeros() for _ in range(self.num_layers)]
         self._v = [zeros() for _ in range(self.num_layers)]
@@ -2315,6 +2361,11 @@ class DeviceKVPool(PagedKVCache):
         boundary (page-to-page inside the resident pools).  Quantized
         pools copy the scale rows with the bytes."""
         jnp = self._jnp
+        if self.state_layers:
+            raise UnsupportedCachePathError(
+                "a shared page was asked of a cache with state layers: "
+                "a prefix's pages say nothing of the recurrent state at "
+                "its end, so no page of it is ever shared")
         if self.rows is not None:
             self._k = _jitted_latent_page_copy()(
                 self._k, jnp.int32(src), jnp.int32(dst))
@@ -2349,20 +2400,32 @@ class DeviceKVPool(PagedKVCache):
     # writes per-head K and V (the eager and fused-decode paths, the
     # disaggregated fleet's page payloads) is refused by name.
     def _row_pool_shape(self, layer):
-        """[pages, page_size, lanes] of one layer's row pool: the
-        window group's pages for a window layer, else this cache's."""
-        pages = (self.window_group.num_pages
-                 if self.layer_kinds is not None
-                 and self.layer_kinds[layer] == "window"
+        """(shape, dtype) of one layer's array: [pages, page_size,
+        lanes] of a row pool (the window group's pages for a window
+        layer, else this cache's), [slots + 1, ...] of a state layer's
+        recurrent state."""
+        kind = self.layer_kinds[layer] if self.layer_kinds else "full"
+        if kind == "state":
+            return ((self.state_slots + 1,) + self.slot_state.state_shape,
+                    self.slot_state.state_dtype)
+        pages = (self.window_group.num_pages if kind == "window"
                  else self.num_pages)
-        return (pages, self.page_size, self.rows.lanes)
+        return (pages, self.page_size, self.rows.lanes), self.dtype
+
+    @property
+    def state_layers(self):
+        """How many layers keep a state a slot and no pages."""
+        return (self.layer_kinds or ()).count("state")
 
     def _refuse_latent(self, what):
         if self.rows is not None:
             raise UnsupportedCachePathError(
                 f"{what} was asked of a row pool, which holds one "
                 f"[{self.rows.lanes}]-lane row a token and no K or V "
-                f"pool")
+                f"pool"
+                + (f"; its {self.state_layers} state layers keep a "
+                   f"recurrent state a slot that no page holds"
+                   if self.state_layers else ""))
 
     def latent_pool(self, layer):
         """One layer's live latent pool [P, page_size, lanes]."""
@@ -2417,6 +2480,14 @@ class DeviceKVPool(PagedKVCache):
         fused wrappers split on."""
         return self._groups
 
+    @property
+    def state_group_sizes(self):
+        """The lengths of the array groups `take_pool_state` lays end to
+        end: `n_state_groups` of L, and behind a row cache's L arrays
+        the state layers' tails, one a state layer."""
+        sizes = (self.num_layers,) * self._groups
+        return sizes + ((self.state_layers,) if self.state_layers else ())
+
     def take_pool_state(self):
         """The WHOLE donated device state as one flat list —
         ``[*k_pools, *v_pools]`` plus ``[*k_scales, *v_scales]`` when
@@ -2433,15 +2504,14 @@ class DeviceKVPool(PagedKVCache):
     def put_pool_state(self, state):
         """Install the flat state list a donating dispatch returned
         (the donation chain's other half)."""
-        want = self.n_state_groups * self.num_layers
+        want = sum(self.state_group_sizes)
         if len(state) != want:
             raise ValueError(
-                f"expected {want} state arrays "
-                f"({self.n_state_groups} groups x {self.num_layers} "
-                f"layers), got {len(state)}")
+                f"expected {want} state arrays (groups of "
+                f"{self.state_group_sizes}), got {len(state)}")
         ll = self.num_layers
         self._k = list(state[:ll])
-        self._v = list(state[ll:2 * ll])
+        self._v = list(state[ll:ll + len(self._v)])
         if self.quantized:
             self._ks = list(state[2 * ll:3 * ll])
             self._vs = list(state[3 * ll:4 * ll])

@@ -334,6 +334,7 @@ MESH_DEVICES = PREFIX + "mesh_devices"
 COLLECTIVE_BYTES_PER_STEP = PREFIX + "collective_bytes_per_step"
 KV_QUANT_DTYPE = PREFIX + "kv_quant_dtype"
 KV_TOKEN_BYTES = PREFIX + "kv_token_bytes"
+KV_STATE_BYTES_A_SLOT = PREFIX + "kv_state_bytes_a_slot"
 KV_LAYER_GROUPS = PREFIX + "kv_layer_groups"
 KV_WINDOW_TOKENS = PREFIX + "kv_window_tokens"
 KV_WINDOW_PAGES_RESERVED = PREFIX + "kv_window_pages_reserved"
@@ -546,6 +547,19 @@ class GenerationMetrics:
         """Gauge: bytes one cached token costs over all layers, stamped
         at engine build by a cache that knows it (a latent pool)."""
         self._stat(KV_TOKEN_BYTES).set(int(n))
+
+    def set_kv_state_bytes_a_slot(self, n):
+        """Gauge: bytes one decode slot costs over all state layers
+        (their tails and recurrent states), stamped at engine build by
+        a cache that has such layers."""
+        self._stat(KV_STATE_BYTES_A_SLOT).set(int(n))
+
+    def set_model_gauges(self, gauges):
+        """Gauges a model states of itself (`build_gauges()`, e.g. the
+        experts a layer holds of its router's width), stamped at engine
+        build under ``generation.<name>``."""
+        for name, value in gauges.items():
+            self._stat(PREFIX + name).set(int(value))
 
     def set_latent_pages_per_cell(self, n):
         """Gauge: pages a grid step of the latent kernel holds, stamped

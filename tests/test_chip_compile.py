@@ -391,6 +391,70 @@ def test_the_glm_ragged_step_lowers_to_the_text_it_had(v5e, monkeypatch):
     assert _step_digest(lowered, 2) == GLM_STEP_DIGEST
 
 
+def _granite_cell():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "granite-4.0-h-small-d10.json")) as f:
+        builder = json.load(f)["builder"]
+    return builder["model_args"], builder["engine"]
+
+
+def test_hybrid_step_compiles_at_the_published_widths(v5e, monkeypatch):
+    """The whole ragged step of granite-4.0-h-small-d10 at the cell's
+    largest pages bucket, weights as shapes: it fits the chip beside its
+    13.2 GB of weights, states and pages; NO operation copies a state
+    array (272 MB a layer: XLA:TPU did, twice a layer, while the scan's
+    state products had two free axes a side), each state array is
+    yielded whole by the one-token update's fusion and by the scan's
+    in-place slot write alone, and the attention layer's pool by its row
+    write; one grouped-query call and 10 layers x 3 grouped products."""
+    from paddle_tpu.generation import hybrid_ssm_moe_model as hm
+
+    args, engine = _granite_cell()
+    _shapes_only(monkeypatch, hm.HybridSSMMoELM)
+    model = hm.HybridSSMMoELM(**args, seed=1)
+    slots = engine["max_decode_slots"]
+    t, s = engine["prefill_chunk_tokens"] + slots, slots + 1
+    rows, state = model.kv_rows(), model.kv_slot_state()
+    assert (rows.lanes, rows.token_bytes(1)) == (2048, 4096)
+    assert state.bytes_a_slot == 4194304 + 50688
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    model.params)
+    pools = [sds((slots + 1,) + state.state_shape, state.state_dtype)
+             if kind == "state" else
+             sds((engine["num_pages"], engine["page_size"], rows.lanes),
+                 rows.dtype) for kind in model.layer_kinds]
+    tails = [sds((slots + 1,) + state.tail_shape, state.tail_dtype)
+             for kind in model.layer_kinds if kind == "state"]
+    fixed = ([sds((t,), "int32")] * 4 + [sds((s, 64), "int32")]
+             + [sds((s,), "int32")] * 4)
+    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                              use_kernel=True)
+    compiled = jax.jit(fn, donate_argnums=(10, 11)).lower(
+        params, *fixed, pools, tails).compile()
+    memory = compiled.memory_analysis()
+    assert 13.0e9 < memory.argument_size_in_bytes < 13.5e9
+    assert memory.temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 + 10 * 3
+    whole = [m.group(1) for m in re.finditer(
+        r"^\s*%?[\w.\-]+ = f32\[65,128,64,128\]\S* ([\w\-]+)\(", text,
+        re.M)]
+    assert "copy" not in whole and "transpose" not in whole, whole
+    assert whole.count("fusion") == 9       # the scan's slot write a layer
+    pool = [m.group(1) for m in re.finditer(
+        r"^\s*%?[\w.\-]+ = bf16\[3072,64,2048\]\S* (?!parameter)([\w\-]+)\(",
+        text, re.M)]
+    assert pool == ["fusion"], pool
+
+
 def test_the_pool_check_tells_the_token_layout(v5e, monkeypatch):
     """The same check on what the engine ran until PR 29: each layer's
     token-layout pools are transposed whole for the kernel (a fusion and

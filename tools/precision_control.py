@@ -8,18 +8,23 @@ precision from a worse one.
     chiprun -- python tools/precision_control.py \\
         --workload glm-4.7-flash-d7.docqa-closed --seed 2147484301 [--rehearse]
 
-(or `--workload trinity-mini-d8.mixed-closed`: any cell whose runner has
-`_load`, `verdict` and the check's prompt lengths)
+(or `--workload trinity-mini-d8.mixed-closed`, or
+`--workload granite-4.0-h-small-d10.chat-closed`: any cell whose runner
+has `_load`, `verdict` and the check's prompt lengths)
 
-The control system is the cell's plain reference with every matrix
-rounded to float8 (e4m3; the configuration states bfloat16): at each of
-the last `check.new_tokens` positions of the check's prompts (seeded as
-the runner seeds them, the document question behind seeded tokens of
-the document's length) it "serves" its argmax.  The float32 reference
-reads those tokens as it reads the engine's, `reference_readings()` by
-another road, and the runner's `verdict()` judges them by the
-configuration's limits.  The last line is a JSON object; exit code 0
-when the control comes out not correct, 1 when it passes.
+A control system is the cell's plain reference computing lower: with
+every matrix rounded to float8 (e4m3; the configurations state
+bfloat16), and, where the runner's `CONTROLS` names it, with the
+recurrent state of its state-space layers rounded to bfloat16 after
+every token (the configuration states float32).  At each of the last
+`check.new_tokens` positions of the check's prompts (seeded as the
+runner seeds them, the document question behind seeded tokens of the
+document's length) a control "serves" its argmax.  The float32
+reference reads those tokens as it reads the engine's,
+`reference_readings()` by another road, and the runner's `verdict()`
+judges them by the configuration's limits.  The last line is a JSON
+object with a verdict a control; exit code 0 when every control comes
+out not correct, 1 when one passes.
 """
 import argparse
 import json
@@ -89,23 +94,56 @@ def main(argv=None):
         full.append((logits, np.min([np.asarray(m)[-n_new:]
                                      for m in margins], axis=0)))
         print(f"float32 reference over {len(text)} tokens", flush=True)
-    _round_matrices_in_place(params, jnp.float8_e4m3fn)
-    requests = []
-    for text, (logits, tie) in zip(texts, full):
-        served = np.asarray(reference.next_token_logits(
-            params, text, builder["model_args"], n_new)).argmax(-1)
-        short = logits.max(-1) - logits[np.arange(n_new), served]
-        requests.append({"what": f"float8 reference, {len(text)} tokens",
-                         "short": short.astype(float).tolist(),
-                         "router_margin": tie.astype(float).tolist()})
-        print(f"  {requests[-1]}", flush=True)
-    ok, worst, lines = runner.verdict(check, requests, True)
-    for line in lines:
-        print("  precision control: " + line, flush=True)
+    # the float32 state after the longest text, before any weight is
+    # rounded
+    longest = max(texts, key=len)
+    state_want = reference.first_layer_state(
+        params, longest, builder["model_args"]) if hasattr(
+            runner, "state_error") else None
+    verdicts = {}
+    # the state's control first: float8 rounds the weights where they lie
+    for control in sorted(getattr(runner, "CONTROLS", ("float8",)),
+                          reverse=True):
+        shape = builder["model_args"]
+        if control == "state_bf16":
+            shape = dict(shape, state_dtype="bfloat16")
+        else:
+            _round_matrices_in_place(params, jnp.float8_e4m3fn)
+        requests = []
+        for text, (logits, tie) in zip(texts, full):
+            served = np.asarray(reference.next_token_logits(
+                params, text, shape, n_new)).argmax(-1)
+            short = logits.max(-1) - logits[np.arange(n_new), served]
+            requests.append({"what": f"{control} reference, {len(text)} "
+                                     f"tokens",
+                             "short": short.astype(float).tolist(),
+                             "router_margin": tie.astype(float).tolist()})
+            print(f"  {requests[-1]}", flush=True)
+        # what the runner holds beside the tokens: True for a cache a
+        # reference has not; a state it does have is held to the
+        # runner's own limit (`state_error`)
+        beside, error = True, None
+        if state_want is not None:
+            error = runner.state_error(
+                state_want,
+                reference.first_layer_state(params, longest, shape))
+            beside = error <= float(check["state_relative_error"])
+            print(f"  precision control {control}: first-layer state "
+                  f"{error} from the float32 reference's, relative (at "
+                  f"most {check['state_relative_error']})", flush=True)
+        ok, worst, lines = runner.verdict(check, requests, beside)
+        for line in lines:
+            print(f"  precision control {control}: " + line, flush=True)
+        verdicts[control] = {"correct": ok, "worst_shortfall": worst,
+                             "requests": requests}
+        if error is not None:
+            verdicts[control]["state_relative_error"] = error
+    passed = any(v["correct"] for v in verdicts.values())
+    # the float8 control's verdict at the top, as every cell has it; a
+    # runner's other controls beside it under their names
     print(json.dumps({"control": "float8_e4m3fn", "seed": args.seed,
-                      "correct": ok, "worst_shortfall": worst,
-                      "requests": requests}))
-    return 1 if ok else 0
+                      **verdicts.pop("float8"), **verdicts}))
+    return 1 if passed else 0
 
 
 if __name__ == "__main__":
