@@ -560,6 +560,17 @@ def _refuse_paths_off_the_ragged_step(model, config, windowed,
             + f"; not carried for it: {', '.join(bad)}")
 
 
+class _StepInFlight:
+    """A ragged step the device has been given and the host has not yet
+    read: its unmaterialized outputs, who samples from which descriptor
+    (``(state, descriptor, state.preemptions as dispatched)``, so that a
+    row is never applied to another incarnation), and the descriptors
+    it advanced."""
+
+    __slots__ = ("ids", "logits", "counters", "samplers", "descriptor_of",
+                 "greedy", "decode_rows", "spec_rows", "advanced")
+
+
 class GenerationEngine:
     """Paged-KV continuous-batching decode engine over a protocol model."""
 
@@ -882,10 +893,6 @@ class GenerationEngine:
                 mesh=mesh, tp_axis=tp_axis,
                 quant_collectives=self._quant_collectives,
                 spec_tokens=self.spec_tokens)
-            if self._ragged.step_counters:
-                # the model counts inside its step: its accounting takes
-                # the place of the plain one on this engine alone
-                self._account_step = self._account_step_counting
         # the host-free decode loop: N fused ragged iterations per
         # dispatch at decode-only boundaries, ONE host fetch per N
         # steps — built ALONGSIDE the single-step RaggedStep, which
@@ -994,6 +1001,9 @@ class GenerationEngine:
         self._handoff = False
         self.on_handoff = None
         self._handoff_out = []
+        # the ragged step keeps ONE step in flight: enqueued, its ids
+        # not read (`_step_ragged`); None at depth 0
+        self._inflight = None
         self._closed = False
         self._stop = threading.Event()
         self._thread = None
@@ -1096,6 +1106,7 @@ class GenerationEngine:
         Expired requests are reaped with the typed deadline error
         instead of being returned."""
         with self._lock:
+            self._retire_inflight("api")
             out = self.scheduler.take_pending()
             if include_active:
                 for state in self.scheduler.active():
@@ -1128,6 +1139,7 @@ class GenerationEngine:
         handle under "future").  Expired requests are reaped typed on
         the way."""
         with self._lock:
+            self._retire_inflight("api")
             cold = self.scheduler.take_pending()
             # snaps already parked for P/D handoff but not yet
             # collected ride the live list unchanged — they hold page
@@ -1194,6 +1206,7 @@ class GenerationEngine:
         if handle is None:
             handle = snap.get("future")
         with self._lock:
+            self._retire_inflight("api")
             if self._closed or self.scheduler.free_slots() == 0:
                 return False
             try:
@@ -1355,6 +1368,7 @@ class GenerationEngine:
         import_prefix_pages — or None when nothing is cached (or the
         prefix cache is off)."""
         with self._lock:
+            self._retire_inflight("api")
             if not self.prefix_cache_enabled:
                 return None
             pages, matched = self.cache.match_prefix_full(tokens)
@@ -1379,6 +1393,7 @@ class GenerationEngine:
         pressure, or layout-incompatible payload); adoption is an
         optimization and must never fail a request."""
         with self._lock:
+            self._retire_inflight("api")
             if not self.prefix_cache_enabled or payload is None:
                 return 0
             try:
@@ -1401,8 +1416,11 @@ class GenerationEngine:
         take_handoffs() and places each snapshot on a decode-class
         sibling via import_sequence; `on_handoff` (called after each
         step that parked something, OUTSIDE the step lock) is the
-        wakeup."""
-        self._handoff = True
+        wakeup.  A step in flight is read first: from here on none is
+        left in flight (`_drain_reason`)."""
+        with self._lock:
+            self._retire_inflight("handoff")
+            self._handoff = True
 
     def _sweep_handoffs_locked(self):
         """Park every prefill-complete resident (under the step lock,
@@ -1443,6 +1461,7 @@ class GenerationEngine:
         paying for decode it stopped reading.  False when the handle
         owns nothing here (already finished, or migrated away)."""
         with self._lock:
+            self._retire_inflight("api")
             for state in self.scheduler.active():
                 if state.handle is handle:
                     self.scheduler.retire(state)
@@ -1653,12 +1672,43 @@ class GenerationEngine:
         sequences — they simply drop out of the decode batch — or even
         a YOUNGER pack member, which then drops out of the pack), then
         the decode capacity check (which may preempt chunkers — their
-        freed rows drop out of the pack)."""
+        freed rows drop out of the pack).
+
+        A pipeline of depth one: the step planned here is enqueued
+        BEHIND the step in flight, its decode rows taking their token
+        from that step's ids on the device, and only then are the
+        tokens of the step in flight read, emitted and accounted — so
+        the host's work on a step runs while the device does the one
+        before.  The depth is decided a step at a time from what the
+        engine sees (`_drain_reason`); at depth 0 a step is dispatched
+        and retired in the same call, the sequence this method always
+        had.  A call that finds nothing to plan retires the step in
+        flight and returns what that advanced."""
+        try:
+            return self._step_ragged_pipelined()
+        except BaseException:
+            # a failure with steps enqueued on donated pools: whatever
+            # is in flight is lost with them, and both steps' sequences
+            # fail as a unit (the worker's contract; `_dispatch_donating`
+            # or `_retire` left the cache on fresh storage)
+            self._inflight = None
+            self._ragged.forget_ids()
+            raise
+
+    def _step_ragged_pipelined(self):
         with RecordEvent("generation::schedule"):
-            decoding, pack, spec_plan = self._plan_ragged()
+            plan = self._plan_ragged()
+        if plan is None:
+            # planning behind the step in flight would preempt: its
+            # tokens first, then today's sequence
+            self._retire_inflight("preempt")
+            with RecordEvent("generation::schedule"):
+                plan = self._plan_ragged()
+        decoding, pack, spec_plan = plan
         if not decoding and not pack:
+            advanced = self._retire_inflight()
             self._account_step()
-            return 0
+            return advanced
         # the host-free loop takes DECODE-ONLY boundaries (no chunk in
         # the pack) whose every row fits the loop's static caps; a page
         # shortfall inside _dispatch_loop rolls back and falls through
@@ -1683,19 +1733,30 @@ class GenerationEngine:
                 seqs=lambda: "/".join(
                     str(s.seq_id)
                     for s in decoding + [c[0] for c in pack])):
-            advanced, sampled = self._dispatch_ragged(
-                decoding, pack, spec_plan)
-        if sampled:
-            self.metrics.observe_step()
+            advanced = self._dispatch_ragged(decoding, pack, spec_plan)
         self._account_step()
         return advanced
+
+    def _decode_ready(self):
+        """The decode batch of the next step: the scheduler's, less the
+        sequences whose token in flight is their last by
+        `max_new_tokens` (a finish by length is the one the host knows
+        a step ahead)."""
+        ready = self.scheduler.decode_ready()
+        if self._inflight is None:
+            return ready
+        ahead = self._inflight.descriptor_of
+        return [s for s in ready if s.seq_id not in ahead
+                or s.n_generated + 1 < s.request.max_new_tokens]
 
     def _plan_ragged(self):
         """Everything a ragged step decides before it packs: admission,
         deadlines, the chunk pack and the drafts, their page
         reservations and the decode capacity check.  Returns
         ``(decoding, pack, spec_plan)`` with pack as
-        ``[(state, n, start)]``: reserved, still-alive chunks."""
+        ``[(state, n, start)]``: reserved, still-alive chunks — or
+        None, with nothing reserved, where a step is in flight and the
+        reservations could not all be met without preempting."""
         admitted = self.scheduler.admit(limit=self.config.max_prefill_batch)
         if not self.prefill_chunk_tokens:
             # no chunking: prompts take the one-shot prefill paths and
@@ -1711,12 +1772,13 @@ class GenerationEngine:
         # preempted below simply leave their drafts unused.
         planned = []
         if self.prefill_chunk_tokens:
-            room = (self.step_token_budget
-                    - len(self.scheduler.decode_ready()))
+            room = self.step_token_budget - len(self._decode_ready())
             planned = self.scheduler.plan_pack(
                 self.prefill_chunk_tokens, room=room,
                 max_seqs=(self._ragged.max_seqs
                           if self.config.prefill_pack else 1))
+        if self._inflight is not None and not self._pages_suffice(planned):
+            return None
         spec_plan = {}
         if self._spec is not None:
             spec_plan = self.scheduler.plan_spec(
@@ -1731,9 +1793,7 @@ class GenerationEngine:
             start = self._reserve_chunk(state, n)
             if start is not None:
                 pack.append((state, n, start))
-        decoding = self.scheduler.decode_ready()
-        if decoding:
-            decoding = self._ensure_step_capacity()
+        decoding = self._ensure_step_capacity()
         # reservations and the capacity check preempt youngest-first —
         # a victim's reserved span died with its pages, so it (and any
         # pack member preempted by a LATER member's reservation) drops
@@ -1741,6 +1801,23 @@ class GenerationEngine:
         pack = [(s, n, st) for s, n, st in pack
                 if s.slot is not None and s.prefilling]
         return decoding, pack, spec_plan
+
+    def _pages_suffice(self, planned):
+        """Whether the planned chunks and a token a decode row can all
+        be reserved from what is free or evictable, in both page
+        groups: the sum `_ensure_step_capacity` takes, over the chunks
+        too.  Asked only with a step in flight, whose tokens are read
+        before anything is preempted."""
+        cache = self.cache
+        spans = ([(s.seq_id, n) for s, n in planned]
+                 + [(s.seq_id, 1) for s in self._decode_ready()])
+        if sum(cache.pages_needed(sid, n)
+               for sid, n in spans) > cache.available_pages:
+            return False
+        wg = cache.window_group
+        return wg is None or sum(
+            wg.pages_needed(sid, cache.seq_len(sid) + n)
+            for sid, n in spans) <= wg.free_pages
 
     def _account_step(self):
         """A ragged step's closing counters, under their own span so
@@ -1755,33 +1832,57 @@ class GenerationEngine:
             self._drain_kv_bytes()
             self._observe_occupancy()
 
-    def _account_step_counting(self):
-        """`_account_step` of an engine whose model counts inside its
-        step (`RaggedStep.step_counters`): the counter blocks of the
-        steps since the last read are fetched here, after the step's own
-        fetch, when the device has long finished them."""
-        GenerationEngine._account_step(self)
-        pending = self._ragged.pending_counters
-        if pending:
-            with RecordEvent("generation::account"):
-                self._ragged.pending_counters = []
-                self.metrics.count_model_step(
-                    self._ragged.step_counters,
-                    np.sum([np.asarray(c) for c in pending], axis=0))
+    def _drain_reason(self, step):
+        """Why `step`, just enqueued, cannot stay in flight — the
+        pipeline's depth, decided a step at a time from what the engine
+        sees — or None where it can.  `stochastic`: a sampler's token is
+        drawn on the host from the logits; `speculation`: the next
+        step's rows depend on what this one accepts (and the host-free
+        loop fetches inside its own dispatch); `handoff`: a sequence is
+        parked, pages and all, the moment its prompt is consumed."""
+        if self._spec is not None or self._loop is not None:
+            return "speculation"
+        if self._handoff:
+            return "handoff"
+        return None if step.greedy else "stochastic"
+
+    def _retire_inflight(self, reason=None):
+        """Read, emit and account the step in flight, if any; `reason`
+        says why the pipeline drains here (`generation.pipeline_drains`)
+        when it is not for want of work.  Returns the descriptors that
+        step advanced, 0 with nothing in flight."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return 0
+        if reason is not None:
+            self.metrics.count_pipeline_drain(reason)
+        try:
+            self._retire(step)
+        finally:
+            # the ids are kept for the rows of a step enqueued behind
+            # this one, and there is none: with nothing in flight the
+            # engine holds no device array but its pools
+            self._ragged.forget_ids()
+        return step.advanced
 
     def _dispatch_ragged(self, decoding, pack, spec_plan=None):
-        """Pack, dispatch, sample: the decode batch's spans first (slot
-        order — each sequence's committed token, followed by its draft
-        tokens when it speculates this step), then each packed chunk's
-        rows consecutively; descriptor i covers decode sequence i
-        (len = 1 + drafts), descriptor B + j the pack's j-th chunk.
-        Returns ``(advanced, sampled)`` — `sampled` counts TOKENS
-        emitted (a speculating row retires accepted + 1 per step)."""
-        b = len(decoding)
+        """Pack and enqueue a step, THEN retire the step in flight: the
+        decode batch's spans first (slot order — each sequence's
+        committed token, or the descriptor of the step in flight that
+        holds it, followed by its draft tokens when it speculates this
+        step), then each packed chunk's rows consecutively; descriptor i
+        covers decode sequence i (len = 1 + drafts), descriptor B + j
+        the pack's j-th chunk.  The new step stays in flight unless
+        `_drain_reason` says otherwise.  Returns the descriptors it
+        advanced."""
+        behind, b = self._inflight, len(decoding)
         with RecordEvent("generation::pack"):
             fixed, spec_rows = self._pack_ragged(decoding, pack, spec_plan)
+        step = _StepInFlight()
         with RecordEvent("generation::dispatch"):
-            ids_dev, logits_dev = self._ragged.dispatch(fixed)
+            step.ids, step.logits, step.counters = \
+                self._ragged.dispatch(fixed)
+        self._inflight = step
         # host work behind the device: hidden for as long as it is
         # shorter than the kernel
         with RecordEvent("generation::post_dispatch"):
@@ -1805,33 +1906,18 @@ class GenerationEngine:
             # row when it just completed its prompt (those logits ARE
             # the first-token logits), each with its descriptor index
             samplers = [(s, i) for i, s in enumerate(decoding)] + finishing
-            greedy = all(s.request.params.greedy for s, _ in samplers)
-        # the single host sync — the wait for the device: the ids (with
-        # speculation the [S, 3] int block) of an all-greedy step, the
-        # logits (augmented [S, V + 3]) when any sampler is stochastic.
-        # A mid-prompt chunk-only step fetches NOTHING — zero host
-        # syncs, exactly like the legacy unmaterialized chunks.
-        fetched = None
-        if samplers:
-            with RecordEvent("generation::fetch"):
-                fetched = np.asarray(ids_dev if greedy else logits_dev)
-        with RecordEvent("generation::emit"):
-            if not samplers:
-                sampled = 0
-            elif self._spec is not None:
-                sampled = self._apply_ragged_spec(samplers, spec_rows, b,
-                                                  fetched, greedy)
-            else:
-                states = [s for s, _ in samplers]
-                picked = fetched[[di for _, di in samplers]]
-                if greedy:
-                    self._apply_tokens(states, picked)
-                else:
-                    self._apply_logits_batch(states, picked)
-                sampled = len(samplers)
-        with RecordEvent("generation::account"):
+            step.samplers = [(s, di, s.preemptions) for s, di in samplers]
+            # {seq_id: descriptor}: whose next token the ids hold
+            step.descriptor_of = {s.seq_id: di for s, di in samplers}
+            step.greedy = all(s.request.params.greedy for s, _ in samplers)
+            step.decode_rows, step.spec_rows = b, spec_rows
+            step.advanced = b + len(pack)
+            # what the dispatch itself settled is booked here, behind
+            # the device (the next step's `pad` overwrites the `last_*`
+            # read): one dispatch, and the one host sync it will be
+            # read with
             self.metrics.observe_decode_step(
-                self._ragged.last_dispatches, 1 if samplers else 0)
+                self._ragged.last_dispatches, 1 if step.samplers else 0)
             self.metrics.observe_collective_bytes(
                 self._ragged.last_collective_bytes)
             # zero padded_token_waste by construction: descriptors
@@ -1848,7 +1934,66 @@ class GenerationEngine:
                 self._ragged.last_score_blocks,
                 self._ragged.last_score_blocks_untiled,
                 self._ragged.last_grid_cells)
-        return b + len(pack), sampled
+        if behind is not None:
+            self.metrics.count_step_overlapped()
+            self._retire(behind)
+        reason = self._drain_reason(step)
+        if reason is not None:
+            self._retire_inflight(reason)
+        return step.advanced
+
+    def _retire(self, step):
+        """A dispatched step's host half: the single host sync — the
+        wait for the device: the ids (with speculation the [S, 3] int
+        block) of an all-greedy step, the logits (augmented [S, V + 3])
+        when any sampler is stochastic; a mid-prompt chunk-only step
+        fetches NOTHING — then sampling and the pushes to the handles,
+        then the step's own counters.  A row is applied only to the
+        sequence it was dispatched for: one that a stop, a deadline or
+        a cancel retired while the row was in flight (or that was
+        preempted and admitted again) never receives it; the row is
+        dropped and counted, and its over-reserved position went back
+        with the sequence's pages."""
+        samplers = [(s, di) for s, di, stamp in step.samplers
+                    if s.slot is not None and s.preemptions == stamp]
+        fetched = None
+        if step.samplers:
+            with RecordEvent("generation::fetch"):
+                try:
+                    fetched = np.asarray(step.ids if step.greedy
+                                         else step.logits)
+                except BaseException:
+                    # the device failed the step, and a later one may
+                    # already sit on its donated pools: fresh storage,
+                    # as `_dispatch_donating` leaves it
+                    self.cache.reset_pools()
+                    raise
+        with RecordEvent("generation::emit"):
+            if not samplers:
+                sampled = 0
+            elif self._spec is not None:
+                sampled = self._apply_ragged_spec(
+                    samplers, step.spec_rows, step.decode_rows, fetched,
+                    step.greedy)
+            else:
+                states = [s for s, _ in samplers]
+                picked = fetched[[di for _, di in samplers]]
+                if step.greedy:
+                    self._apply_tokens(states, picked)
+                else:
+                    self._apply_logits_batch(states, picked)
+                sampled = len(samplers)
+        with RecordEvent("generation::account"):
+            if sampled:
+                self.metrics.observe_step()
+            self.metrics.count_overlap_rows_discarded(
+                len(step.samplers) - len(samplers))
+            if step.counters is not None:
+                # what the model counted inside THIS step, which the
+                # fetch above has waited for: never a later step's
+                # block, whose read would wait for that step
+                self.metrics.count_model_step(
+                    self._ragged.step_counters, np.asarray(step.counters))
 
     def _pack_ragged(self, decoding, pack, spec_plan):
         """The host half of a ragged dispatch: reserve the decode rows
@@ -1882,6 +2027,13 @@ class GenerationEngine:
         tokens = []
         desc_ids = []
         spans = []     # descriptor j's (first position, row count)
+        # a decode row whose sequence sampled in the step in flight has
+        # no token on the host yet: `src` names the descriptor of that
+        # step whose id it is (no drafts ride such a step, so decode
+        # row i is packed row i)
+        ahead = (self._inflight.descriptor_of
+                 if self._inflight is not None else {})
+        src = [ahead.get(s.seq_id, -1) for s in decoding] if ahead else None
         for i, s in enumerate(decoding):
             drafts = spec_rows.get(i, ())
             tokens.append(int(d_tokens[i]))
@@ -1928,6 +2080,8 @@ class GenerationEngine:
                                                          pt.shape[1])
             packed += ((w_pt[desc_of_row, pos_all // ps], w_pt),)
         extra = {}
+        if src is not None:
+            extra["src"] = src + [-1] * (t_real - len(src))
         if self.cache.slot_state is not None:
             # where each descriptor's recurrent state lives: its slot
             extra["state_slots"] = ([s.slot for s in decoding]
@@ -2171,6 +2325,9 @@ class GenerationEngine:
             steps += 1
             if steps > max_steps:
                 raise RuntimeError(f"not idle after {max_steps} steps")
+        with self._lock:
+            # a step whose every row rode in vain may still be in flight
+            self._retire_inflight()
         return steps
 
     # --------------------------- internals --------------------------
@@ -2499,7 +2656,7 @@ class GenerationEngine:
         or give up while preemption could still succeed).  Returns the
         surviving decode batch (slot order)."""
         while True:
-            active = self.scheduler.decode_ready()
+            active = self._decode_ready()
             if not active:
                 return active
             need = sum(self.cache.pages_needed(s.seq_id, 1) for s in active)
@@ -2762,6 +2919,12 @@ class GenerationEngine:
         # client-driven step()) must finish before its pages are freed —
         # retiring mid-step would make attend() write into freed pages.
         with self._lock:
+            # the tokens of a step in flight are computed: they are
+            # delivered before what is still unfinished is failed
+            try:
+                self._retire_inflight("api")
+            except Exception:   # noqa: BLE001 — its sequences fail
+                pass            # with the rest, below
             for state in self.scheduler.active():
                 self.scheduler.retire(state)
                 state.handle.set_exception(ServingError(

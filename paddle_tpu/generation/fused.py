@@ -481,6 +481,30 @@ class ChunkedPrefillStep:
                                   self._num_layers)
 
 
+def hand_over_tokens(tokens, src, prev_ids):
+    """The packed token row of a step enqueued behind another: row r
+    takes descriptor ``src[r]``'s id of the previous step where
+    ``src[r] >= 0`` (a decode row whose sequence sampled there, the id
+    still on the device), and the host's `tokens[r]` elsewhere."""
+    import jax.numpy as jnp
+
+    return jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)], tokens)
+
+
+def handing_over(fn, n_fixed):
+    """A model's `ragged_step_fn` behind the token hand-over, as
+    `RaggedStep` compiles it: ``(params, *fixed, src, prev_ids,
+    *state_groups)``, `fixed` the model's own `n_fixed` arguments with
+    the packed tokens first.  Done here, once, so no model's step
+    function knows of it."""
+    def step(params, *args):
+        fixed, (src, prev_ids) = args[:n_fixed], args[n_fixed:n_fixed + 2]
+        return fn(params, hand_over_tokens(fixed[0], src, prev_ids),
+                  *fixed[1:], *args[n_fixed + 2:])
+
+    return step
+
+
 class RaggedStep:
     """ONE mixed-batch executable per engine step — the Ragged Paged
     Attention serving model (PAPERS.md): the decode batch's single-token
@@ -510,6 +534,15 @@ class RaggedStep:
     logits row.  That is the zero of `generation.padded_token_waste`;
     the inert-slot fraction of the fixed axis is reported honestly by
     `generation.step_row_utilization` instead.
+
+    A step may be enqueued BEHIND another whose ids the host has not
+    read: the previous dispatch's `ids` output stays on the device
+    (`_prev_ids`, never donated) and rides the next dispatch as one more
+    argument beside `src` [max_tokens], which says for each packed row
+    whether its token is the host's (-1) or descriptor `src`'s id of
+    that previous step (`hand_over_tokens`).  With nothing in flight
+    every `src` is -1 and the ids are zeros (`forget_ids`): the same
+    executable either way.
 
     Compiles/hits land under the DECODE cache metrics — the ragged
     executable IS the step executable (the prefill counters keep
@@ -552,15 +585,10 @@ class RaggedStep:
         if self._quant_collectives:
             step_kw["quant_collectives"] = True
         # a model that counts inside its step (`step_counters`, the
-        # names of one more [n] int32 output) gets a third output and
-        # its own dispatch, chosen here once: nothing of it is asked
-        # about again in a step
+        # names of one more [n] int32 output) gets a third output, which
+        # `dispatch` hands back unread beside the other two
         self.step_counters = tuple(getattr(model, "step_counters", ()))
-        self.pending_counters = []
-        self._n_out = 2
-        if self.step_counters:
-            self._n_out = 3
-            self.dispatch = self._dispatch_counting
+        self._n_out = 2 + bool(self.step_counters)
         if self.spec_tokens:
             # only spec-aware models see the kwarg: the plain ragged
             # protocol keeps working unchanged for models without it
@@ -575,21 +603,34 @@ class RaggedStep:
         # page_tables): its rows are written, and its layers read,
         # through a table of their own; a cache with state layers adds
         # each descriptor's state slot, and its tails ride the donation
-        # chain as a group of their own behind the pools
+        # chain as a group of their own behind the pools.  Behind the
+        # model's `_n_fixed` arguments sit the hand-over's two (`src`,
+        # the previous step's ids), which the model never sees
         self._window_group = cache.window_group
         self._state_slots = (cache.state_slots
                              if cache.slot_state is not None else None)
         self._n_fixed = (8 + 2 * (self._window_group is not None)
                          + (self._state_slots is not None))
         sizes = cache.state_group_sizes
+        step = handing_over(fn, self._n_fixed)
         wrapped = _wrap_donating(
             self._num_layers, self._param_tree, jax,
-            lambda params, f, *gs: fn(params, *f, *gs),
-            n_fixed=self._n_fixed, n_out=self._n_out, group_sizes=sizes)
+            lambda params, f, *gs: step(params, *f, *gs),
+            n_fixed=self._n_fixed + 2, n_out=self._n_out,
+            group_sizes=sizes)
         self._exec = CompiledModelCache(
             wrapped, metrics=DecodeCacheMetrics(metrics), aot=True,
             donate_argnums=_pool_donate_plan(
-                self._num_layers, self._n_fixed, group_sizes=sizes))
+                self._num_layers, self._n_fixed + 2, group_sizes=sizes))
+        # the ids of no previous step: zeros in the shape — and, under a
+        # mesh, the replicated placement — of a step's own `ids`
+        self._zero_ids = np.zeros((self.max_seqs,), np.int32)
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._zero_ids = jax.device_put(
+                self._zero_ids, NamedSharding(mesh, PartitionSpec()))
+        self._prev_ids = self._zero_ids
         self.last_dispatches = 0
         self.last_collective_bytes = 0
         self.last_rows_useful = 0
@@ -626,6 +667,10 @@ class RaggedStep:
             fixed += [sds((t,), i32), sds((s, bucket_p), i32)]
         if self._state_slots is not None:
             fixed.append(sds((s,), i32))
+        # the hand-over's two: `src`, and the previous step's ids
+        fixed += [sds((t,), i32),
+                  sds((s,), i32, sharding=getattr(self._zero_ids,
+                                                  "sharding", None))]
         return fixed
 
     def prewarm(self, pages_cols):
@@ -646,7 +691,7 @@ class RaggedStep:
         return self._exec.compile_count > before
 
     def pad(self, tokens, positions, pages, rows, page_tables, starts,
-            lens, kv_lens, window=None, state_slots=None):
+            lens, kv_lens, window=None, state_slots=None, src=None):
         """The executable's eight fixed arguments from the PACKED host
         arrays (the engine built them at exact sizes): the token axis
         padded to `max_tokens` with inert slots (sentinel page,
@@ -657,7 +702,10 @@ class RaggedStep:
         descriptors, padded alike into a ninth and tenth argument.
         `state_slots`: each descriptor's decode slot, for the state
         layers; descriptors past the real ones point at the row behind
-        the last slot, which belongs to no sequence."""
+        the last slot, which belongs to no sequence.  `src`: for each
+        packed row the descriptor of the PREVIOUS dispatch whose id is
+        its token, -1 (all of them when None) for the host's own: the
+        last argument, which `dispatch` completes with those ids."""
         t_real = len(tokens)
         s_real = len(starts)
         if t_real > self.max_tokens:
@@ -701,6 +749,10 @@ class RaggedStep:
             sl = np.full((s,), self._state_slots, np.int32)
             sl[:s_real] = state_slots
             extra.append(sl)
+        sr = np.full((t,), -1, np.int32)
+        if src is not None:
+            sr[:t_real] = src
+        extra.append(sr)
         self.last_pages_bucket = bucket_p
         self.last_rows_useful = t_real
         self.last_rows_dispatched = t
@@ -710,26 +762,32 @@ class RaggedStep:
         return [tok, pos, pg, rw, pt, st, ln, kv, *extra]
 
     def dispatch(self, fixed):
-        """The ONE donated dispatch of a step over `pad`'s arguments.
-        Returns ``(ids [S], logits [S, V])`` UNMATERIALIZED — or, with
-        spec_tokens, ``(ints [S, 3], logits_aug [S, V + 3])`` carrying
-        the accept/bonus columns (model.ragged_step_fn) — the caller
-        fetches at most one of them (its single host sync)."""
-        args = [*fixed, *self._cache.take_pool_state(),
+        """The ONE donated dispatch of a step over `pad`'s arguments,
+        behind the previous one's ids.  Returns ``(ids [S], logits
+        [S, V], counters)`` UNMATERIALIZED — with spec_tokens ``ints
+        [S, 3]`` and ``logits_aug [S, V + 3]`` carrying the accept/bonus
+        columns (model.ragged_step_fn) — of which the caller fetches at
+        most one of the first two (its single host sync); `counters` is
+        the block of a model with `step_counters` (None without), read
+        by the accounting of the step once it has been retired.  The
+        ids stay here too, for the next dispatch's `src` rows (a
+        speculative step's block is no such row: it hands none over)."""
+        args = [*fixed, self._prev_ids, *self._cache.take_pool_state(),
                 *self._param_leaves]
         out = _dispatch_donating(
             self._cache, self._exec, args, self._num_layers,
             n_out=self._n_out)
         self.last_dispatches = 1
-        return out
+        if not self.spec_tokens:
+            self._prev_ids = out[0]
+        return (*out, None)[:3]
 
-    def _dispatch_counting(self, fixed):
-        """`dispatch` for a model with `step_counters`: the step's
-        counter block stays on the device in `pending_counters` until
-        the engine's accounting reads it."""
-        ids, logits, counters = RaggedStep.dispatch(self, fixed)
-        self.pending_counters.append(counters)
-        return ids, logits
+    def forget_ids(self):
+        """Drop the previous step's ids: the engine calls this whenever
+        no step is in flight any more (nothing will be enqueued behind
+        them), and where a step of its pipeline failed (they are
+        poisoned like the pools `_dispatch_donating` reset)."""
+        self._prev_ids = self._zero_ids
 
     def count_kernel_cells(self, fixed):
         """The dispatch's grid and the part of it that computes, per

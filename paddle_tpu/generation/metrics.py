@@ -277,6 +277,30 @@ Metric names:
                                       number (<= 1/N on a decode-only
                                       batch; the per-step path pays
                                       ~1)
+- ``generation.steps_overlapped``      ragged steps enqueued BEHIND a step
+                                      whose tokens the host had not read
+                                      (engine._step_ragged's pipeline at
+                                      depth 1): over the ragged steps
+                                      dispatched, how often the host's
+                                      work on a step hid behind the
+                                      device
+- ``generation.pipeline_drains`` / ``.stochastic`` / ``.speculation`` /
+  ``.preempt`` / ``.handoff`` / ``.api``  times that pipeline ran at
+                                      depth 0 for a cause other than
+                                      want of work, in all and by cause:
+                                      a step with a stochastic sampler
+                                      (its token is drawn on the host),
+                                      speculation or the host-free loop
+                                      on, a plan that would preempt, a
+                                      hand-off engine, a call that needs
+                                      the engine settled (cancel,
+                                      evacuate, import, export,
+                                      shutdown) with a step in flight
+- ``generation.overlap_rows_discarded``  rows of a step in flight whose
+                                      sequence was gone when the step
+                                      was read (a stop token or
+                                      sequence, a deadline, a cancel):
+                                      dispatched in vain, never applied
 - ``generation.loop_early_exits``     loop dispatches that exited
                                       before iteration N because every
                                       live row had finished (the
@@ -354,6 +378,9 @@ LOOP_STEPS = PREFIX + "loop_steps"
 DECODE_HOST_FETCHES_PER_TOKEN = PREFIX + "decode_host_fetches_per_token"
 LOOP_EARLY_EXITS = PREFIX + "loop_early_exits"
 LOOP_WASTED_STEPS = PREFIX + "loop_wasted_steps"
+STEPS_OVERLAPPED = PREFIX + "steps_overlapped"
+PIPELINE_DRAINS = PREFIX + "pipeline_drains"
+OVERLAP_ROWS_DISCARDED = PREFIX + "overlap_rows_discarded"
 
 
 class GenerationMetrics:
@@ -685,6 +712,23 @@ class GenerationMetrics:
         if dispatched:
             self._stat(STEP_ROW_UTILIZATION).set(
                 round(useful / dispatched, 3))
+
+    def count_step_overlapped(self):
+        """One ragged step enqueued behind a step still in flight."""
+        self._stat(STEPS_OVERLAPPED).increase()
+
+    def count_pipeline_drain(self, reason):
+        """The ragged step's pipeline emptied for `reason`
+        (``stochastic`` / ``speculation`` / ``preempt`` / ``handoff`` /
+        ``api``) rather than for want of work."""
+        self._stat(PIPELINE_DRAINS).increase()
+        self._stat(PIPELINE_DRAINS + "." + reason).increase()
+
+    def count_overlap_rows_discarded(self, n):
+        """Rows of a step in flight dropped at its emit: their sequence
+        had been retired meanwhile."""
+        if n:
+            self._stat(OVERLAP_ROWS_DISCARDED).increase(int(n))
 
     def observe_step(self):
         """One engine step that sampled at least one token (the token

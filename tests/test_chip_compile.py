@@ -10,6 +10,7 @@ built is a failure, not a skip.  Shapes are the ones chip_smoke.py's server
 runs: 8 heads of 128, 4096 pages of 16 tokens.
 """
 import importlib
+import math
 import re
 
 import jax
@@ -233,6 +234,37 @@ def test_ragged_kernel_compiles_on_the_head_sharded_mesh(v5e_2x2):
     assert call[:3] == ["s32[]", "s32[1088]", "s32[208]"]
 
 
+def _handed_over(fn, fixed):
+    """A model's step as the engine compiles it (`fused.RaggedStep`):
+    behind the token hand-over, whose two arguments (`src` a packed row,
+    the previous step's ids a descriptor) follow the model's own."""
+    from paddle_tpu.generation.fused import handing_over
+
+    rows, ids = fixed[0], fixed[5]
+    return handing_over(fn, len(fixed)), [*fixed, rows, ids]
+
+
+@pytest.mark.parametrize("t,s", [(80, 17), (528, 17), (576, 65)])
+def test_the_token_hand_over_is_a_gather_of_ids(v5e, t, s):
+    """The merged token row at the cells' packed axes (OPT and GLM,
+    Trinity, Granite): a gather of [max_tokens] ids out of the previous
+    step's [max_seqs] and a select.  Nothing wider than the row is made
+    of the ids (no one-hot product, no broadcast over the rows), and the
+    whole steps below, lowered behind it, still yield no pool and no
+    state array but by their in-place writes."""
+    from paddle_tpu.generation.fused import hand_over_tokens
+
+    row, ids = ((t,), "int32"), ((s,), "int32")
+    structs = [jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=v5e)
+               for shape, dtype in (row, row, ids)]
+    compiled = jax.jit(hand_over_tokens).lower(*structs).compile()
+    shapes = set(re.findall(r"= \w+\[([\d,]*)\]", compiled.as_text()))
+    widest = max(math.prod(int(d) for d in shape.split(",") if d)
+                 for shape in shapes)
+    assert widest <= max(t, 1024), shapes     # a row, or a tile of scratch
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def _opt_step_lowered(v5e, layout, pages_bucket, layers=2):
     """TinyCausalLM's ragged step at opt-6.7b-d8's shapes (32 heads of
     128, a 1280-page pool, 80 packed rows under 17 descriptors; depth and
@@ -260,9 +292,10 @@ def _opt_step_lowered(v5e, layout, pages_bucket, layers=2):
              else (pages, PAGE_SIZE, heads, HEAD_DIM))
     fixed = ([sds((t,), "int32")] * 4 + [sds((s, pages_bucket), "int32")]
              + [sds((s,), "int32")] * 3)
-    fn = model.ragged_step_fn(PAGE_SIZE, pages, use_kernel=True,
-                              pool_layout=layout)
-    return jax.jit(fn, donate_argnums=(9, 10)).lower(
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(PAGE_SIZE, pages, use_kernel=True,
+                             pool_layout=layout), fixed)
+    return jax.jit(fn, donate_argnums=(11, 12)).lower(
         params, *fixed, [sds(shape)] * layers, [sds(shape)] * layers), shape
 
 
@@ -303,11 +336,11 @@ def test_ragged_step_moves_no_pool_in_kernel_layout(v5e, pages_bucket):
 
 
 # sha256 of the OPT step's lowered text with the kernels' source
-# locations dropped, by pages bucket; as PR 35's tree lowers it (the
-# grouped cell of the per-head kernel)
+# locations dropped, by pages bucket; as PR 37's tree lowers it (PR 35's
+# grouped cell of the per-head kernel, behind the token hand-over)
 OPT_STEP_DIGESTS = {
-    16: "1aefcf194a6f5ab6a6dd76196ff4ee29c949cd9714fa3d254264b784ad6b7972",
-    128: "2d44213ba105dcd89fa6a888350f27f6461a190af9c2f0dbdbe25984f9cd48a9",
+    16: "3eafaadc550d323b87221f6250d9755d755599a58c854b0fb65785a6080f8c8f",
+    128: "787bda08bad0814b45e4f71b226a8b1216d4323bf87d3039b7c75f5990da38c1",
 }
 
 
@@ -354,17 +387,18 @@ def _shapes_only(monkeypatch, cls):
         lambda self, seed: jax.eval_shape(lambda: draw(self, seed)))
 
 
-# as PR 33's tree lowers it (one dense and one expert layer, a
-# 1,024-row vocabulary, the 512-page bucket; all else the cell's)
+# as PR 37's tree lowers it: PR 33's step behind the token hand-over
+# (one dense and one expert layer, a 1,024-row vocabulary, the 512-page
+# bucket; all else the cell's)
 GLM_STEP_DIGEST = (
-    "03fbc9c54b1e2478c898525ab5784373f925486ea1e35a1b2a46f89afeb5b5e7")
+    "e527a86a66f2ce5da307f91688f07c15736dc07d91abe7be11c85a39f89b60c3")
 
 
 def test_the_glm_ragged_step_lowers_to_the_text_it_had(v5e, monkeypatch):
     """`LatentMoELM`'s step is not touched by what it now shares with
     the third served model (`generation/blocks.py`) nor by that model's
     cache and kernel: glm-4.7-flash-d7's lowered step, cut to two layers
-    and a small vocabulary, is the text PR 33 left."""
+    and a small vocabulary, is the text PR 33 left behind the hand-over."""
     from paddle_tpu.generation import latent_moe_model as lm
 
     args, engine = _glm_cell()
@@ -384,9 +418,10 @@ def test_the_glm_ragged_step_lowers_to_the_text_it_had(v5e, monkeypatch):
                rows.dtype)
     fixed = [sds((t,), "int32")] * 4 + [sds((s, 512), "int32")] + [
         sds((s,), "int32")] * 3
-    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
-                              use_kernel=True)
-    lowered = jax.jit(fn, donate_argnums=(9,)).lower(
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                             use_kernel=True), fixed)
+    lowered = jax.jit(fn, donate_argnums=(11,)).lower(
         params, *fixed, [pool] * model.num_layers)
     assert _step_digest(lowered, 2) == GLM_STEP_DIGEST
 
@@ -435,9 +470,10 @@ def test_hybrid_step_compiles_at_the_published_widths(v5e, monkeypatch):
              for kind in model.layer_kinds if kind == "state"]
     fixed = ([sds((t,), "int32")] * 4 + [sds((s, 64), "int32")]
              + [sds((s,), "int32")] * 4)
-    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
-                              use_kernel=True)
-    compiled = jax.jit(fn, donate_argnums=(10, 11)).lower(
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                             use_kernel=True), fixed)
+    compiled = jax.jit(fn, donate_argnums=(12, 13)).lower(
         params, *fixed, pools, tails).compile()
     memory = compiled.memory_analysis()
     assert 13.0e9 < memory.argument_size_in_bytes < 13.5e9
@@ -611,9 +647,10 @@ def test_latent_step_compiles_at_the_published_widths(v5e, monkeypatch):
                rows.dtype)
     fixed = [sds((t,), "int32")] * 4 + [sds((s, 512), "int32")] + [
         sds((s,), "int32")] * 3
-    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
-                              use_kernel=True)
-    compiled = jax.jit(fn, donate_argnums=(9,)).lower(
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                             use_kernel=True), fixed)
+    compiled = jax.jit(fn, donate_argnums=(11,)).lower(
         params, *fixed, [pool] * model.num_layers).compile()
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
@@ -710,9 +747,10 @@ def test_gqa_step_compiles_at_the_published_widths(v5e, monkeypatch):
     tables = sds((s, 1024), "int32")
     fixed = ([sds((t,), "int32")] * 4 + [tables] + [sds((s,), "int32")] * 3
              + [sds((t,), "int32"), tables])
-    fn = model.ragged_step_fn(engine["page_size"], engine["num_pages"],
-                              use_kernel=True)
-    compiled = jax.jit(fn, donate_argnums=(11,)).lower(
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                             use_kernel=True), fixed)
+    compiled = jax.jit(fn, donate_argnums=(13,)).lower(
         params, *fixed, pools).compile()
     memory = compiled.memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes

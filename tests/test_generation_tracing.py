@@ -114,17 +114,45 @@ def _steps(spans):
     return out
 
 
+def _closings(names):
+    """`names` cut into the closings of retired steps: (fetch,) emit,
+    account each; None where they are anything else."""
+    out, names = [], list(names)
+    while names:
+        n = 3 if names[0] == "fetch" else 2
+        if names[:n] != PHASES[6 - n:]:
+            return None
+        out.append(names[:n])
+        names = names[n:]
+    return out
+
+
 def test_every_step_holds_its_phases_in_order(traced_run):
+    """A step's span holds the opening phases of the step it enqueues,
+    then the closing phases of the step it retires: none (nothing was in
+    flight), one (the step in flight, or at depth 0 its own) or two (the
+    step in flight and then its own, which cannot stay in flight)."""
     _, spans = traced_run
     steps = _steps(spans)
     assert len(steps) > 5
-    fetches = 0
+    fetches = closed = 0
     for _, inside in steps:
         names = [s[0] for s in inside]
-        fetches += "fetch" in names
-        assert names in (PHASES, [p for p in PHASES if p != "fetch"])
+        assert names[:3] == PHASES[:3]
+        closings = _closings(names[3:])
+        assert closings is not None and len(closings) <= 2, names
+        closed += len(closings)
+        fetches += sum("fetch" in c for c in closings)
         for before, after in zip(inside, inside[1:]):
             assert before[2] <= after[1], (before, after)
+    # every step is closed once: inside a later step's span, or, when
+    # nothing is left to plan, at the top level
+    top = [s[0] for s in spans if s[0] in PHASES[3:] and not any(
+        p[0] == "ragged_step" and p[1] <= s[1] and s[2] <= p[2]
+        for p in spans)]
+    top = [n for i, n in enumerate(top)        # `_account_step`'s own
+           if n != "account" or (i and top[i - 1] == "emit")]
+    assert closed + len(_closings(top)) == len(steps)
     # a mid-prompt chunk-only step fetches nothing; every other does
     assert 0 < fetches <= len(steps)
 

@@ -387,8 +387,8 @@ def test_pad_hands_over_disjoint_ascending_ranges(model, spec_tokens):
     seen = []
     pad = eng._ragged.pad
 
-    def recording_pad(*args):
-        fixed = pad(*args)
+    def recording_pad(*args, **kw):
+        fixed = pad(*args, **kw)
         seen.append((fixed[5], fixed[6]))
         return fixed
 
