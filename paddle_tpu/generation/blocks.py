@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from . import moe
+from .fused import step_scope
 
 # what a step counts, in the order of its third output:
 # generation.moe_* summed over the expert layers (`moe.STATS`: the
@@ -42,27 +43,41 @@ def gated_mlp(x, w_gate_up, w_down):
     return jnp.dot(hidden, w_down, preferred_element_type=jnp.float32)
 
 
+def feed_forward_scope(lp):
+    """The part of the step a layer's feed-forward half is (its norm
+    and residual with it): "experts" where the layer has a router, else
+    "mlp"."""
+    return step_scope("experts" if "w_router" in lp else "mlp")
+
+
 def feed_forward(lp, x, valid, top_k, scaling, scoring="sigmoid_bias",
                  experts_held=None):
     """A layer's feed-forward half over normed rows x [T, d]: the dense
     gated MLP where the layer has no router, else the routed experts
     (`moe.route` in the scoring form `scoring`, `moe.expert_ffn` over
     the experts `experts_held` names, all of them for None) beside the
-    shared one, under the scope a profile tells the experts by.
+    shared one; the caller runs it under `feed_forward_scope(lp)`.
     Returns (y [T, d] in x's dtype, stats int32 as `moe.STATS` or
     None)."""
     if "w_router" not in lp:
         return gated_mlp(x, lp["w_gate_up"], lp["w_down"]).astype(
             x.dtype), None
-    with jax.named_scope("experts"):
-        experts, weights = moe.route(
-            x, lp["w_router"], lp.get("router_bias"), top_k, scaling,
-            scoring)
-        y, stats = moe.expert_ffn(
-            x, experts, weights, valid, lp["experts_gate_up"],
-            lp["experts_down"], experts_held)
-        y = y + gated_mlp(x, lp["shared_gate_up"], lp["shared_down"])
+    experts, weights = moe.route(
+        x, lp["w_router"], lp.get("router_bias"), top_k, scaling, scoring)
+    y, stats = moe.expert_ffn(
+        x, experts, weights, valid, lp["experts_gate_up"],
+        lp["experts_down"], experts_held)
+    y = y + gated_mlp(x, lp["shared_gate_up"], lp["shared_down"])
     return y.astype(x.dtype), stats
+
+
+def valid_rows(starts, lens, t):
+    """[t] bool: the packed rows that belong to a descriptor (the rows
+    the experts route), under the experts' scope."""
+    with step_scope("experts"):
+        row = jnp.arange(t, dtype=jnp.int32)[None, :]
+        return jnp.any((row >= starts[:, None])
+                       & (row < (starts + lens)[:, None]), axis=0)
 
 
 class DeviceDraw:

@@ -51,6 +51,24 @@ import numpy as np
 from ..serving.bucketing import CompiledModelCache, ShapeBucketer
 from .metrics import DecodeCacheMetrics
 
+# The parts of a served model's ragged step.  Every operation of the
+# step sits under exactly one of them, its outermost `jax.named_scope`
+# (a part may hold scopes of its own: attention/window, state_space/
+# scan); the compiled text keeps them, and a device profile is split by
+# them through `profiler.device_op_scopes()` (docs/GENERATION.md,
+# "Reading a trace").
+STEP_SCOPES = ("hand_over", "embed", "attention", "mlp", "experts",
+               "state_space", "head")
+
+
+def step_scope(part):
+    """The named scope of one part of the step, from `STEP_SCOPES`."""
+    import jax
+
+    if part not in STEP_SCOPES:
+        raise ValueError(f"{part!r} is no part of the step: {STEP_SCOPES}")
+    return jax.named_scope(part)
+
 
 def _wrap_donating(num_layers, tree, jax_mod, call, n_fixed=4, n_out=1,
                    n_groups=2, group_sizes=None):
@@ -278,7 +296,9 @@ class FusedDecodeStep:
             self._exec[greedy] = CompiledModelCache(
                 wrapped, metrics=cache_metrics, aot=True,
                 donate_argnums=_pool_donate_plan(
-                    self._num_layers, n_groups=self._n_groups))
+                    self._num_layers, n_groups=self._n_groups),
+                name=lambda args, g="_greedy" if greedy else "":
+                    f"decode_step_b{args[0].shape[0]}_p{args[2].shape[1]}{g}")
         self.last_dispatches = 0
         self.last_syncs = 0
         self.last_collective_bytes = 0
@@ -434,7 +454,8 @@ class ChunkedPrefillStep:
         self._exec = CompiledModelCache(
             wrapped, metrics=metrics, aot=True,
             donate_argnums=_pool_donate_plan(self._num_layers,
-                                             n_groups=self._n_groups))
+                                             n_groups=self._n_groups),
+            name=lambda args: f"prefill_chunk_p{args[3].shape[0]}")
 
     @property
     def compile_count(self):
@@ -499,8 +520,9 @@ def handing_over(fn, n_fixed):
     function knows of it."""
     def step(params, *args):
         fixed, (src, prev_ids) = args[:n_fixed], args[n_fixed:n_fixed + 2]
-        return fn(params, hand_over_tokens(fixed[0], src, prev_ids),
-                  *fixed[1:], *args[n_fixed + 2:])
+        with step_scope("hand_over"):
+            tokens = hand_over_tokens(fixed[0], src, prev_ids)
+        return fn(params, tokens, *fixed[1:], *args[n_fixed + 2:])
 
     return step
 
@@ -621,7 +643,8 @@ class RaggedStep:
         self._exec = CompiledModelCache(
             wrapped, metrics=DecodeCacheMetrics(metrics), aot=True,
             donate_argnums=_pool_donate_plan(
-                self._num_layers, self._n_fixed + 2, group_sizes=sizes))
+                self._num_layers, self._n_fixed + 2, group_sizes=sizes),
+            name=lambda args: f"ragged_step_p{args[4].shape[1]}")
         # the ids of no previous step: zeros in the shape — and, under a
         # mesh, the replicated placement — of a step's own `ids`
         self._zero_ids = np.zeros((self.max_seqs,), np.int32)
@@ -942,7 +965,8 @@ class LoopedRaggedStep:
             wrapped, metrics=DecodeCacheMetrics(metrics), aot=True,
             donate_argnums=_pool_donate_plan(self._num_layers,
                                              self._n_fixed,
-                                             n_groups=self._n_groups))
+                                             n_groups=self._n_groups),
+            name=lambda args: f"ragged_loop_p{args[3].shape[1]}")
         self.last_dispatches = 0
         self.last_syncs = 0
         self.last_iters = 0

@@ -37,7 +37,9 @@ import jax
 import jax.numpy as jnp
 
 from . import decode_attention
-from .blocks import STEP_COUNTERS, DeviceDraw, feed_forward, rms_norm
+from .blocks import (STEP_COUNTERS, DeviceDraw, feed_forward,
+                     feed_forward_scope, rms_norm, valid_rows)
+from .fused import step_scope
 from .kv_cache import HeadRows
 
 WINDOW, FULL = "window", "full"
@@ -222,19 +224,20 @@ class GQAWindowMoELM:
                 write[WINDOW] = jnp.asarray(window_args[0], jnp.int32)
                 table[WINDOW] = jnp.asarray(window_args[1], jnp.int32)
             t = tokens.shape[0]
-            row_ix = jnp.arange(t, dtype=jnp.int32)[None, :]
-            valid = jnp.any((row_ix >= starts[:, None])
-                            & (row_ix < (starts + lens)[:, None]), axis=0)
-            x = (params["embed"][tokens].astype(jnp.float32)
-                 * self.embed_scale).astype(self.dtype)
-            work = decode_attention.gqa_work_lists(
-                starts, lens, kv_lens, page_size, table[FULL].shape[1], t,
-                self.window, use_kernel)
+            valid = valid_rows(starts, lens, t)
+            with step_scope("embed"):
+                x = (params["embed"][tokens].astype(jnp.float32)
+                     * self.embed_scale).astype(self.dtype)
+            with step_scope("attention"):
+                work = decode_attention.gqa_work_lists(
+                    starts, lens, kv_lens, page_size, table[FULL].shape[1],
+                    t, self.window, use_kernel)
             pools_out = []
-            counters = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
+            with step_scope("head"):
+                counters = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
             for lp, pool, kind in zip(params["layers"], pools,
                                       self.layer_kinds):
-                with jax.named_scope(f"{kind}_attention"):
+                with step_scope("attention"), jax.named_scope(kind):
                     q, gate, row = self._queries_gate_and_row(
                         lp, rms_norm(x, lp["norm1"], self.eps), positions,
                         kind)
@@ -247,17 +250,21 @@ class GQAWindowMoELM:
                         interpret=interpret, work=work[kind])
                     x = x + rms_norm(self._attention_out(lp, o, gate),
                                      lp["norm2"], self.eps)
-                y, stats = feed_forward(
-                    lp, rms_norm(x, lp["norm3"], self.eps), valid,
-                    self.top_k, self.scaling)
+                with feed_forward_scope(lp):
+                    y, stats = feed_forward(
+                        lp, rms_norm(x, lp["norm3"], self.eps), valid,
+                        self.top_k, self.scaling)
                 if stats is not None:
-                    counters = counters + stats
-                x = x + rms_norm(y, lp["norm4"], self.eps)
-            sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
-            logits = jnp.dot(
-                rms_norm(x[sample_rows], params["norm_f"], self.eps),
-                params["head"], preferred_element_type=jnp.float32)
-            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    with step_scope("head"):
+                        counters = counters + stats
+                with feed_forward_scope(lp):
+                    x = x + rms_norm(y, lp["norm4"], self.eps)
+            with step_scope("head"):
+                sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
+                logits = jnp.dot(
+                    rms_norm(x[sample_rows], params["norm_f"], self.eps),
+                    params["head"], preferred_element_type=jnp.float32)
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out = (ids, logits, counters) if self.step_counters \
                 else (ids, logits)
             return out, pools_out

@@ -62,7 +62,9 @@ import jax
 import jax.numpy as jnp
 
 from . import decode_attention
-from .blocks import HELD_STEP_COUNTERS, DeviceDraw, feed_forward, rms_norm
+from .blocks import (HELD_STEP_COUNTERS, DeviceDraw, feed_forward,
+                     feed_forward_scope, rms_norm, valid_rows)
+from .fused import step_scope
 from .kv_cache import HeadRows, SlotState
 
 STATE, FULL = "state", "full"
@@ -460,16 +462,17 @@ class HybridSSMMoELM:
             write = (jnp.asarray(pages, jnp.int32),
                      jnp.asarray(rows, jnp.int32))
             t = tokens.shape[0]
-            row_ix = jnp.arange(t, dtype=jnp.int32)[None, :]
-            valid = jnp.any((row_ix >= starts[:, None])
-                            & (row_ix < (starts + lens)[:, None]), axis=0)
-            x = (params["embed"][tokens].astype(jnp.float32)
-                 * self.embed_scale).astype(self.dtype)
-            work = decode_attention.gqa_work_lists(
-                starts, lens, kv_lens, page_size, table.shape[1], t,
-                None, use_kernel)[FULL]
-            sched = self._schedule(starts, lens, kv_lens, state_slots,
-                                   tails[0].shape[0])
+            valid = valid_rows(starts, lens, t)
+            with step_scope("embed"):
+                x = (params["embed"][tokens].astype(jnp.float32)
+                     * self.embed_scale).astype(self.dtype)
+            with step_scope("attention"):
+                work = decode_attention.gqa_work_lists(
+                    starts, lens, kv_lens, page_size, table.shape[1], t,
+                    None, use_kernel)[FULL]
+            with step_scope("state_space"):
+                sched = self._schedule(starts, lens, kv_lens, state_slots,
+                                       tails[0].shape[0])
 
             def attend(q, pool):
                 return decode_attention.gqa_ragged_attention(
@@ -478,35 +481,43 @@ class HybridSSMMoELM:
                     interpret=interpret, work=work)
 
             pools_out, tails_out, tails = [], [], iter(tails)
-            moe_counts = jnp.zeros((len(HELD_STEP_COUNTERS),), jnp.int32)
+            with step_scope("head"):
+                moe_counts = jnp.zeros((len(HELD_STEP_COUNTERS),),
+                                       jnp.int32)
             for lp, pool, kind in zip(params["layers"], pools,
                                       self.layer_kinds):
-                h = rms_norm(x, lp["norm1"], self.eps)
                 if kind == STATE:
-                    with jax.named_scope("state_space"):
+                    with step_scope("state_space"):
                         mixed, pool, tail = self._state_space(
-                            lp, h, pool, next(tails), sched)
+                            lp, rms_norm(x, lp["norm1"], self.eps), pool,
+                            next(tails), sched)
+                        x = self._residual(x, mixed)
                     tails_out.append(tail)
                 else:
-                    with jax.named_scope("full_attention"):
-                        mixed, pool = self._attention(lp, h, pool, write,
-                                                      attend)
+                    with step_scope("attention"), jax.named_scope(FULL):
+                        mixed, pool = self._attention(
+                            lp, rms_norm(x, lp["norm1"], self.eps), pool,
+                            write, attend)
+                        x = self._residual(x, mixed)
                 pools_out.append(pool)
-                x = self._residual(x, mixed)
-                y, stats = feed_forward(
-                    lp, rms_norm(x, lp["norm2"], self.eps), valid,
-                    self.top_k, None, "softmax_topk", self.experts_held)
-                moe_counts = moe_counts + stats
-                x = self._residual(x, y)
-            sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
-            logits = jax.lax.dot_general(
-                rms_norm(x[sample_rows], params["norm_f"], self.eps),
-                params["embed"], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) / self.logits_scaling
-            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            counters = jnp.concatenate([
-                moe_counts, sched["counts"] * jnp.asarray(
-                    [n_state, n_state, 1], jnp.int32)])
+                with feed_forward_scope(lp):
+                    y, stats = feed_forward(
+                        lp, rms_norm(x, lp["norm2"], self.eps), valid,
+                        self.top_k, None, "softmax_topk", self.experts_held)
+                with step_scope("head"):
+                    moe_counts = moe_counts + stats
+                with feed_forward_scope(lp):
+                    x = self._residual(x, y)
+            with step_scope("head"):
+                sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
+                logits = jax.lax.dot_general(
+                    rms_norm(x[sample_rows], params["norm_f"], self.eps),
+                    params["embed"], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) / self.logits_scaling
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                counters = jnp.concatenate([
+                    moe_counts, sched["counts"] * jnp.asarray(
+                        [n_state, n_state, 1], jnp.int32)])
             return (ids, logits, counters), pools_out, tails_out
 
         return step

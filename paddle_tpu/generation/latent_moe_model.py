@@ -38,7 +38,9 @@ import jax
 import jax.numpy as jnp
 
 from . import decode_attention
-from .blocks import STEP_COUNTERS, DeviceDraw, feed_forward, rms_norm
+from .blocks import (STEP_COUNTERS, DeviceDraw, feed_forward,
+                     feed_forward_scope, rms_norm, valid_rows)
+from .fused import step_scope
 from .kv_cache import LatentRows
 
 
@@ -197,16 +199,17 @@ class LatentMoELM:
             lens = jnp.asarray(lens, jnp.int32)
             kv_lens = jnp.asarray(kv_lens, jnp.int32)
             t = tokens.shape[0]
-            row_ix = jnp.arange(t, dtype=jnp.int32)[None, :]
-            valid = jnp.any((row_ix >= starts[:, None])
-                            & (row_ix < (starts + lens)[:, None]), axis=0)
-            x = params["embed"][tokens]
-            work = decode_attention.latent_work_list(
-                pt, starts, lens, kv_lens, page_size, t, use_kernel)
+            valid = valid_rows(starts, lens, t)
+            with step_scope("embed"):
+                x = params["embed"][tokens]
+            with step_scope("attention"):
+                work = decode_attention.latent_work_list(
+                    pt, starts, lens, kv_lens, page_size, t, use_kernel)
             pools_out = []
-            counters = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
+            with step_scope("head"):
+                counters = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
             for lp, pool in zip(params["layers"], pools):
-                with jax.named_scope("latent_attention"):
+                with step_scope("attention"), jax.named_scope("latent"):
                     q_abs, row = self._queries_and_row(
                         lp, rms_norm(x, lp["norm1"], self.eps), positions)
                     pool = pool.at[pages, rows].set(row, mode="drop")
@@ -216,17 +219,21 @@ class LatentMoELM:
                         rows_spec.value_width, use_kernel,
                         interpret=interpret, work=work)
                     x = x + self._attention_out(lp, o_abs)
-                y, stats = feed_forward(
-                    lp, rms_norm(x, lp["norm2"], self.eps), valid,
-                    self.top_k, self.scaling)
+                with feed_forward_scope(lp):
+                    y, stats = feed_forward(
+                        lp, rms_norm(x, lp["norm2"], self.eps), valid,
+                        self.top_k, self.scaling)
                 if stats is not None:
-                    counters = counters + stats
-                x = x + y
-            sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
-            logits = jnp.dot(
-                rms_norm(x[sample_rows], params["norm_f"], self.eps),
-                params["head"], preferred_element_type=jnp.float32)
-            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    with step_scope("head"):
+                        counters = counters + stats
+                with feed_forward_scope(lp):
+                    x = x + y
+            with step_scope("head"):
+                sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
+                logits = jnp.dot(
+                    rms_norm(x[sample_rows], params["norm_f"], self.eps),
+                    params["head"], preferred_element_type=jnp.float32)
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out = (ids, logits, counters) if self.step_counters \
                 else (ids, logits)
             return out, pools_out

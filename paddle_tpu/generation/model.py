@@ -32,6 +32,7 @@ import numpy as np
 
 from . import decode_attention
 from .decode_attention import dense_causal_reference
+from .fused import step_scope
 
 
 def _layer_norm(x, scale, bias, eps=1e-5):
@@ -588,57 +589,62 @@ class TinyCausalLM:
             # inert slots embed token 0 at position 0 (in bounds by
             # construction); their K/V rides the sentinel page and their
             # attention rows belong to no descriptor (exact zeros)
-            x = params["tok_emb"][tokens] + params["pos_emb"][positions]
+            with step_scope("embed"):
+                x = params["tok_emb"][tokens] + params["pos_emb"][positions]
             # the kernel's grid follows the descriptors, not the layer:
             # built once here (once an iteration inside the host-free
             # loop, whose descriptors change), shared by every layer
-            work = decode_attention.ragged_work_list(
-                pt, starts, lens, kv_lens,
-                k_pools[0].shape[2 if pool_layout == "kernel" else 1], t,
-                use_kernel=use_kernel)
+            with step_scope("attention"):
+                work = decode_attention.ragged_work_list(
+                    pt, starts, lens, kv_lens,
+                    k_pools[0].shape[2 if pool_layout == "kernel" else 1],
+                    t, use_kernel=use_kernel)
             k_out, v_out, ks_out, vs_out = [], [], [], []
             for li, blk in enumerate(params["blocks"]):
-                hn = _layer_norm(x, blk["ln1_s"], blk["ln1_b"])
-                q, k, v = self._qkv(blk, hn)
-                q = constrain(q, mesh, None, tp_axis, None)
-                k = constrain(k, mesh, None, tp_axis, None)
-                v = constrain(v, mesh, None, tp_axis, None)
-                ks = vs = None
-                if kv_quant:
-                    kp, ks = quantized_pool_write(
-                        k_pools[li], k_scales[li], pages, rows, k,
-                        pool_layout)
-                    vp, vs = quantized_pool_write(
-                        v_pools[li], v_scales[li], pages, rows, v,
-                        pool_layout)
-                    if scale_spec is not None:
-                        ks = constrain(ks, mesh, *scale_spec)
-                        vs = constrain(vs, mesh, *scale_spec)
-                    ks_out.append(ks)
-                    vs_out.append(vs)
-                else:
-                    kp = scatter_pool_update(
-                        k_pools[li], pages, rows,
-                        k.astype(k_pools[li].dtype), pool_layout,
-                        mesh=mesh, tp_axis=tp_axis)
-                    vp = scatter_pool_update(
-                        v_pools[li], pages, rows,
-                        v.astype(v_pools[li].dtype), pool_layout,
-                        mesh=mesh, tp_axis=tp_axis)
-                if pool_spec is not None:
-                    kp = constrain(kp, mesh, *pool_spec)
-                    vp = constrain(vp, mesh, *pool_spec)
-                k_out.append(kp)
-                v_out.append(vp)
-                attn = decode_attention.ragged_paged_attention(
-                    q, kp, vp, pt, starts, lens, kv_lens,
-                    use_kernel=use_kernel, layout=pool_layout,
-                    mesh=mesh, tp_axis=tp_axis, k_scale=ks, v_scale=vs,
-                    work=work)
-                x = x + rowmm(attn.reshape(t, self.d_model), blk["wo"])
-                x = x + self._mlp_rowmm(
-                    blk, _layer_norm(x, blk["ln2_s"], blk["ln2_b"]),
-                    rowmm)
+                with step_scope("attention"):
+                    hn = _layer_norm(x, blk["ln1_s"], blk["ln1_b"])
+                    q, k, v = self._qkv(blk, hn)
+                    q = constrain(q, mesh, None, tp_axis, None)
+                    k = constrain(k, mesh, None, tp_axis, None)
+                    v = constrain(v, mesh, None, tp_axis, None)
+                    ks = vs = None
+                    if kv_quant:
+                        kp, ks = quantized_pool_write(
+                            k_pools[li], k_scales[li], pages, rows, k,
+                            pool_layout)
+                        vp, vs = quantized_pool_write(
+                            v_pools[li], v_scales[li], pages, rows, v,
+                            pool_layout)
+                        if scale_spec is not None:
+                            ks = constrain(ks, mesh, *scale_spec)
+                            vs = constrain(vs, mesh, *scale_spec)
+                        ks_out.append(ks)
+                        vs_out.append(vs)
+                    else:
+                        kp = scatter_pool_update(
+                            k_pools[li], pages, rows,
+                            k.astype(k_pools[li].dtype), pool_layout,
+                            mesh=mesh, tp_axis=tp_axis)
+                        vp = scatter_pool_update(
+                            v_pools[li], pages, rows,
+                            v.astype(v_pools[li].dtype), pool_layout,
+                            mesh=mesh, tp_axis=tp_axis)
+                    if pool_spec is not None:
+                        kp = constrain(kp, mesh, *pool_spec)
+                        vp = constrain(vp, mesh, *pool_spec)
+                    k_out.append(kp)
+                    v_out.append(vp)
+                    attn = decode_attention.ragged_paged_attention(
+                        q, kp, vp, pt, starts, lens, kv_lens,
+                        use_kernel=use_kernel, layout=pool_layout,
+                        mesh=mesh, tp_axis=tp_axis, k_scale=ks, v_scale=vs,
+                        work=work)
+                    x = x + rowmm(attn.reshape(t, self.d_model),
+                                  blk["wo"])
+                with step_scope("mlp"):
+                    x = x + self._mlp_rowmm(
+                        blk, _layer_norm(x, blk["ln2_s"], blk["ln2_b"]),
+                        rowmm)
             return x, k_out, v_out, ks_out, vs_out
 
         return core
@@ -734,61 +740,62 @@ class TinyCausalLM:
                 params, tokens, positions, pages, rows, page_tables,
                 starts, lens, kv_lens, k_pools, v_pools, k_scales,
                 v_scales)
-            # per-descriptor sampling rows: the last packed row each
-            # descriptor owns (padding descriptors read row 0 — garbage
-            # the engine never fetches a token from)
-            sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
-            if spec_tokens:
-                from .speculation import verify_accept
+            with step_scope("head"):
+                # per-descriptor sampling rows: the last packed row each
+                # descriptor owns (padding descriptors read row 0 — garbage
+                # the engine never fetches a token from)
+                sample_rows = jnp.clip(starts + lens - 1, 0, t - 1)
+                if spec_tokens:
+                    from .speculation import verify_accept
 
-                # the verify epilogue needs argmax at each
-                # descriptor's rows start..start+k (row start+j
-                # predicts the token drafted at row start+j+1) plus
-                # the S sample-row logits — gather those S*(k+2) rows
-                # BEFORE the head matmul, so the epilogue's head cost
-                # is O(S * k), never O(T) (chunk rows past the window
-                # and inert padding can't be read by it anyway)
-                s_n = starts.shape[0]
-                kk = int(spec_tokens)
-                vrows = jnp.clip(
-                    starts[:, None]
-                    + jnp.arange(kk + 1, dtype=jnp.int32)[None, :],
-                    0, t - 1)                            # [S, k + 1]
-                gathered = jnp.concatenate(
-                    [x[vrows.reshape(-1)], x[sample_rows]], axis=0)
-                heads = (_layer_norm(gathered, params["ln_f_s"],
-                                     params["ln_f_b"])
-                         @ params["head"])
-                amax_rows = jnp.argmax(
-                    heads[:s_n * (kk + 1)],
-                    axis=-1).astype(jnp.int32).reshape(s_n, kk + 1)
-                logits = heads[s_n * (kk + 1):]          # [S, V]
+                    # the verify epilogue needs argmax at each
+                    # descriptor's rows start..start+k (row start+j
+                    # predicts the token drafted at row start+j+1) plus
+                    # the S sample-row logits — gather those S*(k+2) rows
+                    # BEFORE the head matmul, so the epilogue's head cost
+                    # is O(S * k), never O(T) (chunk rows past the window
+                    # and inert padding can't be read by it anyway)
+                    s_n = starts.shape[0]
+                    kk = int(spec_tokens)
+                    vrows = jnp.clip(
+                        starts[:, None]
+                        + jnp.arange(kk + 1, dtype=jnp.int32)[None, :],
+                        0, t - 1)                            # [S, k + 1]
+                    gathered = jnp.concatenate(
+                        [x[vrows.reshape(-1)], x[sample_rows]], axis=0)
+                    heads = (_layer_norm(gathered, params["ln_f_s"],
+                                         params["ln_f_b"])
+                             @ params["head"])
+                    amax_rows = jnp.argmax(
+                        heads[:s_n * (kk + 1)],
+                        axis=-1).astype(jnp.int32).reshape(s_n, kk + 1)
+                    logits = heads[s_n * (kk + 1):]          # [S, V]
+                    ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    accepted, bonus = verify_accept(
+                        amax_rows, tokens, starts, lens, kk, np_mod=jnp)
+                    ints = jnp.stack([ids, accepted, bonus],
+                                     axis=1)                     # [S, 3]
+                    # one fetchable array per sampling mix: ints for the
+                    # all-greedy step, logits with the int columns appended
+                    # for a mixed batch — either way ONE host sync
+                    aug = jnp.concatenate(
+                        [logits, ints.astype(logits.dtype)], axis=1)
+                    ints = constrain(ints, mesh)
+                    aug = constrain(aug, mesh)
+                    if kv_quant:
+                        return (ints, aug), k_out, v_out, ks_out, vs_out
+                    return (ints, aug), k_out, v_out
+                xs = x[sample_rows]                              # [S, d]
+                logits = (_layer_norm(xs, params["ln_f_s"],
+                                      params["ln_f_b"]) @ params["head"])
                 ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                accepted, bonus = verify_accept(
-                    amax_rows, tokens, starts, lens, kk, np_mod=jnp)
-                ints = jnp.stack([ids, accepted, bonus],
-                                 axis=1)                     # [S, 3]
-                # one fetchable array per sampling mix: ints for the
-                # all-greedy step, logits with the int columns appended
-                # for a mixed batch — either way ONE host sync
-                aug = jnp.concatenate(
-                    [logits, ints.astype(logits.dtype)], axis=1)
-                ints = constrain(ints, mesh)
-                aug = constrain(aug, mesh)
+                # replicated outputs: the engine's single host fetch reads
+                # ONE of them without a cross-device gather
+                ids = constrain(ids, mesh)
+                logits = constrain(logits, mesh)
                 if kv_quant:
-                    return (ints, aug), k_out, v_out, ks_out, vs_out
-                return (ints, aug), k_out, v_out
-            xs = x[sample_rows]                              # [S, d]
-            logits = (_layer_norm(xs, params["ln_f_s"],
-                                  params["ln_f_b"]) @ params["head"])
-            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # replicated outputs: the engine's single host fetch reads
-            # ONE of them without a cross-device gather
-            ids = constrain(ids, mesh)
-            logits = constrain(logits, mesh)
-            if kv_quant:
-                return (ids, logits), k_out, v_out, ks_out, vs_out
-            return (ids, logits), k_out, v_out
+                    return (ids, logits), k_out, v_out, ks_out, vs_out
+                return (ids, logits), k_out, v_out
 
         return step
 

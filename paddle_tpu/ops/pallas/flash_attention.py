@@ -195,6 +195,7 @@ def _flash_fwd_call(qs, k, v, km, causal, heads, have_mask):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=_interpret(),
     )(qs, k, v, km)
     return out, lse
@@ -329,6 +330,7 @@ def _flash_bwd_call(qs, k, v, km, out, lse, do, causal, heads, have_mask):
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_bwd_dkdv",
         interpret=_interpret(),
     )(*operands)
 
@@ -353,6 +355,7 @@ def _flash_bwd_call(qs, k, v, km, out, lse, do, causal, heads, have_mask):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qs.shape, qs.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(*operands)
     return dq, dk, dv

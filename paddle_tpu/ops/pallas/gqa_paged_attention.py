@@ -366,6 +366,7 @@ def gqa_ragged_attention_kernel(q, pool, page_tables, starts, lens, kv_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, kv_heads, rep * qb, d),
                                        q.dtype),
+        name="gqa_paged_attention",
         interpret=resolve_interpret(interpret),
     )(*prefetch, qs, _in_hbm(pool, interpret))
     out = jnp.transpose(out.reshape(n_tiles, kv_heads, rep, qb, d),

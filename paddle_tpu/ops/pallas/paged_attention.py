@@ -952,6 +952,7 @@ def _ragged_call(page_tables, cells, count, starts, lens, kv_lens, qs, kt, vt,
                           capacity=capacity, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, tpad, width), qs.dtype),
+        name="ragged_paged_attention",
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=RAGGED_VMEM_LIMIT),
         interpret=interpret,
@@ -1036,6 +1037,7 @@ def chunk_prefill_attention_kernel(q, k_pool, v_pool, page_table, start,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, n, d), q.dtype),
+        name="chunk_prefill_attention",
         interpret=resolve_interpret(interpret),
     )(*prefetch, qs, kt, vt, *scales)
     return jnp.transpose(out, (1, 0, 2))
@@ -1119,6 +1121,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, page_tables, seq_lens,
                           n_pages=n_pages, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        name="paged_decode_attention",
         interpret=resolve_interpret(interpret),
     )(*prefetch, qs, kt, vt, *scales)
     return out.reshape(b, h, d)
@@ -1419,6 +1422,7 @@ def latent_ragged_attention_kernel(q, pool, page_tables, starts, lens,
                           capacity=capacity, v_width=v_width),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, h * qb, v_width), q.dtype),
+        name="latent_paged_attention",
         interpret=resolve_interpret(interpret),
     )(*prefetch, qs, pool)
     out = jnp.transpose(out.reshape(n_tiles, h, qb, v_width),
@@ -1523,6 +1527,7 @@ def kernel_pool_scatter(pool, pages, rows, x, interpret=None, mesh=None,
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={1: 0},
+        name="pool_row_write",
         interpret=resolve_interpret(interpret),
     )(x.astype(pool.dtype), pool,
       jnp.concatenate([jnp.asarray(pages, jnp.int32),

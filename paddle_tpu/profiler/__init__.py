@@ -95,8 +95,11 @@ def start_profiler(state="All", tracer_option="Default", trace_dir=None):
 def stop_profiler(sorted_key="default", profile_path=None):
     """EnableProfiler teardown parity (profiler.h:213-216): print the
     sorted summary table and, when profile_path is given, dump the span
-    timeline as chrome-trace JSON (chrome://tracing / Perfetto)."""
+    timeline as chrome-trace JSON (chrome://tracing / Perfetto).  The
+    programs compiled so far are read into `device_op_scopes()` here,
+    while the engine that compiled them still holds them."""
     _enabled[0] = False
+    device_op_scopes()
     if _trace_dir[0]:
         jax.profiler.stop_trace()
         _trace_dir[0] = None
@@ -206,4 +209,7 @@ class Profiler:
 
 from .monitor import (  # noqa: E402,F401  (monitor.h StatRegistry parity)
     Stat, StatRegistry, stat_add, stat_sub, stat_get,
+)
+from .device_scopes import (  # noqa: E402,F401
+    device_op_scopes, register_program,
 )

@@ -13,6 +13,7 @@ report, arxiv 2605.25645, attributes most serving throughput to batching
   of the reference's warmed AnalysisPredictor), so steady-state serving
   never traces or compiles again.
 """
+import re
 import threading
 
 import numpy as np
@@ -155,6 +156,26 @@ class ShapeBucketer:
         return per_request
 
 
+def _default_name(fn):
+    kind = getattr(fn, "__name__", "program")
+
+    def name(args):
+        dims = "x".join(map(str, getattr(args[0], "shape", ()))) \
+            if args else ""
+        return f"{kind}_{dims}" if dims else kind
+
+    return name
+
+
+def _named(fn, name):
+    """`fn` under the name its jitted program and module take."""
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = re.sub(r"\W", "_", name)
+    return program
+
+
 class CompiledModelCache:
     """(shapes, dtypes) -> ahead-of-time compiled executable.
 
@@ -173,10 +194,19 @@ class CompiledModelCache:
     token-identity oracle cannot absorb (docs/GENERATION.md).
     compile_count then still means "distinct shape signatures
     dispatched" — the number the bucket menu exists to bound.
+
+    ``name(args)`` names the program of a signature after its kind and
+    its bucket (``ragged_step_p128``): the module a device profile and
+    `profiler.device_op_scopes()` know it by (``jit_ragged_step_p128``).
+    By default the function's own name and the leading argument's shape.
+    Every executable compiled is registered with the profiler, which
+    reads its text only when asked.
     """
 
-    def __init__(self, fn, metrics=None, aot=True, donate_argnums=()):
+    def __init__(self, fn, metrics=None, aot=True, donate_argnums=(),
+                 name=None):
         self._fn = fn
+        self._name = name or _default_name(fn)
         self._metrics = metrics or ServingMetrics()
         self._aot = bool(aot)
         # buffer-donation plan forwarded to jax.jit: generation's fused
@@ -194,7 +224,7 @@ class CompiledModelCache:
     def _compile(self, args):
         import jax
 
-        from ..profiler import RecordEvent
+        from ..profiler import RecordEvent, register_program
 
         if not self._aot:
             return self._fn
@@ -218,12 +248,14 @@ class CompiledModelCache:
         avals = [aval(a) for a in args]
         with RecordEvent("serving::compile"):
             try:
-                exe = jax.jit(self._fn, donate_argnums=self._donate) \
+                exe = jax.jit(_named(self._fn, self._name(args)),
+                              donate_argnums=self._donate) \
                     .lower(*avals).compile()
             except Exception:
                 # fns that resist lowering (host callbacks, non-jax code)
                 # still serve, just without the AOT guarantee
-                exe = self._fn
+                return self._fn
+        register_program(exe)
         return exe
 
     def get(self, args):

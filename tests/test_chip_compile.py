@@ -336,11 +336,13 @@ def test_ragged_step_moves_no_pool_in_kernel_layout(v5e, pages_bucket):
 
 
 # sha256 of the OPT step's lowered text with the kernels' source
-# locations dropped, by pages bucket; as PR 37's tree lowers it (PR 35's
-# grouped cell of the per-head kernel, behind the token hand-over)
+# locations dropped, by pages bucket; as PR 38's tree lowers it (PR 35's
+# grouped cell of the per-head kernel, behind the token hand-over; PR 38
+# named the Pallas calls, and the text differs from PR 37's by those
+# names alone)
 OPT_STEP_DIGESTS = {
-    16: "3eafaadc550d323b87221f6250d9755d755599a58c854b0fb65785a6080f8c8f",
-    128: "787bda08bad0814b45e4f71b226a8b1216d4323bf87d3039b7c75f5990da38c1",
+    16: "a8df76c3951184e3694960e1867bb914d91fd0d1c4764c1d35d4e3bede26f4d8",
+    128: "d46e3f5461ac8997ec99589ac63d8e68bcaa2c22a7f48c8309da225588282cd3",
 }
 
 
@@ -387,11 +389,12 @@ def _shapes_only(monkeypatch, cls):
         lambda self, seed: jax.eval_shape(lambda: draw(self, seed)))
 
 
-# as PR 37's tree lowers it: PR 33's step behind the token hand-over
+# as PR 38's tree lowers it: PR 33's step behind the token hand-over
 # (one dense and one expert layer, a 1,024-row vocabulary, the 512-page
-# bucket; all else the cell's)
+# bucket; all else the cell's), its Pallas call named (PR 38: the text
+# differs from PR 37's by the kernel's name alone)
 GLM_STEP_DIGEST = (
-    "e527a86a66f2ce5da307f91688f07c15736dc07d91abe7be11c85a39f89b60c3")
+    "3ddb5a0d1784a0105b340d8eaf6f687f6417440f1ba0952c3b23fdc385a160e5")
 
 
 def test_the_glm_ragged_step_lowers_to_the_text_it_had(v5e, monkeypatch):

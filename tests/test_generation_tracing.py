@@ -507,3 +507,39 @@ def test_tokens_per_s_gauge_is_gone_and_steps_still_count(model):
     for name in ("StepTimer", "TOKENS_PER_S"):
         assert not hasattr(gmetrics, name)
     assert not hasattr(gmetrics.GenerationMetrics, "_EWMA")
+
+
+def test_step_programs_are_named_and_read_only_when_asked(model,
+                                                          monkeypatch):
+    """`profiler.device_op_scopes()` through `CompiledModelCache`: the
+    engine's ragged step compiles one program a pages bucket, named
+    after it (`ragged_step_p<bucket>`), and keeps a reference to it and
+    nothing more; the compiled text is read when the profiler stops,
+    once, and the map then holds every bucket's module with the step's
+    parts in it and no other part."""
+    import jax
+
+    from paddle_tpu.generation.fused import STEP_SCOPES
+
+    reads = []
+    as_text = jax.stages.Compiled.as_text
+    monkeypatch.setattr(jax.stages.Compiled, "as_text",
+                        lambda self, *a, **kw: reads.append(self)
+                        or as_text(self, *a, **kw))
+    eng = _engine(model)
+    _serve(eng, PROMPTS)
+    assert not reads
+    buckets = {key[4][0][1] for key in eng._ragged.cached_buckets()}
+    assert buckets
+    profiler.start_profiler()
+    assert not reads
+    profiler.stop_profiler()
+    assert len(reads) == len(buckets)
+    scopes = profiler.device_op_scopes()
+    assert len(reads) == len(buckets)         # read once, kept as strings
+    for bucket in buckets:
+        parts = {path.split("/")[0]
+                 for path in scopes[f"jit_ragged_step_p{bucket}"].values()}
+        # (XLA:CPU fuses the hand-over's gather into the embedding's)
+        assert {"embed", "attention", "mlp", "head"} <= parts
+        assert parts <= set(STEP_SCOPES) | {""}
