@@ -28,6 +28,13 @@ retrace.  Rows of the packed axis that belong to no sequence, and picks
 of experts held elsewhere, are sorted past every group: they touch no
 expert and come back 0.
 
+The pairs lie choice-major (pair j * T + t is token t's j-th choice),
+so the way home is a gather of the float32 products into [k * T, d],
+which is [k, T, d] as it lies, weighed and summed over k.  Token-major,
+[T, k, d] would put k on the second-minor axis, which the TPU tiles by
+8: a k that is no multiple of 8 (Granite's 10, GLM's 4) would have XLA
+relay the products out, padded, in every expert layer.
+
 `distributed/fleet/meta_parallel/moe_layer.py` is the trainer's layer
 (top-2, capacity dropping, one-hot dispatch): another thing.
 """
@@ -74,14 +81,20 @@ def expert_ffn(x, experts, weights, valid, w_gate_up, w_down,
     E the experts held.  `experts_held`: (first, count) of the router's
     experts where the layer holds a share of them (count == E), None
     where it holds them all.
+    The (choice, token) pairs are sorted by expert, multiplied, and
+    gathered home into [k, T, d] (the module's header); the pairs not
+    computed are selected away, the rest weighed and summed over k in
+    float32.
     Returns (y [T, d] float32, stats int32 as `STATS` names them: three
     counts, four by a layer told its share).
     """
     t, k = experts.shape
     n_experts, _, f2 = w_gate_up.shape
-    # the (row, choice) pairs computed here: a sequence's rows, and of
+    # choice-major: pair p = j * T + t is token t's j-th choice
+    experts = experts.T                                    # [k, T]
+    # the (choice, row) pairs computed here: a sequence's rows, and of
     # those, where the layer holds a share, the picks of held experts
-    pair = valid[:, None]
+    pair = valid[None, :]
     if experts_held is not None:
         first, count = experts_held
         if count != n_experts:
@@ -93,22 +106,24 @@ def expert_ffn(x, experts, weights, valid, w_gate_up, w_down,
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[flat].add(1)[
         :n_experts]
-    xs = x[order // k]                                     # [T * k, d]
+    xs = x[order % t]                                      # [k * T, d]
     gate_up = jax.lax.ragged_dot(xs, w_gate_up, sizes,
                                  preferred_element_type=jnp.float32)
     hidden = (jax.nn.silu(gate_up[:, :f2 // 2])
               * gate_up[:, f2 // 2:]).astype(x.dtype)
     out = jax.lax.ragged_dot(hidden, w_down, sizes,
                              preferred_element_type=jnp.float32)
-    # back to (token, choice) order, weighed; a row past the groups is
-    # whatever the product left there, so it is selected away, not
-    # multiplied away
-    out = out[jnp.argsort(order)].reshape(t, k, -1)
-    pair = valid[:, None, None] if experts_held is None else pair[:, :, None]
-    out = jnp.where(pair, out * weights[:, :, None], 0.0)
+    # home through the inverse permutation (a scatter, not a second
+    # sort) as [k, T, d], a bitcast while T is a multiple of the 8-row
+    # tile; weighed, and a row past the groups is whatever the product
+    # left there, so it is selected away, not multiplied away
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(k * t, dtype=order.dtype), unique_indices=True)
+    out = out[back].reshape(k, t, -1)
+    out = jnp.where(pair[:, :, None], out * weights.T[:, :, None], 0.0)
     stats = [jnp.sum(sizes), jnp.max(sizes),
              jnp.sum((sizes > 0).astype(jnp.int32))]
     if experts_held is not None:
         stats.append(jnp.sum(valid.astype(jnp.int32)) * k - stats[0])
     stats = jnp.stack(stats)
-    return jnp.sum(out, axis=1), stats
+    return jnp.sum(out, axis=0), stats

@@ -389,19 +389,19 @@ def _shapes_only(monkeypatch, cls):
         lambda self, seed: jax.eval_shape(lambda: draw(self, seed)))
 
 
-# as PR 38's tree lowers it: PR 33's step behind the token hand-over
-# (one dense and one expert layer, a 1,024-row vocabulary, the 512-page
-# bucket; all else the cell's), its Pallas call named (PR 38: the text
-# differs from PR 37's by the kernel's name alone)
+# the latent step behind the token hand-over (one dense and one expert
+# layer, a 1,024-row vocabulary, the 512-page bucket; all else the
+# cell's), its Pallas call named, the experts' pairs choice-major (the
+# text differs from the token-major form's inside `moe.expert_ffn` alone)
 GLM_STEP_DIGEST = (
-    "3ddb5a0d1784a0105b340d8eaf6f687f6417440f1ba0952c3b23fdc385a160e5")
+    "39cfb42507fc751c33ea1974a678788370c57f0dd7e3b4eec02eb4363d6dff3f")
 
 
 def test_the_glm_ragged_step_lowers_to_the_text_it_had(v5e, monkeypatch):
     """`LatentMoELM`'s step is not touched by what it now shares with
     the third served model (`generation/blocks.py`) nor by that model's
     cache and kernel: glm-4.7-flash-d7's lowered step, cut to two layers
-    and a small vocabulary, is the text PR 33 left behind the hand-over."""
+    and a small vocabulary, is the text `GLM_STEP_DIGEST` pins."""
     from paddle_tpu.generation import latent_moe_model as lm
 
     args, engine = _glm_cell()
@@ -440,6 +440,24 @@ def _granite_cell():
     return builder["model_args"], builder["engine"]
 
 
+def _yielded_outside_fusions(text, shape):
+    """The opcodes of a compiled program's instructions that yield
+    `shape` in the computations no fusion calls: the arrays of that
+    shape the program materialises."""
+    fused = set(re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
+    opcodes, inside = [], False
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.endswith("{"):
+            name = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+            inside = name in fused
+        elif not inside:
+            m = re.match(rf"\s*(?:ROOT )?%?[\w.\-]+ = {re.escape(shape)}\S* "
+                         r"([\w\-]+)\(", line)
+            if m:
+                opcodes.append(m.group(1))
+    return opcodes
+
+
 def test_hybrid_step_compiles_at_the_published_widths(v5e, monkeypatch):
     """The whole ragged step of granite-4.0-h-small-d10 at the cell's
     largest pages bucket, weights as shapes: it fits the chip beside its
@@ -448,7 +466,12 @@ def test_hybrid_step_compiles_at_the_published_widths(v5e, monkeypatch):
     state products had two free axes a side), each state array is
     yielded whole by the one-token update's fusion and by the scan's
     in-place slot write alone, and the attention layer's pool by its row
-    write; one grouped-query call and 10 layers x 3 grouped products."""
+    write; one grouped-query call and 10 layers x 3 grouped products.
+    The experts' way back makes no [576, 10, 4096] relayout of the
+    (token, choice) products (k = 10 on the 8-row tile: 151 MB a layer
+    while the pairs lay token-major) and materialises the products'
+    [5760, 4096] once a layer beside the grouped product itself: the
+    gather home."""
     from paddle_tpu.generation import hybrid_ssm_moe_model as hm
 
     args, engine = _granite_cell()
@@ -492,6 +515,10 @@ def test_hybrid_step_compiles_at_the_published_widths(v5e, monkeypatch):
         r"^\s*%?[\w.\-]+ = bf16\[3072,64,2048\]\S* (?!parameter)([\w\-]+)\(",
         text, re.M)]
     assert pool == ["fusion"], pool
+    assert "f32[576,10,4096]" not in text
+    products = [op for op in _yielded_outside_fusions(text, "f32[5760,4096]")
+                if op != "custom-call"]
+    assert len(products) <= 10, products
 
 
 def test_the_pool_check_tells_the_token_layout(v5e, monkeypatch):

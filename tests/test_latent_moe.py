@@ -351,33 +351,54 @@ def test_the_reference_reports_each_position_s_closest_router_call(model):
         got, ranked[:, -model.top_k] - ranked[:, -model.top_k - 1], atol=1e-6)
 
 
-def test_experts_are_dropless_with_an_idle_and_a_crowded_expert():
-    rng = np.random.default_rng(4)
-    t, d, f, n = 40, 16, 8, 6
+@pytest.mark.parametrize("k,held", [
+    (2, None), (4, None), (8, None), (10, None),
+    (10, (6, 8)),        # 8 of a 20-wide router's experts held here
+])
+def test_experts_are_dropless_with_an_idle_and_a_crowded_expert(k, held):
+    """Every (token, choice) pair of a sequence's rows computed and
+    brought home at every k (the pairs lie choice-major, k at 10 and 4
+    no multiple of the 8-row tile): one expert most rows pick, at a
+    choice that moves with the row, one held expert none, 7 padding
+    rows; where the layer holds a share, picks held elsewhere come back
+    0 and the fourth stat counts them."""
+    rng = np.random.default_rng(4 + k)
+    t, d, f, pad = 40, 16, 8, 7
+    first, n = held or (0, 2 * k + 4)
+    width = n if held is None else first + n + 6
+    crowded, idle = first + 2, first + n - 1
     x = jnp.asarray(rng.standard_normal((t, d), np.float32))
     w_gu = jnp.asarray(rng.standard_normal((n, d, 2 * f), np.float32))
     w_d = jnp.asarray(rng.standard_normal((n, f, d), np.float32))
-    # expert 2 gets most rows, expert 5 none; 7 padding rows
-    experts = np.stack([np.full(t, 2), rng.choice([0, 1, 3, 4], t)], 1)
-    experts[::5, 0] = 1
-    experts[::5, 1] = 3
-    weights = jnp.asarray(rng.random((t, 2), np.float32))
-    valid = np.arange(t) < t - 7
+    others = [e for e in range(width) if e not in (crowded, idle)]
+    experts = np.stack([rng.permutation(others)[:k] for _ in range(t)])
+    most = np.arange(t) % 5 != 0
+    experts[most, (np.arange(t) % k)[most]] = crowded
+    weights = jnp.asarray(rng.random((t, k), np.float32))
+    valid = np.arange(t) < t - pad
     got, stats = moe.expert_ffn(x, jnp.asarray(experts, jnp.int32), weights,
-                                jnp.asarray(valid), w_gu, w_d)
+                                jnp.asarray(valid), w_gu, w_d, held)
     want = np.zeros((t, d), np.float32)
-    for r in range(t - 7):
-        for j in range(2):
-            gu = np.asarray(x[r]) @ np.asarray(w_gu[experts[r, j]])
+    for r in range(t - pad):
+        for j in range(k):
+            e = experts[r, j] - first
+            if not 0 <= e < n:
+                continue
+            gu = np.asarray(x[r]) @ np.asarray(w_gu[e])
             hid = gu[:f] / (1 + np.exp(-gu[:f])) * gu[f:]
-            want[r] += float(weights[r, j]) * (
-                hid @ np.asarray(w_d[experts[r, j]]))
+            want[r] += float(weights[r, j]) * (hid @ np.asarray(w_d[e]))
     # every (token, expert) pair computed: float32 sums over 16 and 8
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-5)
-    counts = np.bincount(experts[valid].reshape(-1), minlength=n)
-    assert counts[5] == 0 and counts[2] == counts.max() > (t - 7) // 2
-    assert stats.tolist() == [2 * (t - 7), counts.max(),
-                              int((counts > 0).sum())]
+    picks = experts[valid].reshape(-1) - first
+    here = picks[(picks >= 0) & (picks < n)]
+    counts = np.bincount(here, minlength=n)
+    assert counts[idle - first] == 0
+    assert counts[crowded - first] == counts.max() > (t - pad) // 2
+    want_stats = [len(here), counts.max(), int((counts > 0).sum())]
+    if held is not None:
+        assert len(here) < len(picks)
+        want_stats.append(len(picks) - len(here))
+    assert stats.tolist() == want_stats
 
 
 def _engine(model, pages=64, slots=4, chunk=8, **kw):
