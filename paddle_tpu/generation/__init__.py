@@ -42,11 +42,12 @@ from .gqa_window_moe_model import GQAWindowMoELM
 from .hybrid_ssm_moe_model import HybridSSMMoELM
 from .kv_cache import (DeviceKVPool, HeadRows, KVQuantMismatchError,
                        LatentRows, OutOfPagesError, PagedKVCache,
-                       UnknownSequenceError, UnsupportedCachePathError,
-                       WindowPageGroup)
+                       SparseLatentRows, UnknownSequenceError,
+                       UnsupportedCachePathError, WindowPageGroup)
 from .latent_moe_model import LatentMoELM
 from .metrics import GenerationMetrics
 from .model import TinyCausalLM
+from .sparse_latent_moe_model import SparseLatentMoELM
 from .sampling import (SampleStream, SamplingParams, sample_token,
                        sample_tokens_batch, sample_tokens_device)
 from .scheduler import (ContinuousBatchingScheduler, GenerationRequest,
@@ -63,7 +64,7 @@ __all__ = [
     "sample_tokens_batch", "sample_tokens_device", "SampleStream",
     "GenerationMetrics", "TinyCausalLM", "LatentMoELM", "LatentRows",
     "GQAWindowMoELM", "HeadRows", "WindowPageGroup", "HybridSSMMoELM",
-    "SlotState",
+    "SlotState", "SparseLatentMoELM", "SparseLatentRows",
     "UnsupportedModelPathError", "UnsupportedCachePathError",
     "FusedDecodeStep", "ChunkedPrefillStep", "RaggedStep",
     "LoopedRaggedStep", "decode_batch_menu",

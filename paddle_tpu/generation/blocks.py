@@ -1,5 +1,6 @@
 """What the served models with experts share: the norm, the gated MLP,
-the expert half of a layer and the on-device draw of seeded weights.
+the expert half of a layer, the hyper-connections of a residual several
+streams wide and the on-device draw of seeded weights.
 
 `latent_moe_model.py` (latent attention), `gqa_window_moe_model.py`
 (grouped-query heads, window and full layers) and
@@ -34,12 +35,12 @@ def rms_norm(x, gain, eps):
     return (y * gain).astype(x.dtype)
 
 
-def gated_mlp(x, w_gate_up, w_down):
+def gated_mlp(x, w_gate_up, w_down, swiglu_limit=None):
     """(silu(x W_g) * x W_u) W_d in float32 out; `w_gate_up` holds the
-    gate and the up projection side by side."""
+    gate and the up projection side by side (`moe.swiglu`, clamped by
+    `swiglu_limit` where given)."""
     gate_up = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
-    f = gate_up.shape[-1] // 2
-    hidden = (jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(x.dtype)
+    hidden = moe.swiglu(gate_up, swiglu_limit).astype(x.dtype)
     return jnp.dot(hidden, w_down, preferred_element_type=jnp.float32)
 
 
@@ -51,7 +52,7 @@ def feed_forward_scope(lp):
 
 
 def feed_forward(lp, x, valid, top_k, scaling, scoring="sigmoid_bias",
-                 experts_held=None):
+                 experts_held=None, swiglu_limit=None):
     """A layer's feed-forward half over normed rows x [T, d]: the dense
     gated MLP where the layer has no router, else the routed experts
     (`moe.route` in the scoring form `scoring`, `moe.expert_ffn` over
@@ -60,15 +61,65 @@ def feed_forward(lp, x, valid, top_k, scaling, scoring="sigmoid_bias",
     Returns (y [T, d] in x's dtype, stats int32 as `moe.STATS` or
     None)."""
     if "w_router" not in lp:
-        return gated_mlp(x, lp["w_gate_up"], lp["w_down"]).astype(
-            x.dtype), None
+        return gated_mlp(x, lp["w_gate_up"], lp["w_down"],
+                         swiglu_limit).astype(x.dtype), None
     experts, weights = moe.route(
         x, lp["w_router"], lp.get("router_bias"), top_k, scaling, scoring)
     y, stats = moe.expert_ffn(
         x, experts, weights, valid, lp["experts_gate_up"],
-        lp["experts_down"], experts_held)
-    y = y + gated_mlp(x, lp["shared_gate_up"], lp["shared_down"])
+        lp["experts_down"], experts_held, swiglu_limit)
+    y = y + gated_mlp(x, lp["shared_gate_up"], lp["shared_down"],
+                      swiglu_limit)
     return y.astype(x.dtype), stats
+
+
+def hyper_coefficients(streams, hc, eps, sinkhorn_eps, magnitude, rounds):
+    """The mixing of one sublayer's hyper-connections (mHC's form), a
+    token at a time, float32: streams [T, n, d] ->
+
+        u = rms(vec X);  a = alpha * (u Phi) + b     (Phi: [n d, n (n + 2)])
+        h_pre = sigmoid(a[:n]);  h_post = magnitude * sigmoid(a[n:2n])
+        H_res = Sinkhorn(exp(a[2n:] as n x n))       doubly stochastic
+
+    `hc`: {"phi", "alpha", "bias"}; the Sinkhorn alternates `rounds`
+    normalisations of rows and of columns, `sinkhorn_eps` under each
+    sum.  Returns (h_pre [T, n], h_post [T, n], h_res [T, n, n])."""
+    t, n, d = streams.shape
+    x = streams.astype(jnp.float32).reshape(t, n * d)
+    u = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    a = jnp.dot(u, hc["phi"], precision="highest") * hc["alpha"] + hc["bias"]
+    m = jnp.exp(a[:, 2 * n:].reshape(t, n, n))
+
+    def normalise(_, m):
+        m = m / (jnp.sum(m, axis=2, keepdims=True) + sinkhorn_eps)
+        return m / (jnp.sum(m, axis=1, keepdims=True) + sinkhorn_eps)
+
+    m = jax.lax.fori_loop(0, rounds, normalise, m)
+    return (jax.nn.sigmoid(a[:, :n]),
+            magnitude * jax.nn.sigmoid(a[:, n:2 * n]), m)
+
+
+def hyper_connection(streams, hc, sublayer, eps, sinkhorn_eps=1e-6,
+                     magnitude=2.0, rounds=20):
+    """One sublayer F behind hyper-connections: the residual is n
+    streams X [T, n, d] in their own dtype, and
+
+        X <- H_res X + h_post (x) F(sum_i h_pre_i X_i)
+
+    with the coefficients of `hyper_coefficients` (float32, the sums
+    over the streams elementwise in float32).  `sublayer(x)` returns
+    (F(x) [T, d], aux); returns (X', aux).  The mixing is the step's
+    part `hyper`, F's own work whatever part F names."""
+    with step_scope("hyper"):
+        h_pre, h_post, h_res = hyper_coefficients(
+            streams, hc, eps, sinkhorn_eps, magnitude, rounds)
+        x32 = streams.astype(jnp.float32)
+        x = jnp.sum(h_pre[:, :, None] * x32, axis=1).astype(streams.dtype)
+    y, aux = sublayer(x)
+    with step_scope("hyper"):
+        mixed = jnp.sum(h_res[:, :, :, None] * x32[:, None, :, :], axis=2)
+        out = mixed + h_post[:, :, None] * y.astype(jnp.float32)[:, None, :]
+    return out.astype(streams.dtype), aux
 
 
 def valid_rows(starts, lens, t):
@@ -118,14 +169,18 @@ class DeviceDraw:
         """[n] float32, uniform in [lo, hi)."""
         return jax.random.uniform(self._key(), (n,), jnp.float32, lo, hi)
 
-    def expert_layer(self, d, width, n_experts, n_shared):
+    def expert_layer(self, d, width, n_experts, n_shared, router_width=None):
         """The router, its correction biases (about a tenth of the
         scores' spread, so that they decide some choices), the experts
-        and the shared expert of one layer, in this order."""
+        and the shared expert of one layer, in this order.  The router
+        is `router_width` wide where the layer holds `n_experts` of
+        them, else `n_experts`."""
         fs = n_shared * width
+        router_width = router_width or n_experts
         return {
-            "w_router": self.w(d, n_experts, dtype=jnp.float32),
-            "router_bias": self.w(n_experts, scale=0.02, dtype=jnp.float32),
+            "w_router": self.w(d, router_width, dtype=jnp.float32),
+            "router_bias": self.w(router_width, scale=0.02,
+                                  dtype=jnp.float32),
             "experts_gate_up": self.w(n_experts, d, 2 * width),
             "experts_down": self.w(n_experts, width, d),
             "shared_gate_up": self.w(d, 2 * fs),

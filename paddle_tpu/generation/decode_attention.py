@@ -517,3 +517,153 @@ def gqa_ragged_attention(q, pool, page_tables, starts, lens, kv_lens, scale,
     return gqa_ragged_attention_kernel(
         jnp.asarray(q), jnp.asarray(pool), page_tables, starts, lens,
         kv_lens, scale, kv_heads, window, interpret=interpret, work=work)
+
+
+# ------------- selected tokens of a latent pool (learned indexer) -------------
+def row_positions(starts, lens, kv_lens, n_rows):
+    """(desc [T], qpos [T]) int32 of the packed rows: each row's
+    descriptor and its position in its sequence, -1 for a row no
+    descriptor owns."""
+    starts, lens, kv_lens = (jnp.asarray(x, jnp.int32)
+                             for x in (starts, lens, kv_lens))
+    row = jnp.arange(n_rows, dtype=jnp.int32)[None, :]
+    mine = (row >= starts[:, None]) & (row < (starts + lens)[:, None])
+    desc = jnp.argmax(mine, axis=0).astype(jnp.int32)
+    qpos = kv_lens[desc] - lens[desc] + (row[0] - starts[desc])
+    return desc, jnp.where(jnp.any(mine, axis=0), qpos, -1)
+
+
+def dsa_index_scores_reference(q, w, keys, page_tables, desc):
+    """Pure-jnp index scores: every row against every slot of its
+    descriptor's page table, ``sum_j w_j relu(q_j . k)``.  q: [T, Hi, D],
+    w: [T, Hi] float32, keys: [P, page_size, D].  [T, MP * page_size]
+    float32, unmasked."""
+    pt = jnp.asarray(page_tables, jnp.int32)
+    k = jnp.asarray(keys)[pt].reshape(pt.shape[0], -1, keys.shape[-1])
+    s = jnp.einsum("thd,tkd->thk", q.astype(jnp.float32),
+                   k[desc].astype(jnp.float32), precision="highest")
+    return jnp.einsum("thk,th->tk", jnp.maximum(s, 0.0), w,
+                      precision="highest")
+
+
+def dsa_index_scores(q, w, keys, page_tables, starts, lens, kv_lens, qpos,
+                     use_kernel, interpret=None):
+    """The learned indexer's scores [T, MP * page_size] float32 of each
+    packed row's context, NEG_INF past the row's position and in rows no
+    descriptor owns: the Pallas kernel, or the jnp form of the same."""
+    if use_kernel:
+        from ..ops.pallas.sparse_latent_attention import (
+            indexer_scores_kernel)
+
+        scores = indexer_scores_kernel(q, w, keys, page_tables, starts,
+                                       lens, kv_lens, interpret=interpret)
+    else:
+        desc, _ = row_positions(starts, lens, kv_lens, q.shape[0])
+        scores = dsa_index_scores_reference(q, w, keys, page_tables, desc)
+    col = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+    return jnp.where(col <= qpos[:, None], scores, NEG_INF)
+
+
+# columns a block of the two-stage top-k (`exact_top_k`); rows a step
+# selects over when few of its rows need a sort (`dsa_select`)
+TOPK_BLOCK = 8
+FEW_LONG_ROWS = 64
+
+
+def exact_top_k(scores, k):
+    """[T, k] int32: the columns of each row's k largest scores, exactly,
+    in two sorts of an eighth of the row's width each: the k blocks of
+    `TOPK_BLOCK` columns with the largest maxima hold every one of the k
+    largest (a block that holds one and is left out would leave k other
+    blocks whose maxima, k more scores, stand at or above it), so the k
+    largest of those blocks' columns are the row's.  Ties at the k-th
+    score may be broken otherwise than one sort over the row breaks
+    them."""
+    t, n = scores.shape
+    if n % TOPK_BLOCK or n <= 2 * k * TOPK_BLOCK:
+        return jax.lax.top_k(scores, k)[1].astype(jnp.int32)
+    blocks = scores.reshape(t, n // TOPK_BLOCK, TOPK_BLOCK)
+    _, chosen = jax.lax.top_k(jnp.max(blocks, axis=-1), k)         # [T, k]
+    candidates = jnp.take_along_axis(blocks, chosen[:, :, None], axis=1)
+    _, best = jax.lax.top_k(candidates.reshape(t, k * TOPK_BLOCK), k)
+    block = jnp.take_along_axis(chosen, best // TOPK_BLOCK, axis=1)
+    return (block * TOPK_BLOCK + best % TOPK_BLOCK).astype(jnp.int32)
+
+
+def dsa_select(scores_fn, qpos, top_k, n_cols):
+    """(positions [T, K], counts [T]) int32: each row's selected tokens.
+    A row whose context holds at most K tokens selects all of them,
+    0 .. qpos, without a sort; otherwise the exact top K of its scores
+    (`scores_fn()`, computed only in a step that has such a row; the
+    order of the K is the sort's), sorted over those rows alone where
+    they are at most `FEW_LONG_ROWS` (a step of decode rows), else over
+    all.  counts = min(qpos + 1, K), 0 for a row no descriptor owns."""
+    t = qpos.shape[0]
+    every = jnp.broadcast_to(jnp.arange(top_k, dtype=jnp.int32), (t, top_k))
+    counts = jnp.clip(qpos + 1, 0, top_k)
+    if n_cols <= top_k:          # no context of this bucket is longer
+        return every, counts
+    long = qpos + 1 > top_k
+    few = min(FEW_LONG_ROWS, t)
+
+    def over_all(scores):
+        return exact_top_k(scores, top_k)
+
+    def over_few(scores):
+        rows = jnp.nonzero(long, size=few, fill_value=0)[0]
+        return every.at[rows].set(exact_top_k(scores[rows], top_k))
+
+    def pick():
+        scores = scores_fn()
+        if few == t:
+            return over_all(scores)
+        return jax.lax.cond(jnp.sum(long) <= few, over_few, over_all,
+                            scores)
+
+    picked = jax.lax.cond(jnp.any(long), pick, lambda: every)
+    return jnp.where(long[:, None], picked, every), counts
+
+
+def selected_slots(positions, counts, page_tables, desc, page_size):
+    """[T, K] int32: each selected position's pool slot ``page *
+    page_size + row`` through its row's page table; 0 past the count."""
+    pt = jnp.asarray(page_tables, jnp.int32)
+    page = jnp.take_along_axis(pt[desc], positions // page_size, axis=1)
+    held = jnp.arange(positions.shape[1], dtype=jnp.int32)[None, :] \
+        < counts[:, None]
+    return jnp.where(held, page * page_size + positions % page_size, 0)
+
+
+def sparse_latent_attention_reference(q, pool, slots, counts, sink, v_width):
+    """Pure-jnp attention over selected latent rows, with a sink: the
+    CPU path and the oracle of `sparse_latent_attention_kernel`.  Returns
+    [T, H, v_width] float32."""
+    from ..ops.pallas.sparse_latent_attention import unpack_rows
+
+    rows = unpack_rows(jnp.asarray(pool).reshape(-1, pool.shape[-1])[slots])
+    held = (jnp.arange(slots.shape[1], dtype=jnp.int32)[None, :]
+            < counts[:, None])                              # [T, K]
+    rows = jnp.where(held[:, :, None], rows, 0.0)
+    z = jnp.einsum("thn,tkn->thk", q.astype(jnp.float32), rows,
+                   precision="highest")
+    z = jnp.where(held[:, None, :], z, NEG_INF)
+    sink = jnp.asarray(sink, jnp.float32)[None, :, None]
+    m = jnp.maximum(jnp.max(z, axis=-1, keepdims=True), sink)
+    p = jnp.where(held[:, None, :], jnp.exp(z - m), 0.0)
+    denom = jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m)
+    return jnp.einsum("thk,tkv->thv", p / denom, rows[..., :v_width],
+                      precision="highest")
+
+
+def sparse_latent_attention(q, pool, slots, counts, sink, v_width,
+                            use_kernel, interpret=None):
+    """Attention of each packed row over its selected latent rows: the
+    Pallas kernel, or the jnp form of the same."""
+    if not use_kernel:
+        return sparse_latent_attention_reference(q, pool, slots, counts,
+                                                 sink, v_width)
+    from ..ops.pallas.sparse_latent_attention import (
+        sparse_latent_attention_kernel)
+
+    return sparse_latent_attention_kernel(q, pool, slots, counts, sink,
+                                          v_width, interpret=interpret)

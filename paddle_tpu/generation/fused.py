@@ -49,6 +49,7 @@ probe: exactly 1 dispatch, <=1 host sync per decode step
 import numpy as np
 
 from ..serving.bucketing import CompiledModelCache, ShapeBucketer
+from .kv_cache import SparseLatentRows
 from .metrics import DecodeCacheMetrics
 
 # The parts of a served model's ragged step.  Every operation of the
@@ -56,9 +57,10 @@ from .metrics import DecodeCacheMetrics
 # (a part may hold scopes of its own: attention/window, state_space/
 # scan); the compiled text keeps them, and a device profile is split by
 # them through `profiler.device_op_scopes()` (docs/GENERATION.md,
-# "Reading a trace").
+# "Reading a trace").  `hyper` is the mixing of a model whose residual
+# is several streams wide (`blocks.hyper_connection`).
 STEP_SCOPES = ("hand_over", "embed", "attention", "mlp", "experts",
-               "state_space", "head")
+               "state_space", "head", "hyper")
 
 
 def step_scope(part):
@@ -597,7 +599,12 @@ class RaggedStep:
         self._quant_collectives = bool(quant_collectives) and self._tp > 1
         self._param_leaves, self._param_tree = _shard_params(
             model, mesh, tp_axis, jax)
-        pages_menu = ShapeBucketer.geometric_menu(cache.num_pages, start=1)
+        # a cache whose step does the same work over every page table up
+        # to some width (`min_pages_bucket`) compiles one program for all
+        floor = (1 if cache.rows is None
+                 else cache.rows.min_pages_bucket(cache.page_size))
+        pages_menu = ShapeBucketer.geometric_menu(cache.num_pages,
+                                                  start=floor)
         self._bucketer = ShapeBucketer(batch_buckets=(1,),
                                        length_buckets=pages_menu)
         step_kw = ({"mesh": mesh, "tp_axis": tp_axis}
@@ -831,6 +838,8 @@ class RaggedStep:
         bucket_p = self.last_pages_bucket
         page_size = self._cache.page_size
         rows = self._cache.rows
+        if isinstance(rows, SparseLatentRows):
+            return      # the selected-token kernel walks no page groups
         if getattr(rows, "kv_heads", None):
             self._count_gqa_cells(st, ln, kv, bucket_p, page_size)
             return

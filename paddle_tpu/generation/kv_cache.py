@@ -134,9 +134,19 @@ class TokenRows:
     def lanes(self):
         return -(-self.width // 128) * 128
 
+    @property
+    def row_shape(self):
+        """A token's row as a page of the pool holds it."""
+        return (self.lanes,)
+
     def token_bytes(self, num_layers):
         """Logical bytes a cached token costs over `num_layers`."""
         return self.width * self.dtype.itemsize * int(num_layers)
+
+    def min_pages_bucket(self, page_size):
+        """The fewest pages a step program's page table holds
+        (`fused.RaggedStep`'s bucket menu starts here)."""
+        return 1
 
 
 class LatentRows(TokenRows):
@@ -152,6 +162,61 @@ class LatentRows(TokenRows):
         if not 0 < self.value_width <= self.width:
             raise ValueError(
                 f"value_width {value_width} outside (0, width={width}]")
+
+
+class SparseLatentRows(TokenRows):
+    """The latent row of a model whose attention reads the tokens a
+    learned indexer SELECTS, one token at a time, and not whole pages
+    (`ops/pallas/sparse_latent_attention.py`).  A copy of one row is one
+    Mosaic takes only if the row is 32-bit words, so a two-byte row is
+    stored packed, two numbers a word (`pack_rows`: number i in the low
+    half of word i, number ``lanes + i`` in the high half), and a
+    float32 row as it is: `dtype` is the stored word's, `number_dtype`
+    the model's, `lanes` the stored words a row (whole 128-lane vregs),
+    a page ``[page_size, 1, lanes]``.
+
+    The layers `index_layers` also keep the indexer's key of every
+    token, `index_width` numbers in `number_dtype`, in a second group of
+    pools ``[P, page_size, index_width]`` at the same (page, row) as the
+    latent row: one page table, one free list, one prefix tree for both
+    (`DeviceKVPool`).  A row attends the `index_topk` tokens its indexer
+    selects.
+
+    It is no `LatentRows`: the dense latent kernel cannot read it."""
+
+    layout = "sparse_latent"
+
+    def __init__(self, width, dtype, index_width, index_layers,
+                 index_topk):
+        super().__init__(width, dtype)
+        self.number_dtype = self.dtype
+        self.per_word = 4 // self.number_dtype.itemsize
+        self.dtype = np.dtype(np.uint32 if self.per_word == 2
+                              else self.number_dtype)
+        self.index_width = int(index_width)
+        self.index_layers = tuple(int(i) for i in index_layers)
+        self.index_topk = int(index_topk)
+
+    @property
+    def lanes(self):
+        return -(-self.width // (128 * self.per_word)) * 128
+
+    @property
+    def row_shape(self):
+        """``(1, lanes)``: each row a tile of its own, since Mosaic
+        copies a single row of a page only where it is one."""
+        return (1, self.lanes)
+
+    def token_bytes(self, num_layers):
+        return self.number_dtype.itemsize * (
+            self.width * int(num_layers)
+            + self.index_width * len(self.index_layers))
+
+    def min_pages_bucket(self, page_size):
+        """Page tables of up to this many pages compile one step
+        program: no context they hold is longer than the selection, so
+        none is scored."""
+        return max(1, self.index_topk // int(page_size))
 
 
 class HeadRows(TokenRows):
@@ -2025,6 +2090,9 @@ class DeviceKVPool(PagedKVCache):
             if rows is None:
                 raise UnsupportedCachePathError(
                     "state layers are carried beside row pools alone")
+            if isinstance(rows, SparseLatentRows):
+                raise UnsupportedCachePathError(
+                    "state layers beside index keys are not carried")
             kinds, self.slot_state, slots = state
             self.layer_kinds = _checked_kinds(kinds, num_layers)
             self.state_slots = int(slots)
@@ -2088,11 +2156,16 @@ class DeviceKVPool(PagedKVCache):
             # a layer's page pool, or a state layer's state a slot ...
             self._k = [jnp.zeros(*self._row_pool_shape(layer))
                        for layer in range(self.num_layers)]
-            # ... and the state layers' tails beside them
+            # ... and the state layers' tails beside them, or the index
+            # keys of the layers that keep them, under the same pages
             self._v = [jnp.zeros((self.state_slots + 1,)
                                  + self.slot_state.tail_shape,
                                  self.slot_state.tail_dtype)
                        for _ in range(self.state_layers)]
+            self._v += [jnp.zeros((self.num_pages, self.page_size,
+                                   self.rows.index_width),
+                                  self.rows.number_dtype)
+                        for _ in self.index_layers]
             return
         self._k = [zeros() for _ in range(self.num_layers)]
         self._v = [zeros() for _ in range(self.num_layers)]
@@ -2369,6 +2442,9 @@ class DeviceKVPool(PagedKVCache):
         if self.rows is not None:
             self._k = _jitted_latent_page_copy()(
                 self._k, jnp.int32(src), jnp.int32(dst))
+            if self._v:     # the index keys share the page
+                self._v = _jitted_latent_page_copy()(
+                    self._v, jnp.int32(src), jnp.int32(dst))
             return
         if self.quantized:
             self._flush_scale_resets()
@@ -2410,12 +2486,19 @@ class DeviceKVPool(PagedKVCache):
                     self.slot_state.state_dtype)
         pages = (self.window_group.num_pages if kind == "window"
                  else self.num_pages)
-        return (pages, self.page_size, self.rows.lanes), self.dtype
+        return (pages, self.page_size) + self.rows.row_shape, self.dtype
 
     @property
     def state_layers(self):
         """How many layers keep a state a slot and no pages."""
         return (self.layer_kinds or ()).count("state")
+
+    @property
+    def index_layers(self):
+        """The layers that keep an index key a token beside their row
+        (a `SparseLatentRows` cache), in order."""
+        return (self.rows.index_layers
+                if isinstance(self.rows, SparseLatentRows) else ())
 
     def _refuse_latent(self, what):
         if self.rows is not None:
@@ -2432,8 +2515,7 @@ class DeviceKVPool(PagedKVCache):
         return self._k[layer]
 
     def _count_latent_payload(self, tokens, layers):
-        self._bytes_moved += (tokens * layers * self.rows.width
-                              * self.dtype.itemsize)
+        self._bytes_moved += tokens * self.rows.token_bytes(layers)
 
     def gather_prefix(self, seq_id, layer, length):
         """Device-resident prefix gather: rows come straight out of the
@@ -2484,9 +2566,11 @@ class DeviceKVPool(PagedKVCache):
     def state_group_sizes(self):
         """The lengths of the array groups `take_pool_state` lays end to
         end: `n_state_groups` of L, and behind a row cache's L arrays
-        the state layers' tails, one a state layer."""
+        the state layers' tails, one a state layer, or the index layers'
+        key pools, one an index layer."""
         sizes = (self.num_layers,) * self._groups
-        return sizes + ((self.state_layers,) if self.state_layers else ())
+        return sizes + tuple(n for n in (self.state_layers,
+                                         len(self.index_layers)) if n)
 
     def take_pool_state(self):
         """The WHOLE donated device state as one flat list —

@@ -140,10 +140,11 @@ class LatentMoELM:
         return jnp.dot(a, w, preferred_element_type=jnp.float32).astype(
             self.dtype)
 
-    def _queries_and_row(self, lp, x, positions):
-        """(q_abs [T, H, lanes], row [T, lanes]): the absorbed queries
-        and the cache row of each packed token, both zero past the
-        row's width."""
+    def _latent_parts(self, lp, x, positions):
+        """(c^Q [T, q_rank], q_abs [T, H, kv_rank], q_rope [T, H, rope],
+        c [T, kv_rank], k_rope [T, rope]) of each packed token: the
+        normed query latent, the absorbed and the rotated queries, the
+        normed compressed kv and the rotated shared key."""
         t, h = x.shape[0], self.num_heads
         cq = rms_norm(self._mm(x, lp["w_qa"]), lp["norm_q"], self.eps)
         q = self._mm(cq, lp["w_qb"]).reshape(t, h, self.nope + self.rope)
@@ -156,6 +157,14 @@ class LatentMoELM:
         q_abs = jnp.einsum("thn,chn->thc", q[..., :self.nope], w_k,
                            preferred_element_type=jnp.float32).astype(
                                self.dtype)
+        return cq, q_abs, q_rope, c, k_rope
+
+    def _queries_and_row(self, lp, x, positions):
+        """(q_abs [T, H, lanes], row [T, lanes]): the absorbed queries
+        and the cache row of each packed token, both zero past the
+        row's width."""
+        t, h = x.shape[0], self.num_heads
+        _, q_abs, q_rope, c, k_rope = self._latent_parts(lp, x, positions)
         pad = self.kv_rows().lanes - self.kv_rank - self.rope
         q_abs = jnp.concatenate(
             [q_abs, q_rope, jnp.zeros((t, h, pad), self.dtype)], axis=-1)
@@ -163,13 +172,17 @@ class LatentMoELM:
             [c, k_rope, jnp.zeros((t, pad), self.dtype)], axis=-1)
         return q_abs, row
 
-    def _attention_out(self, lp, o_abs):
-        """o~ [T, H, kv_rank] through the value half of w_kvb and w_o."""
+    def _attention_out(self, lp, o_abs, gate=None):
+        """o~ [T, H, kv_rank] through the value half of w_kvb and w_o;
+        `gate` [T, H, v_dim] float32, where given, multiplies each
+        head's value before w_o."""
         t, h = o_abs.shape[0], self.num_heads
         w_v = lp["w_kvb"].reshape(self.kv_rank, h, self.nope + self.v_dim)[
             :, :, self.nope:]
         o = jnp.einsum("thc,chv->thv", o_abs.astype(self.dtype), w_v,
                        preferred_element_type=jnp.float32).astype(self.dtype)
+        if gate is not None:
+            o = (o.astype(jnp.float32) * gate).astype(self.dtype)
         return self._mm(o.reshape(t, h * self.v_dim), lp["w_o"])
 
     # --------------------------- the ragged step ---------------------

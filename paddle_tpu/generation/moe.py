@@ -71,8 +71,19 @@ def route(x, w_router, bias, top_k, scaling, scoring="sigmoid_bias"):
     return experts.astype(jnp.int32), weights
 
 
+def swiglu(gate_up, limit=None):
+    """silu(g) * u of the gate and the up projection side by side in
+    `gate_up` [..., 2f]; with `limit`, clamped first as silu(min(g,
+    limit)) * clip(u, -limit, limit)."""
+    f = gate_up.shape[-1] // 2
+    if limit is None:
+        return jax.nn.silu(gate_up[..., :f]) * gate_up[..., f:]
+    return (jax.nn.silu(jnp.minimum(gate_up[..., :f], limit))
+            * jnp.clip(gate_up[..., f:], -limit, limit))
+
+
 def expert_ffn(x, experts, weights, valid, w_gate_up, w_down,
-               experts_held=None):
+               experts_held=None, swiglu_limit=None):
     """The routed experts' part of the layer's output.
 
     x: [T, d]; experts/weights: [T, k] from `route`; valid: [T] bool,
@@ -80,7 +91,8 @@ def expert_ffn(x, experts, weights, valid, w_gate_up, w_down,
     expert's gate and up projections side by side), w_down: [E, f, d],
     E the experts held.  `experts_held`: (first, count) of the router's
     experts where the layer holds a share of them (count == E), None
-    where it holds them all.
+    where it holds them all.  `swiglu_limit`: the clamp of `swiglu`,
+    None for none.
     The (choice, token) pairs are sorted by expert, multiplied, and
     gathered home into [k, T, d] (the module's header); the pairs not
     computed are selected away, the rest weighed and summed over k in
@@ -89,7 +101,7 @@ def expert_ffn(x, experts, weights, valid, w_gate_up, w_down,
     counts, four by a layer told its share).
     """
     t, k = experts.shape
-    n_experts, _, f2 = w_gate_up.shape
+    n_experts = w_gate_up.shape[0]
     # choice-major: pair p = j * T + t is token t's j-th choice
     experts = experts.T                                    # [k, T]
     # the (choice, row) pairs computed here: a sequence's rows, and of
@@ -109,8 +121,7 @@ def expert_ffn(x, experts, weights, valid, w_gate_up, w_down,
     xs = x[order % t]                                      # [k * T, d]
     gate_up = jax.lax.ragged_dot(xs, w_gate_up, sizes,
                                  preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(gate_up[:, :f2 // 2])
-              * gate_up[:, f2 // 2:]).astype(x.dtype)
+    hidden = swiglu(gate_up, swiglu_limit).astype(x.dtype)
     out = jax.lax.ragged_dot(hidden, w_down, sizes,
                              preferred_element_type=jnp.float32)
     # home through the inverse permutation (a scatter, not a second

@@ -796,3 +796,128 @@ def test_gqa_step_compiles_at_the_published_widths(v5e, monkeypatch):
             rf"^\s*%?([\w.\-]+) = bf16\[{pages[kind]},64,1024\]\S* "
             r"(?!parameter)", text, re.M)]
         assert len(whole) == count, (kind, whole)
+
+
+def _hy4_cell():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "hy4-preview-d5.json")) as f:
+        builder = json.load(f)["builder"]
+    return builder["model_args"], builder["engine"]
+
+
+@pytest.mark.parametrize("pages_bucket", [32, 2048])
+def test_sparse_step_compiles_at_the_published_widths(v5e, monkeypatch,
+                                                      pages_bucket):
+    """The whole ragged step of hy4-preview-d5, weights as shapes, at a
+    bucket whose contexts all fit the selection (no sort) and at the
+    cell's largest (66-74 k tokens): it fits the chip beside its 9.1 GB
+    of weights and 3.8 GB of pages and index keys; five selected-token
+    calls and two indexer calls, the pools written in place."""
+    from paddle_tpu.generation import sparse_latent_moe_model as sm
+
+    args, engine = _hy4_cell()
+    _shapes_only(monkeypatch, sm.SparseLatentMoELM)
+    model = sm.SparseLatentMoELM(**args, seed=1)
+    slots = engine["max_decode_slots"]
+    t, s = engine["prefill_chunk_tokens"] + slots, slots + 1
+    rows = model.kv_rows()
+    assert (rows.lanes, rows.dtype, rows.token_bytes(5)) == (
+        384, np.dtype(np.uint32), 6272)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    model.params)
+    pages = (engine["num_pages"], engine["page_size"])
+    pools = [sds(pages + rows.row_shape, rows.dtype)] * model.num_layers
+    keys = [sds(pages + (rows.index_width,), rows.number_dtype)] * len(
+        rows.index_layers)
+    fixed = [sds((t,), "int32")] * 4 + [sds((s, pages_bucket), "int32")] + [
+        sds((s,), "int32")] * 3
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(engine["page_size"], engine["num_pages"],
+                             use_kernel=True), fixed)
+    compiled = jax.jit(fn, donate_argnums=(11, 12)).lower(
+        params, *fixed, pools, keys).compile()
+    memory = compiled.memory_analysis()
+    assert 12.7e9 < memory.argument_size_in_bytes < 13.0e9
+    assert memory.temp_size_in_bytes < 2 << 30
+    text = compiled.as_text()
+    assert len(re.findall(r"%sparse_latent_paged_attention[\w.\-]* = ",
+                          text)) == 5
+    indexer = len(re.findall(r"%dsa_indexer_scores[\w.\-]* = ", text))
+    assert indexer == (0 if pages_bucket * 64 <= 2048 else 2)
+    pool = [m.group(1) for m in re.finditer(
+        r"^\s*%?[\w.\-]+ = u32\[7168,64,1,384\]\S* (?!parameter)([\w\-]+)\(",
+        text, re.M)]
+    assert "copy" not in pool, pool
+
+
+# the expert models' lowered steps (weights as shapes, a 1,024-row
+# vocabulary, four layers, a 64-page bucket over 256 pages; all else
+# their cells') as the tree before the selected-token model had them:
+# the clamp it added to `moe.swiglu` / `blocks.feed_forward` is off for
+# them, and off is the text that was there
+EXPERT_STEP_DIGESTS = {"granite-4.0-h-small-d10": "f436d81922438655",
+                       "trinity-mini-d8": "c6ce88f0013a8292"}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_STEP_DIGESTS))
+def test_the_expert_steps_lower_to_the_text_they_had(v5e, monkeypatch, name):
+    import json
+    import os
+
+    from paddle_tpu.generation import gqa_window_moe_model as gm
+    from paddle_tpu.generation import hybrid_ssm_moe_model as hm
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           name + ".json")) as f:
+        builder = json.load(f)["builder"]
+    args, engine = dict(builder["model_args"]), builder["engine"]
+    granite = name.startswith("granite")
+    cls = hm.HybridSSMMoELM if granite else gm.GQAWindowMoELM
+    _shapes_only(monkeypatch, cls)
+    args.update(vocab_size=1024, num_layers=4)
+    args["layer_types"] = (["mamba", "attention", "mamba", "mamba"]
+                           if granite else args["layer_types"][:4])
+    model = cls(**args, seed=1)
+    t = engine["prefill_chunk_tokens"] + engine["max_decode_slots"]
+    s = engine["max_decode_slots"] + 1
+    rows = model.kv_rows()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    def pool(pages):
+        return sds((pages, engine["page_size"], rows.lanes), rows.dtype)
+
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    model.params)
+    fixed = [sds((t,), "int32")] * 4 + [sds((s, 64), "int32")] + [
+        sds((s,), "int32")] * 3
+    if granite:
+        state = model.kv_slot_state()
+        slots = engine["max_decode_slots"] + 1
+        fixed.append(sds((s,), "int32"))
+        groups = [[sds((slots,) + state.state_shape, state.state_dtype)
+                   if kind == "state" else pool(256)
+                   for kind in model.layer_kinds],
+                  [sds((slots,) + state.tail_shape, state.tail_dtype)
+                   for kind in model.layer_kinds if kind == "state"]]
+    else:
+        fixed += [sds((t,), "int32"), sds((s, 64), "int32")]
+        groups = [[pool(256 if kind == "full" else 128)
+                   for kind in model.kv_layer_kinds()[0]]]
+    fn, fixed = _handed_over(
+        model.ragged_step_fn(engine["page_size"], 256, use_kernel=True),
+        fixed)
+    lowered = jax.jit(fn).lower(params, *fixed, *groups)
+    n_kernels = len(re.findall(r'body\\22: \\22', lowered.as_text()))
+    digest = _step_digest(lowered, n_kernels)
+    assert digest[:16] == EXPERT_STEP_DIGESTS[name]

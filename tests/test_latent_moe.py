@@ -660,3 +660,26 @@ def test_the_grid_counter_of_a_latent_engine_counts_page_slots(model,
     plain = _engine(model)             # the jnp form: no latent kernel
     assert plain.stats()["generation.latent_pages_per_cell"] == 0
     plain.shutdown()
+
+
+# the served logits of `_Stepper` below (a 23-token prompt in chunks of
+# 7, then a decode row beside a 9-token prompt) as the tree before the
+# selected-token model came had them, byte for byte: the jnp form and
+# the kernel.  What that model shares with this one (the projections,
+# `moe.swiglu`, `blocks.feed_forward`) leaves them as they were
+GLM_LOGIT_DIGESTS = {
+    False: "1f762404060f7205c537849e137929053de8e8e9ebb4dc02730cdc85fb8bb6e0",
+    True: "6825e6198686c97198e0091fb4df16c2150a18dd2f01ae15dbdf1977cad5e9b2"}
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_the_served_logits_are_bitwise_the_ones_pinned(model, use_kernel):
+    import hashlib
+
+    stepper = _Stepper(model, use_kernel=use_kernel)
+    for lo in range(0, len(PROMPT), 7):
+        logits = stepper.step([("a", PROMPT[lo:lo + 7])])
+    more = stepper.step([("a", [int(np.argmax(logits[0]))]),
+                         ("b", PROMPT[:9])])
+    digest = hashlib.sha256(np.concatenate([logits, more]).tobytes())
+    assert digest.hexdigest() == GLM_LOGIT_DIGESTS[use_kernel]
